@@ -1,0 +1,84 @@
+"""Start-up: the boot function of ``python -m tidb_tpu`` names its
+device and fails loudly, and the compile cache is placeable from
+outside (ISSUE 22)."""
+
+import json
+import os
+import subprocess
+import sys
+import urllib.request
+
+import pytest
+
+from tidb_tpu import __main__ as entry
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_boot_reports_its_device_on_status(capsys):
+    server = entry.boot(["--device", "cpu", "--port", "0",
+                         "--status-port", "0"])
+    try:
+        assert server.mesh is not None  # --mesh auto is the default
+        with urllib.request.urlopen(
+                f"http://{server.host}:{server.status_port}/status",
+                timeout=10) as r:
+            status = json.loads(r.read())
+        assert status["platform"] == "cpu"
+        assert status["device_kind"]
+        assert status["count"] >= 1
+        assert server.device == {k: status[k] for k in
+                                 ("platform", "device_kind", "count")}
+        line = capsys.readouterr().err.strip().splitlines()[-1]
+        assert "platform=cpu" in line and "device_kind=" in line \
+            and f"devices={status['count']}" in line
+    finally:
+        server.stop()
+
+
+def test_mesh_auto_that_cannot_build_a_mesh_exits_nonzero(monkeypatch, capsys):
+    """No headless boot: --mesh auto without a mesh is an error."""
+    import tidb_tpu.parallel as par
+
+    def boom(*_a, **_k):
+        raise ValueError("mesh 1x9 needs 9 devices, have 8")
+
+    monkeypatch.setattr(par, "make_mesh", boom)
+    argv = ["--device", "cpu", "--port", "0", "--status-port", "-1"]
+    with pytest.raises(ValueError):
+        entry.boot(argv)
+    assert entry.main(argv) != 0
+    assert "failed to start" in capsys.readouterr().err
+    # the explicit single-device choice still boots
+    server = entry.boot(argv + ["--mesh", "none"])
+    try:
+        assert server.mesh is None
+    finally:
+        server.stop()
+
+
+def _cache_config(env_dir):
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import json, jax, tidb_tpu; print(json.dumps(["
+         "jax.config.jax_compilation_cache_dir, "
+         "jax.config.jax_persistent_cache_min_compile_time_secs]))"],
+        env=env, cwd=ROOT, capture_output=True, text=True, timeout=120,
+        check=True)
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("env_dir", ["/some/dir", None],
+                         ids=["env-set", "env-unset"])
+def test_compile_cache_directory(env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins when set (tidb_tpu names no
+    directory in code); otherwise <checkout>/.jax_cache. One threshold
+    of at most a second for every process."""
+    cache_dir, min_secs = _cache_config(env_dir)
+    assert cache_dir == (env_dir or os.path.join(ROOT, ".jax_cache"))
+    assert min_secs <= 1.0

@@ -78,3 +78,62 @@ def test_numpy_fallback_forced():
     counts = load_tpch(s.catalog, sf=0.002, native=False)
     assert counts["lineitem"] > 0
     assert s.query("select count(*) from lineitem")[0][0] == counts["lineitem"]
+
+
+class TestRebuildFromSource:
+    """The library is never committed: a checkout builds it from
+    native/tpch_gen.cpp, and staleness is decided by the recorded source
+    hash, not by mtimes a copied tree does not keep."""
+
+    @pytest.fixture
+    def scratch_native(self, tmp_path, monkeypatch):
+        import shutil
+
+        from tidb_tpu.storage import native_gen as ng
+
+        shutil.copy(ng._SRC, tmp_path / "tpch_gen.cpp")
+        lib = str(tmp_path / "libtpchgen.so")
+        monkeypatch.setattr(ng, "_SRC", str(tmp_path / "tpch_gen.cpp"))
+        monkeypatch.setattr(ng, "_LIB", lib)
+        monkeypatch.setattr(ng, "_LIB_HASH", lib + ".srchash")
+        monkeypatch.setattr(ng, "_lib", None)
+        monkeypatch.setattr(ng, "_load_error", None)
+        return ng
+
+    def test_missing_library_is_rebuilt(self, scratch_native):
+        import os
+
+        ng = scratch_native
+        assert not os.path.exists(ng._LIB)
+        assert ng.load_native() is not None, ng.load_error()
+        assert os.path.exists(ng._LIB)
+        assert ng._built_hash() == ng._source_hash()
+
+    def test_changed_source_hash_forces_rebuild(self, scratch_native,
+                                                monkeypatch):
+        import os
+
+        ng = scratch_native
+        assert ng.load_native() is not None
+        builds = []
+        real = ng._build
+        monkeypatch.setattr(
+            ng, "_build", lambda h: (builds.append(h), real(h)))
+        # same source, same hash: a fresh process loads without building
+        # even though the library is OLDER than the source by mtime
+        os.utime(ng._LIB, (1, 1))
+        monkeypatch.setattr(ng, "_lib", None)
+        assert ng.load_native() is not None and builds == []
+        # the source changes: the recorded hash no longer matches
+        with open(ng._SRC, "a") as f:
+            f.write("\n// edited\n")
+        monkeypatch.setattr(ng, "_lib", None)
+        assert ng.load_native() is not None
+        assert builds == [ng._source_hash()] == [ng._built_hash()]
+
+    def test_gitignore_lists_the_library(self):
+        import os
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, ".gitignore")) as f:
+            assert "native/libtpchgen.so" in f.read().split()

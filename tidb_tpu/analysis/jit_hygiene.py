@@ -28,9 +28,7 @@ __all__ = ["JitHygienePass"]
 # modules whose exported callables are jit-family wrappers
 _WRAPPER_IMPORTS = {
     ("jax", "jit"), ("jax", "shard_map"),
-    ("jax.experimental.shard_map", "shard_map"),
     ("tidb_tpu.utils.dispatch", "counted_jit"),
-    ("tidb_tpu.parallel.mesh", "shard_map_compat"),
 }
 
 
@@ -95,7 +93,7 @@ class JitHygienePass(Pass):
         for sf in project.files():
             out.extend(self._check_module(sf))
         # one violation per wrap site even when wrappers nest
-        # (jax.jit(shard_map_compat(...)) is one device program)
+        # (jax.jit(jax.shard_map(...)) is one device program)
         seen = set()
         uniq = []
         for v in out:
@@ -194,10 +192,7 @@ class JitHygienePass(Pass):
                 root = node.value
                 if isinstance(root, ast.Name) and root.id == "jax":
                     return True
-                # jax.experimental.shard_map.shard_map
-                if isinstance(root, ast.Attribute):
-                    return True
-            if node.attr in ("counted_jit", "shard_map_compat"):
+            if node.attr == "counted_jit":
                 return True
         if isinstance(node, ast.Name) and node.id in wrappers:
             return True

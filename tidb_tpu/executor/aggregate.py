@@ -349,8 +349,8 @@ class HashAggExec(Executor):
         """Host finalize of [G]-shaped accumulators: unpack occupied groups.
         Shared with the distributed executors (parallel/executor.py), which
         produce the same state via collective merge."""
-        # one batched fetch: on a remote/tunneled device, per-key np.asarray
-        # would pay a round trip per state array
+        # one batched fetch: per-key np.asarray would pay a device round
+        # trip per state array
         import jax
 
         from tidb_tpu.utils import dispatch as dsp

@@ -38,10 +38,15 @@ parked in the ``DeviceBufferCache`` so a warm repeated join stages and
 sorts nothing. See the class docstring for the overflow/deferral
 contract.
 
-Glue (finalize, result decode) still runs under ``host_eager`` like the
-rest of the executor tier; the staging device is pinned in the MAIN
-thread (the prefetch thread does not inherit jax's thread-local default
-device) so buffers always land where the fused program runs.
+Placement: on a non-CPU backend everything above lives and runs on the
+accelerator — staged scan buffers, ``DeviceBufferCache`` entries, build
+tables, carried state and the fused programs. The fused execs enter
+``utils.device.device_tier()`` around their staging/build/dispatch
+loops (the tree walk around them is pinned to the host by
+``host_eager``), and the prefetch thread — which does not inherit jax's
+thread-local default device — stages to ``accelerator_device()``
+captured in the constructor. Glue (finalize over a few groups, result
+decode) stays on the host.
 """
 
 from __future__ import annotations
@@ -61,6 +66,7 @@ from tidb_tpu.executor.aggregate import HashAggExec, make_segment_kernel
 from tidb_tpu.executor.base import ExecContext, Executor, raise_if_cancelled
 from tidb_tpu.executor.join import HashJoinExec
 from tidb_tpu.ops import join_kernels as jk
+from tidb_tpu.utils.device import device_tier, note_placement
 from tidb_tpu.utils.jitcache import cached_jit
 from tidb_tpu.utils.memory import QueryOOMError
 
@@ -182,7 +188,7 @@ class ChunkPrefetcher:
     ``jobs`` is an ordered list of zero-arg callables, each returning
     one chunk's HOST pytree (numpy buffers). A daemon thread runs them
     in order, ``jax.device_put``s the result onto the staging device
-    captured in the constructor (thread-locals like ``host_eager`` do
+    captured in the constructor (thread-locals like ``device_tier`` do
     not cross threads), and parks when ``depth`` buffers sit staged but
     unconsumed. In-flight staged bytes are charged to the statement
     MemTracker — a tight ``tidb_mem_quota_query`` surfaces as the same
@@ -193,14 +199,14 @@ class ChunkPrefetcher:
     POLL_S = 0.05
 
     def __init__(self, jobs: List[Callable], ctx: ExecContext, stats=None):
-        from tidb_tpu.utils.device import host_cpu_device
+        from tidb_tpu.utils.device import accelerator_device
 
         self.jobs = jobs
         self.ctx = ctx
         self.stats = stats
         self.depth = max(int(getattr(ctx, "prefetch_depth", 0) or 0), 0)
         self.tracker = ctx.mem_tracker.child("pipeline.prefetch")
-        self._device = host_cpu_device()  # None = default backend is CPU
+        self._device = accelerator_device()  # None = the backend is CPU
         self._staged: Dict[int, Tuple[object, int]] = {}
         self._err: Optional[BaseException] = None
         self._next_get = 0
@@ -224,6 +230,7 @@ class ChunkPrefetcher:
         else:
             staged = jax.device_put(host)
         dsp.record(site="stage")
+        note_placement("stage", staged)
         PIPELINE_PREFETCH_BYTES.inc(nbytes)
         return staged, nbytes
 
@@ -769,12 +776,15 @@ class FusedScanAggExec(_StagedScanMixin, HashAggExec):
             donate_argnums=0)
         init_state, _u, _g = make_segment_kernel(
             self.group_exprs, self.aggs, domains)
-        state = init_state()
-        for staged in self._staged_chunks(jobs):
-            # KILL/deadline polls BETWEEN device steps: the fusion must
-            # not turn a chunked fragment into an uninterruptible run
-            raise_if_cancelled(ctx)
-            state = fused(state, *staged)
+        with device_tier():
+            state = init_state()
+            for staged in self._staged_chunks(jobs):
+                # KILL/deadline polls BETWEEN device steps: the fusion
+                # must not turn a chunked fragment into an
+                # uninterruptible run
+                raise_if_cancelled(ctx)
+                state = fused(state, *staged)
+            note_placement("fused", state)
         self._finalize_segment_state(state, domains)
 
     def _run_generic_fused(self):
@@ -794,10 +804,13 @@ class FusedScanAggExec(_StagedScanMixin, HashAggExec):
                                            self.group_exprs, self.aggs,
                                            seg_cap))
         stack = GroupTableStack(len(self.group_exprs), self.aggs, sig)
-        for staged in self._staged_chunks(jobs):
-            raise_if_cancelled(ctx)  # see _run_segment_fused
-            stack.push(fused(*staged))
-        self._finalize_group_tables(stack.tables())
+        with device_tier():
+            for staged in self._staged_chunks(jobs):
+                raise_if_cancelled(ctx)  # see _run_segment_fused
+                stack.push(fused(*staged))
+            tables = stack.tables()
+            note_placement("fused", tables)
+        self._finalize_group_tables(tables)
 
 
 # ---------------------------------------------------------------------------
@@ -947,7 +960,8 @@ class FusedScanProbeExec(_StagedScanMixin, HashJoinExec):
             return
         self._ran_fused = True
         try:
-            self._open_build(ctx)
+            with device_tier():
+                self._open_build(ctx)
             if self._hash_mode:
                 # composite-key ranges overflowed int64 range packing
                 # (data-dependent, known only after the build drain):
@@ -960,7 +974,8 @@ class FusedScanProbeExec(_StagedScanMixin, HashJoinExec):
                 self._delegate = d
                 return
             jobs = self._plan_staging(ctx)
-            self._fused_fn = self._make_fused()
+            with device_tier():
+                self._fused_fn = self._make_fused()
             self._staged_iter = self._staged_chunks(jobs)
         except BaseException:
             self._release_staging()
@@ -974,7 +989,8 @@ class FusedScanProbeExec(_StagedScanMixin, HashJoinExec):
                 return self._pending.pop(0)
             if self._drained:
                 return None
-            self._fill_pending_fused()
+            with device_tier():
+                self._fill_pending_fused()
 
     def close(self) -> None:
         _close_delegate(self)
@@ -1135,6 +1151,7 @@ class FusedScanProbeExec(_StagedScanMixin, HashJoinExec):
                "total_dev": total_dev, "start": start, "count": count,
                "real_count": real_count, "cum": cum, "p_cols": p_cols,
                "cap": int(sel_tile.shape[0]), "t0": t0}
+        note_placement("fused", (total_dev, sel_tile))
         # the window pins the chunk's expanded tile AND the probe state
         # needed for a potential overflow re-expansion
         tok["nbytes"] = _pytree_nbytes(
@@ -1381,12 +1398,15 @@ class FusedScanTopNExec(_StagedScanMixin, Executor):
             donate_argnums=0)
         key_floats = tuple(tk.key_spec(e.type_) for e in sort_irs)
         dtypes = tuple(c.type_.np_dtype for c in self.schema)
-        state = tk.topk_init(cap_state, key_floats, dtypes)
-        for staged in self._staged_chunks(jobs):
-            # KILL/deadline polls BETWEEN device steps: the fusion must
-            # not turn a chunked fragment into an uninterruptible run
-            raise_if_cancelled(ctx)
-            state = fused(state, *staged)
+        with device_tier():
+            state = tk.topk_init(cap_state, key_floats, dtypes)
+            for staged in self._staged_chunks(jobs):
+                # KILL/deadline polls BETWEEN device steps: the fusion
+                # must not turn a chunked fragment into an
+                # uninterruptible run
+                raise_if_cancelled(ctx)
+                state = fused(state, *staged)
+            note_placement("fused", state[0])
         # THE intentional top-k sync: ONE fetch of the C winners at
         # finalize, however many chunks streamed through (sanctioned
         # device_get outside any loop — the chunk-loop sync-budget pass

@@ -42,7 +42,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from tidb_tpu.ops.segment_sum import pallas_enabled
+from tidb_tpu.errors import UnsupportedError
+from tidb_tpu.ops.segment_sum import (pallas_enabled, pallas_interpret,
+                                      target_platform)
 
 __all__ = ["probe_ranges", "xla_probe_ranges", "probe_for_join",
            "set_mode", "resolve_mode", "table_capacity", "MAX_CAPACITY"]
@@ -52,7 +54,9 @@ import os
 # "off": always searchsorted; "auto" (default): hash table when the
 # computation targets TPU (trace-time force_platform aware, like
 # segment_sum); "xla": hash table everywhere (window-scan probe);
-# "pallas": hash table with the Pallas VMEM kernel.
+# "pallas": hash table with the Pallas VMEM kernel — CPU interpret only:
+# the TPU compiler refuses the kernel (_refuse_pallas_on_tpu), so no
+# automatic choice ever picks it.
 #
 # Auto keeps CPU on searchsorted because it measures faster there
 # (bench.py bench_probe — 32 fixed window rounds vs ~2*log2(Rb)
@@ -80,6 +84,22 @@ def set_mode(m: str) -> None:
     _mode = m
 
 
+PALLAS_PROBE_REFUSAL = "Cannot do int indexing on TPU"
+
+
+def _refuse_pallas_on_tpu() -> None:
+    """'pallas' asked for on a TPU: raise the compiler's refusal typed,
+    at plan time, instead of interpreting the kernel or quietly probing
+    another way. _probe_pallas gathers table slots by a vector of
+    positions (keys_ref[pos]), which Mosaic does not lower;
+    tests/test_chip_compile.py pins the compiler's own error."""
+    if target_platform() == "tpu":
+        raise UnsupportedError(
+            "tidb_tpu_join_probe_mode=pallas cannot run on a TPU: the "
+            "chip's compiler refuses hash_probe._probe_pallas "
+            f"(ValueError: {PALLAS_PROBE_REFUSAL}); use auto, xla or off")
+
+
 def resolve_mode(mode: str = None) -> str:
     """Concrete probe strategy — 'sorted' | 'xla' | 'pallas' — for the
     platform the CURRENT computation targets (trace-time, so mesh
@@ -90,6 +110,8 @@ def resolve_mode(mode: str = None) -> str:
         return "sorted"
     if m == "auto":
         return "xla" if pallas_enabled() else "sorted"
+    if m == "pallas":
+        _refuse_pallas_on_tpu()
     return m
 
 
@@ -105,6 +127,8 @@ def probe_for_join(sorted_hashes: jax.Array, probes: jax.Array,
     if m == "off" or (m == "auto" and not pallas_enabled()):
         lo, hi = xla_probe_ranges(sorted_hashes, probes)
         return lo.astype(jnp.int64), hi.astype(jnp.int64)
+    if m == "pallas":
+        _refuse_pallas_on_tpu()
     return probe_ranges(sorted_hashes, probes,
                         use_pallas=(m == "pallas"))
 
@@ -296,7 +320,7 @@ def _probe_pallas(keys, los, his, sh, probes, cap):
                   whole_rb, whole_rb],
         out_specs=[tile, tile],
         out_shape=[jax.ShapeDtypeStruct((n_tiles * T,), jnp.int32)] * 2,
-        interpret=not pallas_enabled(),
+        interpret=pallas_interpret(),
     )(home, fp, pr_hi, pr_lo, keys, los, his, sh_hi, sh_lo)
     return lo32[:Rp].astype(jnp.int64), hi32[:Rp].astype(jnp.int64)
 

@@ -12,7 +12,8 @@ Exactness: f32 matmul accumulation is integer-exact below 2^24, so
     partial <= TILE, total <= R) — counts dispatch to Pallas on TPU;
   * segment_sum_f32 matches XLA f32 summation to reordering — used for
     FLOAT aggregates where SQL float semantics already permit it;
-  * int64/decimal sums stay on the XLA path (exactness first).
+  * int64/decimal sums run the byte-limb kernel (_pallas_segsum_i64),
+    exact by construction.
 
 Group count G is padded to the 128-lane boundary; segment ids >= G are
 the caller's NULL/overflow slots and pad lanes simply accumulate zeros
@@ -23,6 +24,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -30,10 +32,16 @@ import numpy as np
 
 __all__ = ["segment_count", "segment_sum_f32", "segment_sum_i64",
            "pallas_enabled", "set_pallas_enabled", "xla_segment_sum",
-           "force_platform"]
+           "force_platform", "target_platform", "pallas_interpret"]
 
 _TILE = 1024
-_MAX_PALLAS_G = 8192  # above this the [TILE, G] one-hot exceeds VMEM budget
+# Largest group count the Pallas kernels take; above it the XLA scatter
+# runs. The [8, 128, Gp] one-hot must fit Mosaic's 16 MiB scoped VMEM:
+# the v5e compiler accepts the f32/count kernel up to Gp = 3584 and the
+# int64 limb kernel up to Gp = 4096, and refuses the next 128-lane
+# buckets (RESOURCE_EXHAUSTED ... vmem). 2048 leaves both a margin;
+# tests/test_chip_compile.py compiles both kernels at this cap.
+_MAX_PALLAS_G = 2048
 
 _enabled: bool | None = None  # None = auto (TPU backend only)
 
@@ -43,31 +51,35 @@ def set_pallas_enabled(v: bool | None) -> None:
     _enabled = v
 
 
-_forced_platform: str | None = None
+# thread-local: the server traces statements on several worker threads,
+# and one leaving its block must not un-pin another mid-trace
+_forced = threading.local()
 
 
 @contextlib.contextmanager
 def force_platform(p: str):
-    """Pin the Pallas target platform for the duration of a call. Mesh
-    fragments are traced while the executor glue has jax.default_device
+    """Pin the Pallas target platform for the duration of a call. Device
+    programs are traced while the executor glue has jax.default_device
     pinned to host CPU (utils/device.py host_eager), yet they execute on
-    the mesh's devices — the fragment runner wraps each dispatch in
-    force_platform(mesh_platform) so kernels pick the right mode."""
-    global _forced_platform
-    prev, _forced_platform = _forced_platform, p
+    the devices their arrays live on — both device tiers wrap each
+    dispatch in force_platform(that platform) so kernel choice follows
+    the arrays, not the glue's pin."""
+    prev = getattr(_forced, "platform", None)
+    _forced.platform = p
     try:
         yield
     finally:
-        _forced_platform = prev
+        _forced.platform = prev
 
 
-def _target_platform() -> str:
+def target_platform() -> str:
     """Platform the *current* computation lands on: an explicit
     force_platform() wins (mesh fragments), then the pinned default
     device (host-eager glue), then the default backend. The backend name
     alone is wrong in both pinned cases."""
-    if _forced_platform is not None:
-        return _forced_platform
+    forced = getattr(_forced, "platform", None)
+    if forced is not None:
+        return forced
     d = jax.config.jax_default_device
     if d is not None:
         return d.platform
@@ -80,7 +92,27 @@ def _target_platform() -> str:
 def pallas_enabled() -> bool:
     if _enabled is not None:
         return _enabled
-    return _target_platform() == "tpu"
+    return target_platform() == "tpu"
+
+
+def pallas_interpret() -> bool:
+    """The ``interpret=`` of every pallas_call in ops/. A TPU always
+    compiles the kernel: one the chip's compiler refuses raises, it is
+    never run interpreted and never handed to the XLA reference. On the
+    CPU a Pallas kernel is reached only when the caller asked for it
+    explicitly (set_pallas_enabled(True), probe mode 'pallas' — the
+    tier-1 tests), and there it interprets."""
+    from tidb_tpu.utils.device import note_placement
+
+    p = target_platform()
+    if p == "tpu":
+        note_placement("pallas", None, platform="tpu")
+        return False
+    if p == "cpu":
+        note_placement("pallas", None, platform="interpret")
+        return True
+    raise NotImplementedError(
+        f"the Pallas kernels target TPU; no lowering for platform {p!r}")
 
 
 def xla_segment_sum(vals: jax.Array, seg: jax.Array, G: int) -> jax.Array:
@@ -113,8 +145,6 @@ def _pallas_segsum_f32(vals: jax.Array, seg: jax.Array, G: int, Gp: int) -> jax.
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from jax._src.config import enable_x64
-
     vals2 = _pad_tile(vals.astype(jnp.float32), 0.0)
     seg2 = _pad_tile(seg.astype(jnp.int32), Gp)  # pad rows land off-range
     n_tiles = vals2.shape[0]
@@ -138,7 +168,7 @@ def _pallas_segsum_f32(vals: jax.Array, seg: jax.Array, G: int, Gp: int) -> jax.
     # trace the kernel with x64 OFF: the engine enables x64 globally
     # (decimals are scaled int64), but Mosaic can't legalize the i64
     # constants that leak into index maps / grid bookkeeping
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct((1, Gp), jnp.float32),
@@ -151,9 +181,7 @@ def _pallas_segsum_f32(vals: jax.Array, seg: jax.Array, G: int, Gp: int) -> jax.
             ],
             out_specs=pl.BlockSpec((1, Gp), lambda i: (0, 0),
                                    memory_space=pltpu.VMEM),
-            # off-TPU (tests force-enable) the interpreter runs the same
-            # kernel logic, so CPU CI covers the Pallas path too
-            interpret=_target_platform() != "tpu",
+            interpret=pallas_interpret(),
         )(vals2, seg2)
     return out[0, :G]
 
@@ -187,8 +215,6 @@ def _pallas_segsum_i64(vals: jax.Array, seg: jax.Array, G: int, Gp: int) -> jax.
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from jax._src.config import enable_x64
-
     u = jax.lax.bitcast_convert_type(vals.astype(jnp.int64), jnp.uint64)
     limbs = [
         ((u >> jnp.uint64(_LIMB_BITS * j)) & jnp.uint64(0xFF)).astype(jnp.int32)
@@ -214,7 +240,7 @@ def _pallas_segsum_i64(vals: jax.Array, seg: jax.Array, G: int, Gp: int) -> jax.
             part = jnp.sum(v[:, :, None] * onehot, axis=(0, 1))  # [Gp]
             out_ref[j, :] = out_ref[j, :] + part
 
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct((_N_LIMBS, Gp), jnp.int32),
@@ -228,7 +254,7 @@ def _pallas_segsum_i64(vals: jax.Array, seg: jax.Array, G: int, Gp: int) -> jax.
             ],
             out_specs=pl.BlockSpec((_N_LIMBS, Gp), lambda i: (0, 0),
                                    memory_space=pltpu.VMEM),
-            interpret=_target_platform() != "tpu",
+            interpret=pallas_interpret(),
         )(limbs2, seg2)
     # recombine: limb sums (int32, exact) widen to uint64, shift, add —
     # wraparound is exactly int64 addition's

@@ -50,8 +50,8 @@ from tidb_tpu.executor.aggregate import make_segment_kernel
 from tidb_tpu.executor.builder import peel_stages, scan_stages_for
 from tidb_tpu.executor.scan import make_pipeline_fn
 from tidb_tpu.expression.compiler import compile_predicate, eval_expr
-from tidb_tpu.parallel.distsql import merge_state, pmax_compat, repartition_by_key
-from tidb_tpu.parallel.mesh import dcn_axis, shard_axis, shard_map_compat
+from tidb_tpu.parallel.distsql import merge_state, pmax, repartition_by_key
+from tidb_tpu.parallel.mesh import dcn_axis, shard_axis
 from tidb_tpu.planner.physical import PHashAgg, PHashJoin, PScan
 from tidb_tpu.types import TypeKind
 
@@ -428,11 +428,11 @@ class _Compiler:
             capP = int(np.ceil(growths[g_pcomp] * p_base))
             if capP < pch.capacity:
                 pch, o = _compact_chunk(pch, capP)
-                ovfs.append((g_pcomp, pmax_compat(o, _AXES)))
+                ovfs.append((g_pcomp, pmax(o, _AXES)))
             capB = int(np.ceil(growths[g_bcomp] * b_base))
             if capB < bch.capacity:
                 bch, o = _compact_chunk(bch, capB)
-                ovfs.append((g_bcomp, pmax_compat(o, _AXES)))
+                ovfs.append((g_bcomp, pmax(o, _AXES)))
 
             p_outs = [eval_expr(k, pch) for k in probe_keys]
             b_outs = [eval_expr(k, bch) for k in build_keys]
@@ -518,7 +518,7 @@ class _Compiler:
             capJ = int(np.ceil(growth_j * Rp))
             # required-factor-minus-one, maxed over shards (0 = fits)
             factor = (total + capJ - 1) // capJ
-            ovfs.append((g_expand, pmax_compat(jnp.maximum(factor - 1, 0), _AXES)))
+            ovfs.append((g_expand, pmax(jnp.maximum(factor - 1, 0), _AXES)))
 
             valid_out, p_row, b_sorted_pos, k = tile_positions(
                 lo, cnt, cum, 0, capJ, Rp, Rb)
@@ -579,7 +579,7 @@ class _Compiler:
             capO = int(np.ceil(growths[g_ocomp] * o_base))
             if capO < result.capacity:
                 result, o = _compact_chunk(result, capO)
-                ovfs.append((g_ocomp, pmax_compat(o, _AXES)))
+                ovfs.append((g_ocomp, pmax(o, _AXES)))
             return result, ovfs
 
         return emit
@@ -726,7 +726,7 @@ class _Compiler:
             capI = int(np.ceil(growths[g_in] * in_base))
             if capI < chunk.capacity:
                 chunk, o = _compact_chunk(chunk, capI)
-                ovfs.append((g_in, pmax_compat(o, _AXES)))
+                ovfs.append((g_in, pmax(o, _AXES)))
             table = partial(chunk)  # local dedup before the exchange
             S = table["k0.d"].shape[0]
             capT = int(np.ceil(growths[g_tab] * tab_base))
@@ -734,7 +734,7 @@ class _Compiler:
                 # groups are dense in [0, n): slicing the slot arrays is
                 # free and shrinks everything the exchange must carry
                 factor = (table["n"] + capT - 1) // capT
-                ovfs.append((g_tab, pmax_compat(jnp.maximum(factor - 1, 0), _AXES)))
+                ovfs.append((g_tab, pmax(jnp.maximum(factor - 1, 0), _AXES)))
                 table = {k: (v if k == "n" else v[:capT])
                          for k, v in table.items()}
                 S = capT
@@ -840,7 +840,7 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
         # caches build_fn(growths) under (sig, growths, shapes, types)
         # via ShardCache.get_fragment; the closure carries the compiled
         # plan description only — every array arrives as an argument
-        return jax.jit(shard_map_compat(
+        return jax.jit(jax.shard_map(
             frag, mesh=mesh, in_specs=in_specs, out_specs=(out_spec, P()),
             # pallas_call outputs carry no vma metadata; the fragment's
             # out_specs are the authority here

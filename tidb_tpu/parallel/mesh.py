@@ -13,10 +13,6 @@ gRPC; here placement is a jax.sharding.Mesh. Two axes:
 A 1-D mesh (dcn=1) is the common case on a single slice.
 """
 
-# lint: module-disable=jit-hygiene -- shard_map_compat IS the wrapper
-# machinery: it forwards the caller's fn verbatim across jax versions;
-# closure/identity discipline is enforced at every call site instead
-
 from __future__ import annotations
 
 from typing import Optional, Sequence
@@ -25,26 +21,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-__all__ = ["make_mesh", "shard_axis", "dcn_axis", "shard_map_compat"]
+__all__ = ["make_mesh", "shard_axis", "dcn_axis"]
 
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions: new jax exposes it as
-    jax.shard_map(check_vma=...); 0.4.x has
-    jax.experimental.shard_map.shard_map(check_rep=...). Both flags
-    disable the same replication/vma verification, which pallas_call
-    outputs fail spuriously."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm
-
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-    except TypeError:  # very old/new experimental signature: no flag
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 shard_axis = "shard"
 dcn_axis = "dcn"

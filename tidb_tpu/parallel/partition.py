@@ -160,6 +160,7 @@ def shard_table(table, mesh: Mesh, columns: Optional[List[str]] = None,
             vbuf[p, :m] = v[s:e]
 
     from tidb_tpu.utils import dispatch as dsp
+    from tidb_tpu.utils.device import note_placement
 
     for name in names:
         buf, vbuf, _, _ = host_cols[name]
@@ -168,6 +169,7 @@ def shard_table(table, mesh: Mesh, columns: Optional[List[str]] = None,
         dsp.record(2, site="stage")
     sel = jax.device_put(live, spec)
     dsp.record(site="stage")
+    note_placement("shard", (data, valid, sel))
 
     return ShardedTable(
         mesh=mesh, n_parts=n_parts, rows_per_part=R, total_rows=n,

@@ -7,7 +7,8 @@ Endpoints:
                    over DCN and renders per-worker `worker` labels plus
                    the merged `worker="fleet"` view (unreachable
                    workers become error samples, never a failed scrape)
-    /status      - JSON: version, connections, schema version, uptime
+    /status      - JSON: version, platform/device_kind/count, connections,
+                   schema version, uptime
     /schema      - JSON: databases -> tables -> row counts
     /statements  - JSON: top-N statement digests by cumulative latency
                    (?top=N, default 50) from the statements-summary store
@@ -76,11 +77,13 @@ class StatusServer:
                             body = render_prometheus().encode()
                         ctype = "text/plain; version=0.0.4"
                     elif self.path == "/status":
+                        from tidb_tpu.utils.device import device_info
                         from tidb_tpu.utils.metrics import CONN_GAUGE
 
                         body = json.dumps({
                             "version": outer.version,
                             "status": "ok",
+                            **device_info(),
                             "connections": CONN_GAUGE.value(),
                             "schema_version": outer.catalog.schema_version,
                             "uptime_s": round(time.time() - outer.started, 1),

@@ -9,43 +9,79 @@ numpy generator in tpch.py is the fallback and the oracle.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import shlex
 import subprocess
 from typing import Optional
 
-__all__ = ["load_native", "native_orders_lineitem"]
+__all__ = ["load_native", "native_orders_lineitem", "load_error"]
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
+_NATIVE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "native"))
 _SRC = os.path.join(_NATIVE_DIR, "tpch_gen.cpp")
 _LIB = os.path.join(_NATIVE_DIR, "libtpchgen.so")
+# sha256 of the source the library on disk was built from; a checkout or
+# a copied tree keeps no trustworthy mtimes, so staleness is decided by
+# content. Neither file is committed (.gitignore).
+_LIB_HASH = _LIB + ".srchash"
 
 _lib = None
-_load_failed = False
+_load_error: Optional[str] = None
+
+
+def load_error() -> Optional[str]:
+    """Why load_native() returned None (None while it has not failed)."""
+    return _load_error
+
+
+def _source_hash() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _built_hash() -> Optional[str]:
+    try:
+        with open(_LIB_HASH) as f:
+            return f.read().strip()
+    except OSError:
+        return None
+
+
+def _build(src_hash: str) -> None:
+    """Compile with the Makefile's recipe (same CXX/CXXFLAGS defaults)
+    into a private file and rename it into place: several processes
+    (pytest workers) may build at once, and none may ever load a
+    half-written library."""
+    cxx = os.environ.get("CXX", "g++")
+    flags = shlex.split(os.environ.get(
+        "CXXFLAGS", "-O3 -fPIC -shared -std=c++17 -Wall"))
+    tmp = f"{_LIB}.{os.getpid()}.tmp"
+    try:
+        subprocess.run([cxx, *flags, "-o", tmp, _SRC],
+                       check=True, capture_output=True, timeout=300)
+        os.replace(tmp, _LIB)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    tmp = f"{_LIB_HASH}.{os.getpid()}.tmp"
+    with open(tmp, "w") as f:
+        f.write(src_hash + "\n")
+    os.replace(tmp, _LIB_HASH)
 
 
 def load_native() -> Optional[ctypes.CDLL]:
-    """Build (if stale) and load the generator library; None on failure."""
-    global _lib, _load_failed
+    """Build (when the library is absent or was built from another
+    source) and load the generator library; None on failure."""
+    global _lib, _load_error
     if _lib is not None:
         return _lib
-    if _load_failed:
+    if _load_error is not None:
         return None
     try:
-        if not os.path.exists(_SRC):
-            raise FileNotFoundError(_SRC)
-        if (not os.path.exists(_LIB)
-                or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
-            try:  # one build recipe: the Makefile (honors CXX/CXXFLAGS)
-                subprocess.run(
-                    ["make", "-C", _NATIVE_DIR, "libtpchgen.so"],
-                    check=True, capture_output=True, timeout=120,
-                )
-            except (FileNotFoundError, subprocess.CalledProcessError):
-                subprocess.run(  # make absent: the Makefile's default recipe
-                    ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-Wall",
-                     "-o", _LIB, _SRC],
-                    check=True, capture_output=True, timeout=120,
-                )
+        src_hash = _source_hash()
+        if not os.path.exists(_LIB) or _built_hash() != src_hash:
+            _build(src_hash)
         lib = ctypes.CDLL(_LIB)
         i64p = ctypes.POINTER(ctypes.c_int64)
         lib.tpch_sizes.argtypes = [ctypes.c_double, ctypes.c_uint64, i64p, i64p]
@@ -58,8 +94,8 @@ def load_native() -> Optional[ctypes.CDLL]:
         lib.tpch_gen.restype = None
         _lib = lib
         return _lib
-    except Exception:  # noqa: BLE001 — fall back to the numpy generator
-        _load_failed = True
+    except Exception as e:  # noqa: BLE001 — fall back to the numpy generator
+        _load_error = f"{type(e).__name__}: {e}"
         return None
 
 

@@ -14,6 +14,7 @@ already in device representation for bulk ingest.
 from __future__ import annotations
 
 import datetime
+import sys
 from typing import Dict, Optional
 
 import numpy as np
@@ -345,6 +346,13 @@ def load_tpch(catalog: Catalog, sf: float = 0.01, db: str = "test", seed: int = 
     if native is not False and not cluster_lineitem:
         done = _load_orders_lineitem_native(
             make_table, counts, sf, seed, npart, ns, nc)
+        if native is None:  # auto: say which generator made the data
+            from tidb_tpu.storage.native_gen import load_error
+
+            print("# TPC-H orders/lineitem generator: "
+                  + ("native (native/tpch_gen.cpp)" if done else
+                     f"numpy (native unavailable: {load_error()})"),
+                  file=sys.stderr)
         if done:
             return counts
         if native is True:
