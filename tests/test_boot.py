@@ -29,7 +29,8 @@ def test_boot_reports_its_device_on_status(capsys):
         assert status["count"] >= 1
         assert server.device == {k: status[k] for k in
                                  ("platform", "device_kind", "count")}
-        line = capsys.readouterr().err.strip().splitlines()[-1]
+        (line,) = [ln for ln in capsys.readouterr().err.splitlines()
+                   if ln.startswith("# device ")]
         assert "platform=cpu" in line and "device_kind=" in line \
             and f"devices={status['count']}" in line
     finally:
@@ -82,3 +83,43 @@ def test_compile_cache_directory(env_dir):
     cache_dir, min_secs = _cache_config(env_dir)
     assert cache_dir == (env_dir or os.path.join(ROOT, ".jax_cache"))
     assert min_secs <= 1.0
+
+
+def _run_smoke(args, cwd, extra_env=None):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", **(extra_env or {})}
+    return subprocess.run([sys.executable, "chip_smoke.py", *args], cwd=cwd,
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+
+
+def test_chip_smoke_alone_fails_and_prints_no_result(tmp_path):
+    """In a directory that holds the script and nothing else of the repo
+    it must fail without a result line."""
+    import shutil
+
+    shutil.copy(os.path.join(ROOT, "chip_smoke.py"), tmp_path)
+    out = _run_smoke([], str(tmp_path), {"PYTHONPATH": ""})
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+
+
+def test_chip_smoke_rehearsal_walks_every_phase_and_never_says_ok():
+    """The guide's first rehearsal as a test: SF0.01 on the CPU through
+    boot(), both connections, the transaction on the fused tier and the
+    read-back — and, without a TPU, never `"ok": true`, never exit 0."""
+    out = _run_smoke(["--rehearse"], ROOT,
+                     {"XLA_FLAGS": "--xla_force_host_platform_device_count=1"})
+    lines = [json.loads(ln) for ln in out.stdout.splitlines()
+             if ln.startswith("{")]
+    assert out.returncode != 0, out.stderr[-2000:]
+    assert lines and lines[-1]["ok"] is False and lines[-1]["rehearsal"], \
+        out.stderr[-2000:]
+    assert '"ok": true' not in out.stdout
+    phases = [ln.get("phase") for ln in lines]
+    for want in ("boot", "reference", "statement", "resident",
+                 "transaction", "read_back", "point_get", "summary"):
+        assert want in phases, (want, phases)
+    txn = next(ln for ln in lines if ln.get("phase") == "transaction")
+    assert set(txn["placement"]) >= {"stage", "fused"}
+    assert next(ln for ln in lines if ln.get("phase") == "point_get")[
+        "device_dispatches"] == {}

@@ -742,8 +742,9 @@ class TestSuppressionCountPinned:
     constant DELIBERATELY when adding/removing a suppression."""
 
     # ISSUE 14 added two: the ping health arm (protocol-conformance)
-    # and GroupTableStack's caller-supplied key (cache-key-completeness)
-    EXPECTED_SUPPRESSIONS = 28
+    # and GroupTableStack's caller-supplied key (cache-key-completeness);
+    # ISSUE 22 removed three with parallel/mesh.py's shard_map wrapper
+    EXPECTED_SUPPRESSIONS = 25
     # annotated-allowlist entries are the same drift class: a future
     # `# lifecycle:` on a real leak must move a pinned number
     EXPECTED_LIFECYCLE_ANNOTATIONS = 2

@@ -105,9 +105,11 @@ def boot(argv=None):
     if server.status_port is not None:
         print(f"# status port http://{server.host}:{server.status_port}"
               "/metrics /status /schema", file=sys.stderr)
-    print(f"# tidb_tpu server listening on {server.host}:{server.port} "
-          f"platform={info['platform']} device_kind={info['device_kind']} "
-          f"devices={info['count']} mesh={mesh_mode}", file=sys.stderr)
+    print(f"# device platform={info['platform']} "
+          f"device_kind={info['device_kind']} devices={info['count']} "
+          f"mesh={mesh_mode}", file=sys.stderr)
+    print(f"# tidb_tpu server listening on {server.host}:{server.port}",
+          file=sys.stderr)
     return server
 
 

@@ -41,6 +41,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tidb_tpu.errors import UnsupportedError
 from tidb_tpu.ops.segment_sum import (pallas_enabled, pallas_interpret,
@@ -138,7 +139,10 @@ MAX_PROBES = 32
 # searchsorted path
 MAX_CAPACITY = 1 << 19
 
-_EMPTY = jnp.int32(0x7FFFFFFF)
+# a numpy scalar, not a jax array: this module is first imported lazily,
+# possibly inside a shard_map trace, and a jax array made there would be
+# typed with that trace's mesh and refused under any other mesh
+_EMPTY = np.int32(0x7FFFFFFF)
 
 
 def _mix32(h: jax.Array, salt: int = 0) -> jax.Array:
