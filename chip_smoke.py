@@ -503,10 +503,11 @@ def _drive(args, server, boot_s) -> dict:
     _n, rows = b.query(
         f"select o_orderkey, o_totalprice from orders where o_orderkey = {key}")
     compare("read-back orders", rows, [(key, 600006.00)])
-    _n, rows = b.query("select l_linenumber, l_extendedprice from lineitem "
-                       f"where l_orderkey = {key} order by l_linenumber")
-    compare("read-back lineitem", rows,
-            [(i + 1, p / 1e2) for i, p in enumerate(prices)])
+    for i, p in enumerate(prices):  # by primary key: (orderkey, linenumber)
+        _n, rows = b.query(
+            "select l_linenumber, l_extendedprice from lineitem "
+            f"where l_orderkey = {key} and l_linenumber = {i + 1}")
+        compare("read-back lineitem", rows, [(i + 1, p / 1e2)])
     c0 = obs.counters()
     rows, q6_after = timed(b, Q["q6"][0])
     compare("Q6 after commit (conn B)", rows, in_txn_want)

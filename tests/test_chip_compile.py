@@ -148,36 +148,45 @@ def test_probe_mode_pallas_raises_typed_on_tpu(tpu_target):
     assert hp.resolve_mode("auto") == "xla"  # never picked automatically
 
 
-def test_join_build_probe_expand_compile(one_chip, tpu_target):
+@pytest.mark.parametrize("kernel", [
+    # any lax.sort with int64 keys costs the chip's compiler minutes
+    # (~200 s for this one): not tier-1, run it with -m slow
+    pytest.param("build_sort", marks=pytest.mark.slow),
+    "direct_index", "probe_direct", "probe_sorted", "expand",
+])
+def test_join_kernel_compiles(one_chip, tpu_target, kernel):
     """lineitem ⋈ orders at SF1: build_sort + direct index over the
     orders bucket, probe_count and expand_tiles over one served chunk.
     The probe side is CHUNK rows, not 1<<20: XLA:TPU takes ~70 s to
-    compile a flat 1<<20-row int64 cumsum (6 s at 65536), and any
-    lax.sort with int64 keys ~80-150 s whatever its length — findings
+    compile a flat 1<<20-row int64 cumsum (6 s at 65536) — findings
     recorded in ROADMAP S3, too slow to repeat per shape in tier-1."""
     s = _sds(one_chip)
     B, N = ORDERS_BUCKET, CHUNK
     i64, b = jnp.int64, jnp.bool_
     scal = s((), i64)
-    _compile(jk._build_sort, (s((B,), i64),), (s((B,), b),), s((B,), b),
-             (s((B,), i64),), (s((B,), b),), (scal,), (scal,), (scal,),
-             modes=("int",), hash_mode=False)
     rng_bucket = 1 << 21  # SF1 o_orderkey domain (1,500,000) bucketed
-    _compile(jk._build_direct_index, s((B,), i64), scal, scal,
-             rng_bucket=rng_bucket)
-    table = _like(jax.eval_shape(jk.no_table), one_chip)
-    for direct, firsts in ((True, s((rng_bucket + 1,), i64)),
-                           (False, s((2,), i64))):
+    if kernel == "build_sort":
+        _compile(jk._build_sort, (s((B,), i64),), (s((B,), b),), s((B,), b),
+                 (s((B,), i64),), (s((B,), b),), (scal,), (scal,), (scal,),
+                 modes=("int",), hash_mode=False)
+    elif kernel == "direct_index":
+        _compile(jk._build_direct_index, s((B,), i64), scal, scal,
+                 rng_bucket=rng_bucket)
+    elif kernel == "expand":
+        _compile(jk._expand_tiles, s((N,), i64), s((N,), i64), s((N,), i64),
+                 s((N,), i64), scal, (s((N,), i64),), (s((N,), b),),
+                 (s((B,), i64),), (s((B,), b),),
+                 n_tiles=1, tile_cap=N, build_cap=B, left=False,
+                 with_probe_row=False, with_build_pos=False)
+    else:
+        direct = kernel == "probe_direct"
+        firsts = s((rng_bucket + 1 if direct else 2,), i64)
+        table = _like(jax.eval_shape(jk.no_table), one_chip)
         _compile(jk._probe_count, s((B,), i64), scal,
                  (s((N,), i64),), (s((N,), b),), s((N,), b),
                  (scal,), (scal,), (scal,), firsts, scal, scal, *table,
                  modes=("int",), hash_mode=False, left_pad=False,
                  direct=direct, probe="sorted")
-    _compile(jk._expand_tiles, s((N,), i64), s((N,), i64), s((N,), i64),
-             s((N,), i64), scal,
-             (s((N,), i64),), (s((N,), b),), (s((B,), i64),), (s((B,), b),),
-             n_tiles=1, tile_cap=N, build_cap=B, left=False,
-             with_probe_row=False, with_build_pos=False)
 
 
 # -- top-k ------------------------------------------------------------------
