@@ -30,3 +30,9 @@ def devices8():
     devs = jax.devices()
     assert len(devs) >= 8, f"expected 8 virtual devices, got {len(devs)}"
     return devs[:8]
+
+
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers", "slow: minutes-long cases (chip-compiler sorts); "
+        "tier-1 runs -m 'not slow'")
