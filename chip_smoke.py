@@ -8,11 +8,16 @@ answers correctly on the TPU.
                                        never "ok": true, never exit code 0
 
 Statements: TPC-H Q6 and Q1 and the lineitem-orders join statement on the
-mesh tier, a transaction (insert, aggregate, ORDER BY LIMIT) on the fused
-segment-store tier with read-back on the other connection, and a point
-get. TPC-H Q3 and Q18 have a numpy reference here too but are run only
-with ``--also q3,q18``: their first execution does not finish inside the
-smoke's time limit on a v5e (ROADMAP S3).
+mesh tier (the two hand-written fragments of parallel/distsql.py), TPC-H
+Q18's inner aggregate (GROUP BY l_orderkey, 1.5M groups) through the
+general fragment compiler (parallel/fragment.py: sort-reduce, partial
+groups repartitioned over all_to_all), a transaction (insert, aggregate,
+ORDER BY LIMIT) on the fused segment-store tier with read-back on the
+other connection, and a point get. The whole of TPC-H Q3 and Q18 is NOT
+covered: the general fragments their joins compile to (20+ whole-table
+sorts and scans in one program) do not finish their first execution
+inside the smoke's time limit on a v5e (ROADMAP S3;
+tests/test_chip_compile.py pins it).
 
 One process. The server is booted through ``tidb_tpu.__main__.boot`` with
 the default configuration (``--mesh auto``, status port on) and TPC-H SF1
@@ -67,7 +72,7 @@ class Ref:
 
     def __init__(self, catalog, db="test"):
         self.t = {n: catalog.table(db, n)
-                  for n in ("lineitem", "orders", "customer")}
+                  for n in ("lineitem", "orders")}
         for name, tab in self.t.items():
             check(bool(tab.live_mask(0, tab.n).all()),
                   f"{name}: freshly loaded rows must all be live")
@@ -81,22 +86,10 @@ class Ref:
         return self.t[table].dicts[name].decode(
             codes, np.ones(len(codes), dtype=np.bool_))
 
-    def code_of(self, table, name, value):
-        codes = np.unique(self.col(table, name))
-        for c, s in zip(codes, self.decode(table, name, codes)):
-            if s == value:
-                return c
-        raise SmokeFailure(f"{value!r} not in {table}.{name}")
-
     @staticmethod
     def days(iso):
         return (datetime.date.fromisoformat(iso)
                 - datetime.date(1970, 1, 1)).days
-
-    @staticmethod
-    def iso(days):
-        return (datetime.date(1970, 1, 1)
-                + datetime.timedelta(days=int(days))).isoformat()
 
     # -- statements ---------------------------------------------------------
 
@@ -146,49 +139,12 @@ class Ref:
         return [(int(m.sum()),
                  int(self.col("lineitem", "l_quantity")[m].sum()) / 1e2)]
 
-    def q3(self):
-        seg = self.code_of("customer", "c_mktsegment", "BUILDING")
-        ck = self.col("customer", "c_custkey")[
-            self.col("customer", "c_mktsegment") == seg]
-        day = self.days("1995-03-15")
-        o_sel = (np.isin(self.col("orders", "o_custkey"), ck)
-                 & (self.col("orders", "o_orderdate") < day))
-        li = self._order_index()[self.col("lineitem", "l_orderkey")]
-        m = ((li >= 0) & o_sel[np.maximum(li, 0)]
-             & (self.col("lineitem", "l_shipdate") > day))
-        oi = li[m]
-        rev = (self.col("lineitem", "l_extendedprice")[m]
-               * (100 - self.col("lineitem", "l_discount")[m]))
-        order = np.argsort(oi, kind="stable")
-        oi, rev = oi[order], rev[order]
-        starts = np.flatnonzero(np.r_[True, oi[1:] != oi[:-1]])
-        g_oi, g_rev = oi[starts], np.add.reduceat(rev, starts)
-        od = self.col("orders", "o_orderdate")[g_oi]
-        top = np.lexsort((od, -g_rev))[:10]
-        return [(int(self.col("orders", "o_orderkey")[g_oi[i]]),
-                 int(g_rev[i]) / 1e4, self.iso(od[i]),
-                 int(self.col("orders", "o_shippriority")[g_oi[i]]))
-                for i in top]
-
-    def q18(self):
+    def q18_inner(self):
         lk = self.col("lineitem", "l_orderkey")
-        sumq = np.bincount(lk, weights=self.col("lineitem", "l_quantity")
-                           .astype(np.float64)).astype(np.int64)
-        idx = self._order_index()
+        sumq = np.zeros(int(lk.max()) + 1, dtype=np.int64)
+        np.add.at(sumq, lk, self.col("lineitem", "l_quantity"))
         big = np.flatnonzero(sumq > 300 * 100)
-        big = big[idx[big] >= 0]
-        oi = idx[big]
-        tp = self.col("orders", "o_totalprice")[oi]
-        od = self.col("orders", "o_orderdate")[oi]
-        cust = self.col("orders", "o_custkey")[oi]
-        ck = self.col("customer", "c_custkey")
-        cidx = np.full(int(ck.max()) + 1, -1, dtype=np.int64)
-        cidx[ck] = np.arange(len(ck))
-        names = self.decode("customer", "c_name",
-                            self.col("customer", "c_name")[cidx[cust]])
-        top = np.lexsort((od, -tp))[:100]
-        return [(names[i], int(cust[i]), int(big[i]), self.iso(od[i]),
-                 int(tp[i]) / 1e2, int(sumq[big[i]]) / 1e2) for i in top]
+        return [(int(k), int(sumq[k]) / 1e2) for k in big]
 
     def top_prices(self, k):
         ext = self.col("lineitem", "l_extendedprice")
@@ -204,6 +160,13 @@ Q6_SHAPED = ("select sum(l_extendedprice * l_discount) as revenue "
              "from lineitem where l_shipdate >= date '1994-01-01' "
              "and l_shipdate < date '1995-01-01' "
              "and l_discount between 0.05 and 0.07 and l_quantity < 24")
+# TPC-H Q18's inner aggregate: one group per order (1.5M groups at SF1)
+# is no segment aggregation — the general fragment compiler's generic
+# path takes it (per-shard sort-reduce, partial groups repartitioned by
+# key over all_to_all, merged where they land)
+Q18_INNER_SQL = ("select l_orderkey, sum(l_quantity) as q from lineitem "
+                 "group by l_orderkey having sum(l_quantity) > 300 "
+                 "order by l_orderkey")
 TOPN_SQL = ("select l_extendedprice, l_orderkey from lineitem "
             "order by l_extendedprice desc limit 10")
 
@@ -236,12 +199,14 @@ class Observer:
         mon.register_event_duration_secs_listener(on_duration)
 
     def counters(self) -> dict:
-        from tidb_tpu.utils.metrics import DISPATCH_TOTAL
+        from tidb_tpu.utils.metrics import DISPATCH_TOTAL, FRAGMENT_DISPATCH
 
         d = {"compiles": self.compiles, "compile_s": self.compile_s,
              "cache_hits": self.cache_hits, "cache_misses": self.cache_misses}
         for labels, v in DISPATCH_TOTAL.samples():
             d["dispatch:" + labels.get("site", "?")] = v
+        for labels, v in FRAGMENT_DISPATCH.samples():
+            d["fragment:" + labels.get("kind", "?")] = v
         return d
 
     @staticmethod
@@ -310,17 +275,15 @@ def resident_platforms(server) -> dict:
         cache = getattr(sess, "_shard_cache", None)
         if cache is None:
             continue
-        for held, _ver, _enc, st in list(cache._cache.values()):
+        for held, st in cache.resident():
             res["sharded_tables"][f"conn{cid}:{held.schema.name}"] = {
                 "platforms": platforms((st.data, st.valid, st.sel)),
                 "bytes": int(sum(a.nbytes for a in jax.tree_util.tree_leaves(
                     (st.data, st.valid, st.sel)))),
                 "rows_per_part": st.rows_per_part, "n_parts": st.n_parts}
-    with DEVICE_CACHE._lock:
-        entries = list(DEVICE_CACHE._entries.items())
-    for (_tid, tag), e in entries:
-        res["device_cache"][f"{e['table'].schema.name}:{tag[0]}"] = {
-            "platforms": platforms(e["chunks"]), "bytes": e["nbytes"]}
+    for table, tag, chunks, nbytes in DEVICE_CACHE.resident():
+        res["device_cache"][f"{table.schema.name}:{tag[0]}"] = {
+            "platforms": platforms(chunks), "bytes": nbytes}
     return res
 
 
@@ -360,8 +323,7 @@ def boot_argv(args) -> list:
     """The default configuration (--mesh auto, status port on) with
     TPC-H preloaded; the rehearsal only shrinks the data and names the
     CPU explicitly."""
-    argv = ["--load-tpch", "0.01" if args.rehearse else "1",
-            "--tpch-seed", str(args.seed), "--port", "0",
+    argv = ["--load-tpch", "0.01" if args.rehearse else "1", "--port", "0",
             "--status-port", "0"]
     if args.rehearse:
         argv += ["--device", "cpu"]
@@ -371,7 +333,9 @@ def boot_argv(args) -> list:
 def run(args, drive) -> dict:
     """Boot the server in this process, drive it, stop it."""
     from tidb_tpu.__main__ import boot  # nothing of jax is imported before
+    from tidb_tpu.utils.device import track_placement
 
+    track_placement()
     t0 = time.perf_counter()
     server = boot(boot_argv(args))  # raises without a device
     boot_s = time.perf_counter() - t0
@@ -413,8 +377,8 @@ def _drive(args, server, boot_s) -> dict:
     obs = Observer()
     t0 = time.perf_counter()
     ref = Ref(server.catalog)
-    expected = {"q6": ref.q6(), "q1": ref.q1(), "join": ref.join()}
-    expected.update({n: getattr(ref, n)() for n in args.also})
+    expected = {"q6": ref.q6(), "q1": ref.q1(), "join": ref.join(),
+                "q18_inner": ref.q18_inner()}
     base_top = ref.top_prices(10)
     emit(phase="reference", seconds=round(time.perf_counter() - t0, 1),
          q6=expected["q6"], join=expected["join"])
@@ -426,8 +390,8 @@ def _drive(args, server, boot_s) -> dict:
         # the CPU rehearsal has to ask for it to walk the same path
         for c in (a, b):
             c.query("set tidb_device_engine_mode = 'force'")
-    stmts = [("q6", Q["q6"][0]), ("q1", Q["q1"][0]), ("join", JOIN_SQL)]
-    stmts += [(n, Q[n][0]) for n in args.also]
+    stmts = [("q6", Q["q6"][0]), ("q1", Q["q1"][0]), ("join", JOIN_SQL),
+             ("q18_inner", Q18_INNER_SQL)]
     classic = {}
 
     # 1-3: the analytic statements, mesh tier, connection A cold then warm
@@ -444,6 +408,10 @@ def _drive(args, server, boot_s) -> dict:
              cold_s=round(cold, 3), warm_s=round(warm, 3),
              cold=obs.delta(c0, c1), warm=obs.delta(c1, c2), placement=pd)
         check_placement(want, name, pd, need=("fragment",))
+        if name == "q18_inner":
+            check("fragment:general_generic" in obs.delta(c1, c2),
+                  "Q18's inner aggregate did not run as a general fragment "
+                  f"(parallel/fragment.py): {obs.delta(c1, c2)}")
         if name == "q6":
             emit(phase="hbm", after="connection A first analytic statement",
                  hbm=hbm())
@@ -567,8 +535,8 @@ def _drive_mesh4(args, server, boot_s) -> dict:
          mesh=str(dict(server.mesh.shape)), hbm=hbm())
     obs = Observer()
     ref = Ref(server.catalog)
-    stmts = [("q1", Q["q1"][0], ref.q1()), ("join", JOIN_SQL, ref.join())]
-    stmts += [(n, Q[n][0], getattr(ref, n)()) for n in args.also]
+    stmts = [("q1", Q["q1"][0], ref.q1()), ("join", JOIN_SQL, ref.join()),
+             ("q18_inner", Q18_INNER_SQL, ref.q18_inner())]
     c = Client(server.host, server.port, db="test", timeout=STMT_TIMEOUT_S)
     got4 = {}
     if args.rehearse:
@@ -580,14 +548,18 @@ def _drive_mesh4(args, server, boot_s) -> dict:
         c1 = obs.counters()
         rows, warm = timed(c, sql)
         got4[name] = rows
+        warm_d = obs.delta(c1, obs.counters())
         emit(phase="statement", name=name, mesh=f"1x{n_shards}",
              cold_s=round(cold, 3), warm_s=round(warm, 3),
-             cold=obs.delta(c0, c1), warm=obs.delta(c1, obs.counters()))
+             cold=obs.delta(c0, c1), warm=warm_d)
+        if name == "q18_inner":
+            check("fragment:general_generic" in warm_d,
+                  f"Q18's inner aggregate ran no general fragment: {warm_d}")
 
     # placement: a quarter of every column on each device, HBM balanced
     (sess,) = server.sessions.values()
     shares = {}
-    for held, _v, _e, st in sess._shard_cache._cache.values():
+    for held, st in sess._shard_cache.resident():
         for col, arr in list(st.data.items()) + [("<sel>", st.sel)]:
             per = {}
             for sh in arr.addressable_shards:
@@ -634,13 +606,6 @@ def main(argv=None) -> int:
                     help="4: run only the mesh phase on four chips")
     ap.add_argument("--rehearse", action="store_true",
                     help="SF0.01 on the CPU; ends with \"ok\": false")
-    ap.add_argument("--seed", type=int, default=7,
-                    help="seed of the generated TPC-H data")
-    ap.add_argument("--also", type=lambda v: [n for n in v.split(",") if n],
-                    default=[], metavar="q3,q18",
-                    help="more TPC-H statements (reference: q3, q18). Not "
-                         "in the default list: on a v5e their first "
-                         "execution outlasts the smoke's 1200 s (ROADMAP S3)")
     args = ap.parse_args(argv)
     try:
         dev = run(args, _drive_mesh4 if args.chips == 4 else _drive)

@@ -58,6 +58,33 @@ def test_mesh_auto_that_cannot_build_a_mesh_exits_nonzero(monkeypatch, capsys):
         server.stop()
 
 
+def test_accelerator_without_host_backend_stops_the_boot(monkeypatch, capsys):
+    """JAX_PLATFORMS=tpu initialises no cpu backend, and the host glue
+    needs one: start-up says so once instead of every statement failing
+    inside host_eager()."""
+    import jax
+
+    from tidb_tpu.utils import device
+
+    class Chip:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+    def no_cpu(backend=None, **_k):
+        raise RuntimeError(f"Unknown backend {backend}")
+
+    for name in ("_probed", "_cpu_device", "_accel_device"):
+        monkeypatch.setattr(device, name, getattr(device, name))  # restored
+    device._probed = False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [Chip()])
+    monkeypatch.setattr(jax, "local_devices", no_cpu)
+    argv = ["--port", "0", "--status-port", "-1", "--mesh", "none"]
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS must include cpu"):
+        entry.boot(argv)
+    assert entry.main(argv) != 0
+    assert "JAX_PLATFORMS must include cpu" in capsys.readouterr().err
+
+
 def _cache_config(env_dir):
     env = {k: v for k, v in os.environ.items()
            if k != "JAX_COMPILATION_CACHE_DIR"}

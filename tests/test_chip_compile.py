@@ -25,6 +25,7 @@ from tidb_tpu.ops import hash_probe as hp
 from tidb_tpu.ops import join_kernels as jk
 from tidb_tpu.ops import segment_sum as ss
 from tidb_tpu.ops import topk as tk
+from tidb_tpu.utils.device import force_platform
 
 R = 1 << 20                  # a packed scan batch (16 segments of 65536)
 CHUNK = 1 << 16              # tidb_max_chunk_size: the served path's chunk
@@ -61,7 +62,7 @@ def tpu_target(topo):
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    with ss.force_platform("tpu"):
+    with force_platform("tpu"):
         yield
     jax.config.update("jax_enable_compilation_cache", prev)
     cc.reset_cache()
@@ -156,12 +157,12 @@ def test_probe_mode_pallas_raises_typed_on_tpu(tpu_target):
 ])
 def test_join_kernel_compiles(one_chip, tpu_target, kernel):
     """lineitem ⋈ orders at SF1: build_sort + direct index over the
-    orders bucket, probe_count and expand_tiles over one served chunk.
-    The probe side is CHUNK rows, not 1<<20: XLA:TPU takes ~70 s to
-    compile a flat 1<<20-row int64 cumsum (6 s at 65536) — findings
-    recorded in ROADMAP S3, too slow to repeat per shape in tier-1."""
+    orders bucket, probe_count and expand_tiles over a packed 1<<20-row
+    probe batch. Seconds each since the prefix sums are blocked
+    (ops/prefix.py): with a flat jnp.cumsum the direct index alone took
+    the chip's compiler 48 s."""
     s = _sds(one_chip)
-    B, N = ORDERS_BUCKET, CHUNK
+    B, N = ORDERS_BUCKET, R
     i64, b = jnp.int64, jnp.bool_
     scal = s((), i64)
     rng_bucket = 1 << 21  # SF1 o_orderkey domain (1,500,000) bucketed
@@ -187,6 +188,22 @@ def test_join_kernel_compiles(one_chip, tpu_target, kernel):
                  (scal,), (scal,), (scal,), firsts, scal, scal, *table,
                  modes=("int",), hash_mode=False, left_pad=False,
                  direct=direct, probe="sorted")
+
+
+# -- prefix sums ------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", [jnp.int64, jnp.int32], ids=["i64", "i32"])
+def test_prefix_cumsum_compiles_at_whole_table_length(one_chip, tpu_target,
+                                                      dtype):
+    """ops/prefix.cumsum over a whole SF1 lineitem column: seconds, where
+    the flat jnp.cumsum costs the chip's compiler minutes."""
+    import time
+
+    from tidb_tpu.ops import prefix
+
+    t0 = time.perf_counter()
+    _compile(jax.jit(prefix.cumsum), _sds(one_chip)((LINEITEM_SF1,), dtype))
+    assert time.perf_counter() - t0 < 60
 
 
 # -- top-k ------------------------------------------------------------------
@@ -283,7 +300,7 @@ def test_fused_scan_agg_program_compiles(one_chip, tpu_target, tiny_tpch):
 
     pl.FusedScanAggExec._staged_chunks = spy_chunks
     try:
-        with ss.force_platform("cpu"), \
+        with force_platform("cpu"), \
                 _capture(pl, "_make_fused_segment_fn") as made:
             s.query(Q["q6"][0])
     finally:
@@ -353,6 +370,137 @@ def test_mesh_q1_fragment_compiles(topo, tpu_target, tiny_tpch, n_dev):
     assert "tpu_custom_call" in text
     if n_dev > 1:
         assert "all-reduce" in text
+
+
+# -- general fragments (parallel/fragment.py compile_fragment) ---------------
+
+def _general_fragments(catalog, sql):
+    """The compile_fragment programs the engine runs for `sql` on a mesh:
+    [(FragmentProgram, argument shapes, growths, probe mode)], captured
+    where DistFragmentExec dispatches them (after its capacity retries,
+    so the growths are the ones that hold at this data size)."""
+    from tidb_tpu.parallel import executor as pe
+    from tidb_tpu.parallel import make_mesh
+    from tidb_tpu.session import Session
+
+    s = Session(catalog=catalog, mesh=make_mesh(devices=jax.devices()[:1]))
+    # a one-device CPU mesh routes joins to the host engine unless asked
+    s.execute("set tidb_device_engine_mode = 'force'")
+    got = []
+    real = pe.DistFragmentExec._dispatch_retry
+
+    def spy(self, prog, args, shapes_sig, types_sig, growths):
+        out, growths = real(self, prog, args, shapes_sig, types_sig, growths)
+        got.append((prog, jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), args),
+            growths, getattr(self.ctx, "join_probe_mode", None)))
+        return out, growths
+
+    pe.DistFragmentExec._dispatch_retry = spy
+    try:
+        with force_platform("cpu"):
+            s.query(sql)
+    finally:
+        pe.DistFragmentExec._dispatch_retry = real
+    assert got, "the statement did not take a compile_fragment program"
+    return got
+
+
+def _described_fragment(topo, prog, shapes, growths, probe_mode, n_dev=1):
+    """(jitted fragment, arguments) of a captured program on a 1 x n_dev
+    mesh of the described chips: the captured [1, R] sources become
+    [n_dev, R / n_dev]."""
+    from tidb_tpu.parallel import make_mesh
+    from tidb_tpu.parallel.fragment import _SPEC, compile_fragment
+
+    mesh = make_mesh(devices=topo.devices[:n_dev])
+    again = compile_fragment(prog.agg, mesh, n_dev, topn=prog.topn)
+    specs = ([_SPEC, _SPEC, _SPEC, P()] * len(prog.sources)
+             + [P()] * 3 * len(prog.broadcasts))
+
+    def place(a, spec):
+        shape = a.shape
+        if spec is _SPEC:
+            shape = (n_dev, -(-shape[1] // n_dev))
+        return jax.ShapeDtypeStruct(shape, a.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    args = [jax.tree_util.tree_map(lambda a, sp=sp: place(a, sp), sh)
+            for sh, sp in zip(shapes, specs)]
+    return again.build_fn(growths, probe_mode=probe_mode), args
+
+
+def _big_sorts_and_scans(jaxpr, rows=CHUNK):
+    """lax.sort and cumsum equations over at least `rows` rows, nested
+    jaxprs included."""
+    n = 0
+    for e in jaxpr.eqns:
+        if e.primitive.name in ("sort", "cumsum") \
+                and e.invars[0].aval.shape[-1] >= rows:
+            n += 1
+        for v in e.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                n += _big_sorts_and_scans(sub, rows)
+    return n
+
+
+# What one such equation costs the chip's compiler (sandbox, libtpu
+# 0.0.34): a lax.sort of >= 65536 rows 30-150 s whatever its key width, a
+# flat int64 cumsum 16 s at 65536 rows and 81 s at 1<<20. A statement
+# whose first execution must fit a 1200 s smoke can afford a handful.
+SORT_SCAN_BUDGET = 6
+
+
+@pytest.mark.xfail(strict=True, reason="ROADMAP S3: Q3's and Q18's general "
+                   "fragments hold 20+ whole-table sorts and flat cumsums; "
+                   "their first execution outlasts the smoke's 1200 s")
+@pytest.mark.parametrize("q", ["q3", "q18"])
+def test_general_fragment_fits_a_cold_statement(topo, tpu_target, tiny_tpch, q):
+    """Strict: when S3 brings the programs under the budget, this turns
+    green and Q3/Q18 belong in chip_smoke.py's statement list."""
+    from tidb_tpu.storage.tpch_queries import Q
+
+    counts = []
+    for prog, shapes, growths, mode in _general_fragments(tiny_tpch, Q[q][0]):
+        fn, args = _described_fragment(topo, prog, shapes, growths, mode)
+        counts.append(_big_sorts_and_scans(
+            jax.make_jaxpr(fn)(*args).jaxpr, rows=1))
+    assert sum(counts) <= SORT_SCAN_BUDGET, counts
+
+
+@pytest.fixture(scope="module")
+def sf1_tpch():
+    from tidb_tpu.storage.catalog import Catalog
+    from tidb_tpu.storage.tpch import load_tpch
+
+    catalog = Catalog()
+    load_tpch(catalog, sf=1.0)
+    return catalog
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("stmt,n_dev", [
+    ("q18_inner", 1), ("q18_inner", 4), ("q3", 1), ("q18", 1)])
+def test_general_fragment_compiles_at_sf1(topo, tpu_target, sf1_tpch, stmt,
+                                          n_dev):
+    """The mesh tier's compile_fragment programs as planned at SF1,
+    compiled for the described chip(s) at the shapes and capacities the
+    SF1 run settled on: chip_smoke.py's general-fragment statement (Q18's
+    inner aggregate, one chip and four), and the whole of Q3 and Q18,
+    which the smoke leaves out. The compiler accepts them all; it needs
+    minutes for each (the census above says why), so this is not tier-1:
+    run it with -m slow."""
+    from chip_smoke import Q18_INNER_SQL
+    from tidb_tpu.storage.tpch_queries import Q
+
+    sql = Q18_INNER_SQL if stmt == "q18_inner" else Q[stmt][0]
+    for prog, shapes, growths, mode in _general_fragments(sf1_tpch, sql):
+        fn, args = _described_fragment(topo, prog, shapes, growths, mode,
+                                       n_dev)
+        text = _compile(fn, *args).as_text()
+        if n_dev > 1:
+            assert "all-to-all" in text
 
 
 def _collective(topo, fn, dtype):

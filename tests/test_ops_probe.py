@@ -101,7 +101,7 @@ class TestModeResolution:
         assert hp.resolve_mode("pallas") == "pallas"
 
     def test_resolve_mode_tracks_forced_platform(self):
-        from tidb_tpu.ops.segment_sum import force_platform
+        from tidb_tpu.utils.device import force_platform
 
         with force_platform("tpu"):
             assert hp.resolve_mode("auto") == "xla"

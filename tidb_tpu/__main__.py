@@ -1,7 +1,7 @@
 """tidb-server equivalent: boot the MySQL-protocol server from the CLI.
 
     python -m tidb_tpu [--host H] [--port P] [--config file.toml]
-                       [--mesh {auto,none}] [--load-tpch SF] [--tpch-seed N]
+                       [--mesh {auto,none}] [--load-tpch SF]
                        [--root-password PW]
 
 Ref: tidb-server/main.go (flag parsing -> config merge -> bootstrap ->
@@ -25,8 +25,6 @@ def parse_args(argv):
                     help="auto: shard tables over all visible devices")
     ap.add_argument("--load-tpch", type=float, default=None, metavar="SF",
                     help="preload TPC-H tables at scale factor SF")
-    ap.add_argument("--tpch-seed", type=int, default=7,
-                    help="seed of the generated TPC-H data")
     ap.add_argument("--root-password", default=None,
                     help="set the root account password at boot")
     ap.add_argument("--plugin-modules", default=None,
@@ -96,7 +94,7 @@ def boot(argv=None):
     if sf:
         from tidb_tpu.storage.tpch import load_tpch
 
-        counts = load_tpch(catalog, sf=float(sf), seed=args.tpch_seed)
+        counts = load_tpch(catalog, sf=float(sf))
         print(f"# loaded TPC-H sf={sf}: {counts}", file=sys.stderr)
 
     server = Server(catalog=catalog, host=host, port=port, mesh=mesh,

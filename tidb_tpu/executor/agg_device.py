@@ -44,6 +44,7 @@ import numpy as np
 
 from tidb_tpu.chunk.chunk import Chunk
 from tidb_tpu.expression.compiler import eval_expr
+from tidb_tpu.ops import prefix
 from tidb_tpu.planner.logical import AggSpec
 from tidb_tpu.types import TypeKind
 from tidb_tpu.utils.jitcache import cached_jit
@@ -129,7 +130,7 @@ def _sort_reduce(kbits: List[jax.Array], kvalids: List[jax.Array],
     for b, v in zip(s_kbits, s_kvalids):
         diff = diff | (b != jnp.roll(b, 1)) | (v != jnp.roll(v, 1))
     newseg = s_live & ((idx == 0) | diff)
-    seg = jnp.clip(jnp.cumsum(newseg.astype(jnp.int64)) - 1, 0, R - 1)
+    seg = jnp.clip(prefix.cumsum(newseg.astype(jnp.int64)) - 1, 0, R - 1)
     ngroups = jnp.sum(newseg.astype(jnp.int64))
 
     # representative key values per group, scattered from boundary rows

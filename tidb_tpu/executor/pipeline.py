@@ -66,6 +66,7 @@ from tidb_tpu.executor.aggregate import HashAggExec, make_segment_kernel
 from tidb_tpu.executor.base import ExecContext, Executor, raise_if_cancelled
 from tidb_tpu.executor.join import HashJoinExec
 from tidb_tpu.ops import join_kernels as jk
+from tidb_tpu.ops import prefix
 from tidb_tpu.utils.device import device_tier, note_placement
 from tidb_tpu.utils.jitcache import cached_jit
 from tidb_tpu.utils.memory import QueryOOMError
@@ -154,6 +155,13 @@ class DeviceBufferCache:
                 _k, ev = self._entries.popitem(last=False)
                 self._bytes -= ev["nbytes"]
                 self._count("evict")
+
+    def resident(self) -> list:
+        """Snapshot of the cached device buffers:
+        [(table, tag, chunks, nbytes)]."""
+        with self._lock:
+            return [(e["table"], key[1], e["chunks"], e["nbytes"])
+                    for key, e in self._entries.items()]
 
     def on_schema_change(self) -> None:
         """Eager clear on any catalog.schema_version bump (DDL) — the
@@ -871,7 +879,7 @@ def _make_fused_probe_fn(stages, col_types, key_irs, modes, probe_uids,
             # slot; the pad slot carries NULL build payload (the
             # classic probe's left_pad arithmetic, traced here)
             count = jnp.where(ch.sel, jnp.maximum(count, 1), 0)
-        cum = jnp.cumsum(count)
+        cum = prefix.cumsum(count)
         total = cum[-1]
         R = packed.shape[0]
         B = sorted_keys.shape[0]

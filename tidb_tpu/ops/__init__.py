@@ -10,7 +10,6 @@ records the microbenchmark that justifies the default.
 """
 
 from tidb_tpu.ops.segment_sum import (
-    force_platform,
     pallas_enabled,
     segment_count,
     segment_sum_f32,
@@ -19,5 +18,4 @@ from tidb_tpu.ops.segment_sum import (
 )
 
 __all__ = ["segment_count", "segment_sum_f32", "segment_sum_i64",
-           "pallas_enabled",
-           "set_pallas_enabled", "force_platform"]
+           "pallas_enabled", "set_pallas_enabled"]

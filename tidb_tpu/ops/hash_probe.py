@@ -44,8 +44,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from tidb_tpu.errors import UnsupportedError
-from tidb_tpu.ops.segment_sum import (pallas_enabled, pallas_interpret,
-                                      target_platform)
+from tidb_tpu.ops.segment_sum import pallas_enabled, pallas_interpret
+from tidb_tpu.utils.device import target_platform
 
 __all__ = ["probe_ranges", "xla_probe_ranges", "probe_for_join",
            "set_mode", "resolve_mode", "table_capacity", "MAX_CAPACITY"]

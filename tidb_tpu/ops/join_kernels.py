@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tidb_tpu.ops import prefix
 from tidb_tpu.utils import dispatch
 from tidb_tpu.utils.hashutil import SM_ADD, SM_MUL1, SM_MUL2
 
@@ -200,7 +201,7 @@ def _build_direct_index(sorted_keys, n_build, lo, rng_bucket):
     # firsts[i] = first sorted position with key >= lo + i; probes then
     # resolve in O(1) gathers instead of O(log B) dependent rounds
     return jnp.concatenate([jnp.zeros(1, dtype=jnp.int64),
-                            jnp.cumsum(counts[:rng_bucket])])
+                            prefix.cumsum(counts[:rng_bucket])])
 
 
 def build_direct_index(sorted_keys, n_build, lo, rng_bucket: int):
@@ -327,7 +328,7 @@ def _probe_count(sorted_keys, n_build, key_datas, key_valids, sel,
         # unfiltered LEFT JOIN: every live probe row emits >= 1 slot; the
         # slot beyond real_count carries NULL build payload
         count = jnp.where(sel, jnp.maximum(count, 1), 0)
-    cum = jnp.cumsum(count)
+    cum = prefix.cumsum(count)
     return start, count, real_count, cum, cum[-1], ok, matched
 
 
@@ -383,7 +384,7 @@ def tile_positions(start, count, cum, w0, n_slots: int,
     in_win = (bound >= 1) & (bound <= n_slots - 1)
     marks = jnp.zeros(n_slots + 1, dtype=jnp.int64).at[
         jnp.where(in_win, bound, n_slots)].add(1, mode="drop")
-    probe_row = jnp.clip(row0 + jnp.cumsum(marks[:n_slots]),
+    probe_row = jnp.clip(row0 + prefix.cumsum(marks[:n_slots]),
                          0, n_probe_cap - 1)
     k = j - (cum[probe_row] - count[probe_row])
     build_pos = jnp.clip(start[probe_row] + k, 0, max(n_build_cap - 1, 0))
@@ -459,7 +460,7 @@ def sort_build_hashes(b_hash, b_live):
         (b_hash, inval, jnp.arange(Rb)), num_keys=2)
     cvi = jnp.concatenate([
         jnp.zeros(1, dtype=jnp.int64),
-        jnp.cumsum((sinv == 0).astype(jnp.int64)),
+        prefix.cumsum((sinv == 0).astype(jnp.int64)),
     ])
     return sh, cvi, order
 

@@ -22,17 +22,17 @@ that are sliced off.
 
 from __future__ import annotations
 
-import contextlib
 import functools
-import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tidb_tpu.utils.device import note_placement, target_platform
+
 __all__ = ["segment_count", "segment_sum_f32", "segment_sum_i64",
            "pallas_enabled", "set_pallas_enabled", "xla_segment_sum",
-           "force_platform", "target_platform", "pallas_interpret"]
+           "pallas_interpret"]
 
 _TILE = 1024
 # Largest group count the Pallas kernels take; above it the XLA scatter
@@ -51,44 +51,6 @@ def set_pallas_enabled(v: bool | None) -> None:
     _enabled = v
 
 
-# thread-local: the server traces statements on several worker threads,
-# and one leaving its block must not un-pin another mid-trace
-_forced = threading.local()
-
-
-@contextlib.contextmanager
-def force_platform(p: str):
-    """Pin the Pallas target platform for the duration of a call. Device
-    programs are traced while the executor glue has jax.default_device
-    pinned to host CPU (utils/device.py host_eager), yet they execute on
-    the devices their arrays live on — both device tiers wrap each
-    dispatch in force_platform(that platform) so kernel choice follows
-    the arrays, not the glue's pin."""
-    prev = getattr(_forced, "platform", None)
-    _forced.platform = p
-    try:
-        yield
-    finally:
-        _forced.platform = prev
-
-
-def target_platform() -> str:
-    """Platform the *current* computation lands on: an explicit
-    force_platform() wins (mesh fragments), then the pinned default
-    device (host-eager glue), then the default backend. The backend name
-    alone is wrong in both pinned cases."""
-    forced = getattr(_forced, "platform", None)
-    if forced is not None:
-        return forced
-    d = jax.config.jax_default_device
-    if d is not None:
-        return d.platform
-    try:
-        return jax.default_backend()
-    except RuntimeError:  # pragma: no cover
-        return "cpu"
-
-
 def pallas_enabled() -> bool:
     if _enabled is not None:
         return _enabled
@@ -101,9 +63,8 @@ def pallas_interpret() -> bool:
     never run interpreted and never handed to the XLA reference. On the
     CPU a Pallas kernel is reached only when the caller asked for it
     explicitly (set_pallas_enabled(True), probe mode 'pallas' — the
-    tier-1 tests), and there it interprets."""
-    from tidb_tpu.utils.device import note_placement
-
+    tier-1 tests), and there it interprets. The choice goes to the
+    placement log when chip_smoke.py has switched that on."""
     p = target_platform()
     if p == "tpu":
         note_placement("pallas", None, platform="tpu")

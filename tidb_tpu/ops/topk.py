@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tidb_tpu.ops import prefix
 from tidb_tpu.utils import dispatch
 
 __all__ = ["rank_operands", "topk_init", "topk_merge", "merge_topk",
@@ -128,7 +129,7 @@ def _kth_smallest(masked, k):
         # count), not scatter -- XLA CPU scatter is a serial loop over
         # all n updates and would cost more than the sort it replaces
         vals, kk = operands
-        ccum = jnp.cumsum(cand.astype(jnp.int32))
+        ccum = prefix.cumsum(cand.astype(jnp.int32))
         pos = jnp.searchsorted(
             ccum, jnp.arange(1, _CAND + 1, dtype=jnp.int32), side="left")
         buf = jnp.where(jnp.arange(_CAND) < count,
@@ -200,7 +201,7 @@ def _cut_single_key(nullrank, value, sel, cap: int, desc: bool):
         k_null = jnp.minimum(c, n_null)
         k_val = jnp.minimum(c - k_null, n_val)
         k_nan = jnp.minimum(c - k_null - k_val, n_nan)
-    ncum = jnp.cumsum(is_null.astype(jnp.int64))
+    ncum = prefix.cumsum(is_null.astype(jnp.int64))
     win_null = is_null & (ncum <= k_null)
     if floating:
         sentinel = jnp.asarray(jnp.inf, value.dtype)
@@ -210,18 +211,18 @@ def _cut_single_key(nullrank, value, sel, cap: int, desc: bool):
     thresh = _kth_smallest(masked, jnp.maximum(k_val, 1))
     strict = is_val & (masked < thresh)
     boundary = is_val & (masked == thresh)
-    bcum = jnp.cumsum(boundary.astype(jnp.int64))
+    bcum = prefix.cumsum(boundary.astype(jnp.int64))
     n_strict = jnp.sum(strict.astype(jnp.int64))
     win_val = (strict | (boundary & (bcum <= k_val - n_strict))) \
         & (k_val > 0)
     win = win_null | win_val
     if is_nan is not None:
-        nancum = jnp.cumsum(is_nan.astype(jnp.int64))
+        nancum = prefix.cumsum(is_nan.astype(jnp.int64))
         win = win | (is_nan & (nancum <= k_nan))
     # compact the <= cap winners by gather, not scatter: the j-th winner
     # sits at the first index whose running win-count reaches j+1, and
     # cap binary searches beat an n-update serial XLA CPU scatter
-    wcum = jnp.cumsum(win.astype(jnp.int32))
+    wcum = prefix.cumsum(win.astype(jnp.int32))
     idx = jnp.searchsorted(
         wcum, jnp.arange(1, cap + 1, dtype=jnp.int32), side="left")
     live = jnp.arange(cap, dtype=jnp.int32) < wcum[n - 1]

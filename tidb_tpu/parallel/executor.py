@@ -120,6 +120,11 @@ class ShardCache:
             self._cache.popitem(last=False)
         return st
 
+    def resident(self) -> list:
+        """Snapshot of what is held on the devices: [(table, ShardedTable)]."""
+        return [(held, st) for held, _ver, _enc, st in
+                list(self._cache.values())]
+
     def evict(self, table) -> None:
         """Drop a table's resident sharding (e.g. it grew past the
         device-cache budget and the streaming path takes over)."""

@@ -50,6 +50,7 @@ from tidb_tpu.executor.aggregate import make_segment_kernel
 from tidb_tpu.executor.builder import peel_stages, scan_stages_for
 from tidb_tpu.executor.scan import make_pipeline_fn
 from tidb_tpu.expression.compiler import compile_predicate, eval_expr
+from tidb_tpu.ops import prefix
 from tidb_tpu.parallel.distsql import merge_state, pmax, repartition_by_key
 from tidb_tpu.parallel.mesh import dcn_axis, shard_axis
 from tidb_tpu.planner.physical import PHashAgg, PHashJoin, PScan
@@ -79,7 +80,7 @@ def _compact(arrays: Dict[str, jax.Array], sel: jax.Array, cap: int):
     estimate-sized buffer (with the usual overflow-retry knob) is the
     static-shape analogue of a dynamic repartition. Returns
     (arrays', sel', required_factor_minus_one)."""
-    pos = jnp.cumsum(sel.astype(jnp.int64)) - 1
+    pos = prefix.cumsum(sel.astype(jnp.int64)) - 1
     total = jnp.sum(sel.astype(jnp.int64))
     tgt = jnp.where(sel & (pos < cap), pos, cap)  # dead rows -> drop lane
     out = {}
@@ -512,7 +513,7 @@ class _Compiler:
             lo, cnt = probe_hash_ranges(sh, cvi, p_hash2, p_ok,
                                         mode=env.get("probe_mode"))
 
-            cum = jnp.cumsum(cnt)
+            cum = prefix.cumsum(cnt)
             total = cum[-1]
             growth_j = growths[g_expand]
             capJ = int(np.ceil(growth_j * Rp))

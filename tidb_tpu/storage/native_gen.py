@@ -11,7 +11,6 @@ from __future__ import annotations
 import ctypes
 import hashlib
 import os
-import shlex
 import subprocess
 from typing import Optional
 
@@ -19,6 +18,7 @@ __all__ = ["load_native", "native_orders_lineitem", "load_error"]
 
 _NATIVE_DIR = os.path.abspath(
     os.path.join(os.path.dirname(__file__), "..", "..", "native"))
+_MAKEFILE = os.path.join(_NATIVE_DIR, "Makefile")
 _SRC = os.path.join(_NATIVE_DIR, "tpch_gen.cpp")
 _LIB = os.path.join(_NATIVE_DIR, "libtpchgen.so")
 # sha256 of the source the library on disk was built from; a checkout or
@@ -49,16 +49,12 @@ def _built_hash() -> Optional[str]:
 
 
 def _build(src_hash: str) -> None:
-    """Compile with the Makefile's recipe (same CXX/CXXFLAGS defaults)
-    into a private file and rename it into place: several processes
-    (pytest workers) may build at once, and none may ever load a
-    half-written library."""
-    cxx = os.environ.get("CXX", "g++")
-    flags = shlex.split(os.environ.get(
-        "CXXFLAGS", "-O3 -fPIC -shared -std=c++17 -Wall"))
+    """Run native/Makefile's recipe into a private file and rename it
+    into place: several processes (pytest workers) may build at once,
+    and none may ever load a half-written library."""
     tmp = f"{_LIB}.{os.getpid()}.tmp"
     try:
-        subprocess.run([cxx, *flags, "-o", tmp, _SRC],
+        subprocess.run(["make", "-f", _MAKEFILE, f"SRC={_SRC}", f"OUT={tmp}"],
                        check=True, capture_output=True, timeout=300)
         os.replace(tmp, _LIB)
     finally:

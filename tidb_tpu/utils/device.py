@@ -20,9 +20,10 @@ Two contexts say which side a piece of code is on:
     arrays, so only kernel choice needs it) and the fused segment-store
     pipeline's staging, build tables, state and program dispatches
     (``executor/pipeline.py``). It makes the accelerator the default
-    device again and tells the Pallas dispatch which platform the
-    program runs on (``ops.force_platform``), so neither placement nor
-    kernel choice depends on the glue's pin.
+    device again and tells the kernel dispatch which platform the
+    program runs on (``force_platform``, read back by
+    ``target_platform``), so neither placement nor kernel choice depends
+    on the glue's pin.
 
 When the default backend is the CPU (tests, ``--device cpu``) there is
 no second backend and both contexts do nothing.
@@ -37,7 +38,8 @@ from typing import Optional
 import jax
 
 __all__ = ["host_eager", "host_cpu_device", "accelerator_device",
-           "device_tier", "device_info", "note_placement", "placement"]
+           "device_tier", "force_platform", "target_platform",
+           "device_info", "track_placement", "note_placement", "placement"]
 
 _cpu_device: Optional[object] = None
 _accel_device: Optional[object] = None
@@ -49,7 +51,13 @@ def _probe() -> None:
     if not _probed:
         if jax.default_backend() != "cpu":
             _accel_device = jax.devices()[0]
-            _cpu_device = jax.local_devices(backend="cpu")[0]
+            try:
+                _cpu_device = jax.local_devices(backend="cpu")[0]
+            except RuntimeError as e:
+                raise RuntimeError(
+                    "the host glue needs jax's cpu backend beside the "
+                    f"{_accel_device.platform}: JAX_PLATFORMS must include "
+                    f"cpu (it is {jax.config.jax_platforms!r})") from e
         _probed = True
 
 
@@ -76,14 +84,49 @@ def host_eager():
     return jax.default_device(dev)
 
 
+# thread-local: the server traces statements on several worker threads,
+# and one leaving its block must not un-pin another mid-trace
+_forced = threading.local()
+
+
+@contextlib.contextmanager
+def force_platform(p: str):
+    """Name the platform the enclosed device programs run on. They are
+    traced while the executor glue has jax's default device pinned to
+    the host CPU (``host_eager``), yet they execute where their arrays
+    live — kernel choice (``target_platform``) must follow the arrays,
+    not the glue's pin."""
+    prev = getattr(_forced, "platform", None)
+    _forced.platform = p
+    try:
+        yield
+    finally:
+        _forced.platform = prev
+
+
+def target_platform() -> str:
+    """Platform the *current* computation lands on: an enclosing
+    force_platform() wins (both device tiers), then the pinned default
+    device (host-eager glue), then the default backend. The backend name
+    alone is wrong in both pinned cases."""
+    forced = getattr(_forced, "platform", None)
+    if forced is not None:
+        return forced
+    d = jax.config.jax_default_device
+    if d is not None:
+        return d.platform
+    try:
+        return jax.default_backend()
+    except RuntimeError:  # pragma: no cover
+        return "cpu"
+
+
 @contextlib.contextmanager
 def device_tier(platform: Optional[str] = None):
     """Run the enclosed staging/dispatches on the accelerator (see the
     module docstring). ``platform`` names the platform of the arrays the
     programs consume when the caller knows it (a mesh); default is the
     accelerator's."""
-    from tidb_tpu.ops import force_platform
-
     dev = accelerator_device()
     if platform is None and dev is not None:
         platform = dev.platform
@@ -95,18 +138,29 @@ def device_tier(platform: Optional[str] = None):
         yield
 
 
-# (site, platform) -> arrays seen. Written at the choke points where a
-# tier stages to or gets results from a device ("stage", "shard",
-# "fragment", "fused") and where a Pallas kernel picks its mode
-# ("pallas"; platform "interpret" when interpreted). chip_smoke.py reads
-# it to prove nothing of the served path sits on the host backend.
-_placement: dict = {}
+# (site, platform) -> arrays seen; None = not tracking (the default:
+# note_placement then costs one test). chip_smoke.py switches it on to
+# prove nothing of the served path sits on the host backend. Sites are
+# the choke points where a tier stages to or gets results from a device
+# ("stage", "shard", "fragment", "fused") and where a Pallas kernel
+# picks its mode ("pallas"; platform "interpret" when interpreted).
+_placement: Optional[dict] = None
 _placement_lock = threading.Lock()
 
 
+def track_placement() -> None:
+    """Start recording placements (process-wide, stays on)."""
+    global _placement
+    with _placement_lock:
+        if _placement is None:
+            _placement = {}
+
+
 def note_placement(site: str, tree, platform: Optional[str] = None) -> None:
-    """Record where the arrays of ``tree`` live (or ``platform`` itself
-    for an event that has no array)."""
+    """When tracking is on, record where the arrays of ``tree`` live (or
+    ``platform`` itself for an event that has no array)."""
+    if _placement is None:
+        return
     seen = [platform] if platform is not None else [
         next(iter(leaf.devices())).platform
         for leaf in jax.tree_util.tree_leaves(tree)
@@ -119,7 +173,7 @@ def note_placement(site: str, tree, platform: Optional[str] = None) -> None:
 def placement() -> dict:
     """Snapshot: {site: {platform: count}}."""
     with _placement_lock:
-        items = list(_placement.items())
+        items = list((_placement or {}).items())
     out: dict = {}
     for (site, p), n in items:
         out.setdefault(site, {})[p] = n
@@ -127,8 +181,12 @@ def placement() -> dict:
 
 
 def device_info() -> dict:
-    """What the boot line and the status port's /status report."""
+    """What the boot line and the status port's /status report. Start-up
+    calls it first: it initialises the backend and finds the host glue's
+    CPU device, and raises when either is missing — a server must not
+    come up to fail on every statement."""
     devs = jax.devices()
+    _probe()
     return {"platform": devs[0].platform,
             "device_kind": devs[0].device_kind,
             "count": len(devs)}
