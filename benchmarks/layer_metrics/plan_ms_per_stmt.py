@@ -1,0 +1,10 @@
+"""Parse and plan time (``session.parse`` + ``session.plan``), per
+statement.
+Mean over the statements of the window (``program_spans.py``).
+Source: program span."""
+
+from benchmarks import program_spans
+
+
+def read(ctx):
+    return program_spans.mean_ms(ctx, "plan")

@@ -182,7 +182,9 @@ def test_join_kernel_compiles(one_chip, tpu_target, kernel):
     else:
         direct = kernel == "probe_direct"
         firsts = s((rng_bucket + 1 if direct else 2,), i64)
-        table = _like(jax.eval_shape(jk.no_table), one_chip)
+        # called, not traced: eval_shape would memoize tracers in the
+        # module's cache and fail every later join of this worker
+        table = _like(jk.no_table(), one_chip)
         _compile(jk._probe_count, s((B,), i64), scal,
                  (s((N,), i64),), (s((N,), b),), s((N,), b),
                  (scal,), (scal,), (scal,), firsts, scal, scal, *table,
@@ -372,6 +374,50 @@ def test_mesh_q1_fragment_compiles(topo, tpu_target, tiny_tpch, n_dev):
         assert "all-reduce" in text
 
 
+STAGE_SCOPES = {
+    "make_agg_fragment": ("frag_scan_agg", ["scan", "agg.update", "agg.merge"]),
+    "make_join_agg_fragment": ("frag_join_agg", [
+        "scan", "exchange.probe/exchange.sort", "exchange.probe/exchange.scatter",
+        "exchange.probe/exchange.all_to_all", "exchange.build/exchange.sort",
+        "exchange.build/exchange.scatter", "exchange.build/exchange.all_to_all",
+        "join.build_sort", "join.probe", "join.gather", "agg.update",
+        "agg.merge"]),
+}
+# a build-side column above the join, so that the gather has work
+JOIN_SQL = ("select count(*), sum(l_quantity), max(o_totalprice) from lineitem"
+            " join orders on l_orderkey = o_orderkey where o_totalprice > 100000")
+
+
+@pytest.mark.parametrize("maker", sorted(STAGE_SCOPES))
+def test_fragment_program_is_named_and_its_stages_are_scoped(tiny_tpch, maker):
+    """What a device trace shows of a mesh fragment: the module is named
+    for the fragment's kind (``jit_frag_join_agg``, not ``jit_per_shard``)
+    and every op's metadata carries the stage that emitted it."""
+    from tidb_tpu.parallel import executor as pe
+    from tidb_tpu.parallel import make_mesh
+    from tidb_tpu.session import Session
+    from tidb_tpu.storage.tpch_queries import Q
+
+    s = Session(catalog=tiny_tpch, mesh=make_mesh(devices=jax.devices()[:1]))
+    s.execute("set tidb_device_engine_mode = 'force'")
+    # lowered for the CPU the statement ran on, whatever the module's
+    # other tests trace for: names and scopes are the platform's no more
+    # than the plan's
+    with force_platform("cpu"), _capture(pe, maker) as made:
+        s.query(Q["q1"][0] if maker == "make_agg_fragment" else JOIN_SQL)
+        assert made, f"the statement did not take {maker}"
+        args, kw = made[-1]
+        tables = [a for a in args if hasattr(a, "rows_per_part")]
+        fn = getattr(pe, maker)(*args, **kw)
+        text = fn.lower(*[x for st in tables for x in
+                          (st.data, st.valid, st.sel, st.refs)]).as_text(
+                              debug_info=True)
+    name, scopes = STAGE_SCOPES[maker]
+    assert f"module @jit_{name} " in text
+    for scope in scopes:
+        assert f"jit({name})/{scope}/" in text, scope
+
+
 # -- general fragments (parallel/fragment.py compile_fragment) ---------------
 
 def _general_fragments(catalog, sql):
@@ -389,8 +435,9 @@ def _general_fragments(catalog, sql):
     got = []
     real = pe.DistFragmentExec._dispatch_retry
 
-    def spy(self, prog, args, shapes_sig, types_sig, growths):
-        out, growths = real(self, prog, args, shapes_sig, types_sig, growths)
+    def spy(self, prog, args, shapes_sig, types_sig, growths, *span):
+        out, growths = real(self, prog, args, shapes_sig, types_sig, growths,
+                            *span)
         got.append((prog, jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), args),
             growths, getattr(self.ctx, "join_probe_mode", None)))

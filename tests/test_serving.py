@@ -533,3 +533,42 @@ class TestWireLevel:
             boot.close()
         finally:
             srv.shutdown()
+
+
+class TestBatchedMemberTraces:
+    def test_a_coalesced_member_carries_its_share_of_the_groups_waits(self):
+        """ISSUE 25: a batched statement's request trace (opened by the
+        submitter, carried by the Member to the worker) holds the wait
+        for the seal, the group's wait for the catalog lock and the
+        group's pass before the member's own turn as spans with true
+        starts — nothing of the request is unaccounted."""
+        from tidb_tpu.utils.tracing import STORE
+
+        cat, _boot = make_cat(tidb_tpu_batch_window_us=100_000,
+                              tidb_tpu_max_batch_size=4)
+        sched = StatementScheduler(cat, workers=2)
+        t0 = time.perf_counter()
+        _sessions, _results, errors = run_clients(
+            sched, cat, 4, lambda ci: [ci + 60, ci + 64])
+        sched.shutdown()
+        assert not [e for errs in errors for e in errs]
+        members = [
+            tr for tr in STORE.finished()
+            if tr.interval_perf()[0] >= t0 and any(
+                s.name.startswith("sched.batch[n=")
+                and s.name != "sched.batch[n=1]" for s in tr.spans)]
+        assert members, "no coalesced member's trace finished"
+        for tr in members:
+            root = tr.root()
+            assert root.name == "sched.stmt"  # no wire server: the scheduler's
+            names = [s.name for s in tr.spans if s.parent_id == root.span_id]
+            assert names[:3] == ["sched.queue", "sched.lock_wait",
+                                 "sched.batch_pass"], names
+            assert names[3].startswith("stmt.")
+            spans = {s.name: s for s in tr.spans}
+            # true starts: each begins where the one before it ended
+            q, w, p = (spans[n] for n in names[:3])
+            assert abs(q.start_us + q.dur_us - w.start_us) <= 2
+            assert abs(w.start_us + w.dur_us - p.start_us) <= 2
+            assert abs(sum(tr.self_us().values()) - root.dur_us) \
+                <= len(tr.spans)

@@ -418,3 +418,247 @@ class TestDistributedTracing:
             assert cancel_obs, names
         finally:
             cl.shutdown()
+
+
+# -- the served path: one request, one trace (ISSUE 25) ----------------------
+
+
+@pytest.fixture
+def served():
+    """A wire server over a small table, two warmed clients; no head
+    sampling, so what the kept store holds is kept by a tail rule."""
+    from tidb_tpu.server.client import Client
+    from tidb_tpu.server.server import Server
+    from tidb_tpu.storage.catalog import Catalog
+
+    cat = Catalog()
+    cat.global_vars["tidb_trace_sample_rate"] = 0.0
+    boot = Session(catalog=cat)
+    boot.execute("create table rt (a bigint primary key, b bigint)")
+    boot.execute("insert into rt values (1, 2), (3, 4), (5, 6)")
+    srv = Server(catalog=cat, port=0)
+    srv.start()
+    clients = [Client(srv.host, srv.port, db="test") for _ in range(2)]
+    try:
+        for c in clients:
+            c.query(SERVED_SQL)
+        yield srv, clients
+    finally:
+        for c in clients:
+            c.close()
+        srv.stop()
+
+
+SERVED_SQL = "select a, sum(b) from rt group by a order by a"
+
+
+def _dispatches():
+    return sum(v for _labels, v in M.DISPATCH_TOTAL.samples())
+
+
+def _served_trace(fn, fails=None):
+    """Run `fn` (one statement of one client; `fails`: the error it has
+    to relay); its request's trace, and the client's own clock around it."""
+    t0 = time.perf_counter()
+    if fails is None:
+        fn()
+    else:
+        with pytest.raises(Exception, match=fails):
+            fn()
+    t1 = time.perf_counter()
+    # the client has its last packet before the connection thread has
+    # closed the root: give that thread its microseconds
+    deadline = time.monotonic() + 5
+    while time.monotonic() < deadline:
+        for tr in reversed(tracing.STORE.finished()):
+            start, _end = tr.interval_perf()
+            if tr.root().name == "wire.stmt" and t0 <= start <= t1:
+                return tr, t0, t1
+        time.sleep(0.001)
+    raise AssertionError("no wire.stmt trace finished in the interval")
+
+
+def _children(tr, span):
+    return [s for s in tr.spans if s.parent_id == span.span_id]
+
+
+class TestRequestTrace:
+    def test_one_trace_from_the_packet_to_the_last_write(self, served):
+        _srv, (c, _) = served
+        d0 = _dispatches()
+        tr, t0, t1 = _served_trace(lambda: c.query(SERVED_SQL))
+        root = tr.root()
+        assert root.name == "wire.stmt"
+        assert [s.name for s in _children(tr, root)] == [
+            "sched.queue", "sched.lock_wait", "session.parse",
+            "stmt.select", "wire.write"]
+        stmt = next(s for s in tr.spans if s.name == "stmt.select")
+        assert [s.name for s in _children(tr, stmt)] == [
+            "session.plan", "session.execute"]
+        execute = _children(tr, stmt)[1]
+        under = {s.span_id for s in tr.spans if s.span_id == execute.span_id}
+        for s in tr.spans:  # spans are recorded parents first
+            if s.parent_id in under:
+                under.add(s.span_id)
+        waits = [s for s in tr.spans if s.name == "device.wait"]
+        assert waits and all(s.span_id in under for s in waits)
+        # the trace covers the request: the client's clock around the
+        # statement reads what the root span reads, to the loopback's
+        # hops (the root opens after the read, and closes after the
+        # client has its last packet)
+        assert all(s.dur_us >= 0 for s in tr.spans)
+        assert abs((t1 - t0) * 1e3 - root.dur_us / 1e3) < 10.0
+        # every round trip is counted and spanned by the same line
+        launches = [s for s in tr.spans if s.name.startswith("dispatch.")]
+        assert len(launches) == _dispatches() - d0 > 0
+        # nothing of the request is in two spans or in none
+        self_us = tr.self_us()
+        assert abs(sum(self_us.values()) - root.dur_us) <= len(tr.spans)
+        assert sum(tr.self_us_by_name().values()) == sum(self_us.values())
+        assert tracing.STORE.get(tr.trace_id) is None  # uneventful: not kept
+
+    def test_the_lock_wait_is_the_other_statements_remaining_run(self, served):
+        """Two connections: the first holds the catalog lock inside its
+        commit, the second's statement is claimed by a free worker at
+        once (no queue) and parks on the lock until the first is let go."""
+        _srv, (c1, c2) = served
+        reached, gate = threading.Event(), threading.Event()
+
+        def hold():
+            reached.set()
+            assert gate.wait(10)
+
+        with failpoint("2pc.before_prewrite", action=hold, times=1):
+            first = threading.Thread(
+                target=c1.query, args=("insert into rt values (7, 8)",))
+            first.start()
+            assert reached.wait(10)
+            out = {}
+            second = threading.Thread(target=lambda: out.update(
+                zip(("tr", "t0", "t1"),
+                    _served_trace(lambda: c2.query(SERVED_SQL)))))
+            second.start()
+            time.sleep(0.25)  # the second is parked; the first still holds
+            released = time.perf_counter()
+            gate.set()
+            first.join(10)
+            second.join(10)
+        assert not first.is_alive() and not second.is_alive()
+        tr = out["tr"]
+        by_name = {s.name: s for s in tr.spans}
+        held_ms = (released - out["t0"]) * 1e3
+        wait_ms = by_name["sched.lock_wait"].dur_us / 1e3
+        assert held_ms >= 250
+        assert by_name["sched.queue"].dur_us / 1e3 < wait_ms / 4
+        # the wait is in the request's time, as the client felt it, and
+        # before the statement's own span
+        assert held_ms - 100 < wait_ms < tr.root().dur_us / 1e3
+        assert (by_name["stmt.select"].start_us
+                >= by_name["sched.lock_wait"].start_us
+                + by_name["sched.lock_wait"].dur_us)
+
+    def test_an_errored_statement_closes_its_trace_on_both_threads(self, served):
+        _srv, (c, _) = served
+        tr, _t0, _t1 = _served_trace(
+            lambda: c.query("select * from missing_rt"), fails="missing_rt")
+        assert all(s.dur_us >= 0 and s.ann is None for s in tr.spans)
+        assert tr.keep_reasons == ["error:SchemaError"]
+        assert tracing.STORE.get(tr.trace_id) is tr  # the error tail rule
+        assert "wire.write" in [s.name for s in tr.spans]  # the error packet
+        # both threads are clean: the next statement's trace is its own
+        nxt, _t0, _t1 = _served_trace(lambda: c.query(SERVED_SQL))
+        assert nxt is not tr and nxt.root().name == "wire.stmt"
+        assert not nxt.keep_reasons
+
+    def test_a_killed_statement_closes_its_trace(self, served):
+        srv, (c1, c2) = served
+        victim = min(srv.sessions)  # c1's connection: the first opened
+        reached, gate = threading.Event(), threading.Event()
+
+        def hold():
+            reached.set()
+            assert gate.wait(10)
+
+        out = []
+
+        def run():  # killed inside its commit or not: the kill's business
+            try:
+                out.append(_served_trace(
+                    lambda: c1.query("insert into rt values (9, 10)")))
+            except Exception:  # noqa: BLE001 — relayed to the client
+                pass
+
+        with failpoint("2pc.before_prewrite", action=hold, times=1):
+            t = threading.Thread(target=run)
+            t.start()
+            assert reached.wait(10)
+            srv.sessions[victim]._killed = True  # KILL CONNECTION's flag
+            gate.set()
+            t.join(10)
+        assert not t.is_alive()
+        for tr, _t0, _t1 in out:  # whatever its end, finished and closed
+            assert all(s.dur_us >= 0 and s.ann is None for s in tr.spans)
+        tr, _t0, _t1 = _served_trace(lambda: c1.query(SERVED_SQL),
+                                     fails="killed")
+        assert "error:QueryKilledError" in tr.keep_reasons
+        assert all(s.dur_us >= 0 and s.ann is None for s in tr.spans)
+        nxt, _t0, _t1 = _served_trace(lambda: c2.query(SERVED_SQL))
+        assert not nxt.keep_reasons
+
+    def test_slow_is_judged_on_the_requests_whole_time(self, served):
+        """The tail rules run where the root closes, with the session's
+        threshold: a statement quick under the lock but long in the
+        request (here: everything is slow) is kept as the client felt it."""
+        _srv, (c, _) = served
+        c.query("set tidb_slow_log_threshold = 0")
+        tr, _t0, _t1 = _served_trace(lambda: c.query(SERVED_SQL))
+        c.query("set tidb_slow_log_threshold = 300")
+        assert tr.keep_reasons == ["slow"]
+        assert tracing.STORE.get(tr.trace_id) is tr
+
+    def test_session_without_a_server_still_owns_its_trace(self):
+        s = _quiet(Session())
+        s.execute("create table own (a bigint)")
+        before = {id(t) for t in tracing.STORE.finished()}
+        s.query("select count(*) from own")
+        tr = tracing.STORE.finished()[-1]  # finished, though not kept
+        assert id(tr) not in before and not tr.kept
+        assert tr.root().name == "stmt.select"
+        assert "session.parse" not in [s.name for s in tr.spans]
+        assert tracing.current() is None
+
+    def test_spans_are_on_the_profilers_clock(self, served, tmp_path):
+        """With a profiler session open, the host plane of the xplane
+        holds the request's spans as ``tidb.*`` events, the root with
+        its trace_id, nested as the program recorded them."""
+        import jax
+        from jax.profiler import ProfileData
+
+        _srv, (c, _) = served
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            tr, _t0, _t1 = _served_trace(lambda: c.query(SERVED_SQL))
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        events = {}
+        for plane in ProfileData.from_file(str(path)).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("tidb."):
+                        events.setdefault(e.name, []).append(
+                            (e.start_ns, e.duration_ns, {k: v for k, v in e.stats}))
+        for name in ("tidb.wire.stmt", "tidb.sched.lock_wait",
+                     "tidb.session.parse", "tidb.session.plan",
+                     "tidb.session.execute", "tidb.device.wait",
+                     "tidb.wire.write"):
+            assert name in events, sorted(events)
+        assert "tidb.sched.queue" not in events  # crosses threads: host clock only
+        (r0, rd, stats), = events["tidb.wire.stmt"]
+        assert stats.get("trace_id") == tr.trace_id
+        assert abs(rd / 1e3 - tr.root().dur_us) < 2_000
+        for start, dur, _ in events["tidb.device.wait"]:
+            assert r0 <= start and start + dur <= r0 + rd

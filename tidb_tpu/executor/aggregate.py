@@ -21,7 +21,6 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -351,12 +350,10 @@ class HashAggExec(Executor):
         produce the same state via collective merge."""
         # one batched fetch: per-key np.asarray would pay a device round
         # trip per state array
-        import jax
 
         from tidb_tpu.utils import dispatch as dsp
 
-        host = dsp.record_fetch(jax.device_get(state))
-        dsp.record(site="fetch")
+        host = dsp.device_get(state)
         if self.group_exprs:
             occupied = np.nonzero(host["occ"] > 0)[0]
         else:
@@ -470,7 +467,7 @@ class HashAggExec(Executor):
             # per-column np.asarray syncs this loop used to pay. The
             # device tiers (fused pipeline / _run_generic_device) are
             # the no-per-chunk-fetch paths
-            outs, sel = dsp.record_fetch(jax.device_get(eval_all(chunk)))
+            outs, sel = dsp.device_get(eval_all(chunk), counted=False)
             sel = np.asarray(sel)
             live = np.nonzero(sel)[0]
             total += len(live)
@@ -613,8 +610,6 @@ class HashAggExec(Executor):
         """Sort-based grouping on device (agg_device.py): per-chunk
         partial group tables, pairwise device merges, one batched fetch,
         host finalize through the shared partial-state path."""
-        import jax
-
         from tidb_tpu.executor.agg_device import (
             GroupTableStack,
             make_partial_kernel,
@@ -635,8 +630,6 @@ class HashAggExec(Executor):
         emit. Shared by the pull-based device path above and the fused
         scan→partial-agg pipeline (executor/pipeline.py), which
         accumulates the same tables from its fused chunk programs."""
-        import jax
-
         from tidb_tpu.executor.agg_device import table_to_host_partial
         from tidb_tpu.utils import dispatch as dsp
 
@@ -644,8 +637,8 @@ class HashAggExec(Executor):
         if not tables:
             self._out = []  # grouped agg over empty input -> no rows
             return
-        host_tables = dsp.record_fetch(
-            jax.device_get(tables))  # ONE round trip (finalize)
+        # ONE round trip (finalize)
+        host_tables = dsp.device_get(tables, counted=False)
         # account the durable (ngroups-sliced) partial tables with the
         # same incremental discipline as the host spill-merge path; the
         # padded slot arrays are transients

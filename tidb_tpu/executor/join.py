@@ -38,7 +38,6 @@ from __future__ import annotations
 import time
 from typing import List, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -753,9 +752,7 @@ class HashJoinExec(Executor):
         # chunk-loop sync-budget pass watches the loop form)
         from tidb_tpu.utils import dispatch as dsp
 
-        totals = dsp.record_fetch(
-            jax.device_get([t["total_dev"] for t in tokens]))
-        dsp.record(site="fetch")
+        totals = dsp.device_get([t["total_dev"] for t in tokens])
         if self.kind == "inner" and not self._has_filter:
             # plan feedback: for the unfiltered inner join the summed
             # match totals ARE the output cardinality, host-known from

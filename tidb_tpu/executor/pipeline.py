@@ -1177,9 +1177,7 @@ class FusedScanProbeExec(_StagedScanMixin, HashJoinExec):
         # expansion fit their in-program tile need nothing further
         # (sanctioned device_get outside any loop — the chunk-loop
         # sync-budget pass watches the loop form)
-        totals = dsp.record_fetch(
-            jax.device_get([t["total_dev"] for t in tokens]))
-        dsp.record(site="fetch")
+        totals = dsp.device_get([t["total_dev"] for t in tokens])
         # plan feedback: the fused inner PK-FK shape's summed totals are
         # its exact output cardinality, and total vs tile capacity is
         # the overflow telemetry that sizes join_tiles next time —
@@ -1420,8 +1418,7 @@ class FusedScanTopNExec(_StagedScanMixin, Executor):
         # device_get outside any loop — the chunk-loop sync-budget pass
         # watches the loop form)
         dead, _ranks, _pos, _next, payload = state
-        host = dsp.record_fetch(jax.device_get((dead, payload)))
-        dsp.record(site="fetch")
+        host = dsp.device_get((dead, payload))
         self._emit_winners(*host)
 
     def _emit_winners(self, dead, payload) -> None:

@@ -8,7 +8,6 @@ from __future__ import annotations
 
 from typing import List, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -50,7 +49,7 @@ class _Materializing(Executor):
             # ONE device_get per chunk (Chunk/Column are pytrees) — the
             # per-column np.asarray calls below then see numpy and cost
             # nothing (was 2 syncs per column)
-            kcols, ch = _dsp.record_fetch(jax.device_get(eval_chunk(ch)))
+            kcols, ch = _dsp.device_get(eval_chunk(ch), counted=False)
             sel = np.asarray(ch.sel)
             live = np.nonzero(sel)[0]
             named = {}

@@ -180,10 +180,10 @@ def build_sort(key_datas, key_valids, ok, payload_datas, payload_valids,
     """Device-resident build: returns (sorted_keys [B], n_build scalar,
     sorted payload datas/valids, sorted raw key values). Inputs must be
     padded to a ``shape_bucket`` capacity with ok=False padding."""
-    dispatch.record(site="jit:join.build")
-    return _build_sort(key_datas, key_valids, ok, payload_datas,
-                       payload_valids, los, strides, rngs,
-                       modes=tuple(modes), hash_mode=bool(hash_mode))
+    with dispatch.launch("jit:join.build"):
+        return _build_sort(key_datas, key_valids, ok, payload_datas,
+                           payload_valids, los, strides, rngs,
+                           modes=tuple(modes), hash_mode=bool(hash_mode))
 
 
 # -- direct-address (radix-histogram) index over the packed-key domain ------
@@ -210,10 +210,10 @@ def build_direct_index(sorted_keys, n_build, lo, rng_bucket: int):
     Built once per join build; XLA:CPU measures the O(1) gather probe
     ~30x faster than its searchsorted lowering (and on TPU it replaces
     log(B) dependent gather rounds with two vector gathers)."""
-    dispatch.record(site="jit:join.build")
-    return _build_direct_index(sorted_keys, n_build,
-                               jnp.asarray(lo, dtype=jnp.int64),
-                               rng_bucket=int(rng_bucket))
+    with dispatch.launch("jit:join.build"):
+        return _build_direct_index(sorted_keys, n_build,
+                                   jnp.asarray(lo, dtype=jnp.int64),
+                                   rng_bucket=int(rng_bucket))
 
 
 # -- open-addressing hash table over the sorted build keys ------------------
@@ -241,8 +241,8 @@ def build_hash_table(sorted_keys):
     cap = table_capacity(sorted_keys.shape[0])
     if cap is None:
         return None
-    dispatch.record(site="jit:join.build")
-    return _build_hash_table(sorted_keys, cap=cap)
+    with dispatch.launch("jit:join.build"):
+        return _build_hash_table(sorted_keys, cap=cap)
 
 
 _NO_TABLE = None
@@ -347,15 +347,15 @@ def probe_count(sorted_keys, n_build, key_datas, key_valids, sel,
     probe = "sorted" if table is None else str(probe)
     JOIN_PROBE_MODE_TOTAL.inc(mode="direct" if direct else probe)
     tkeys, tlos, this, tok = table if table is not None else no_table()
-    dispatch.record(site="jit:join.probe")
-    return _probe_count(sorted_keys, n_build, key_datas, key_valids, sel,
-                        los, strides, rngs, firsts,
-                        jnp.asarray(lo_packed, dtype=jnp.int64),
-                        jnp.asarray(rng_packed, dtype=jnp.int64),
-                        tkeys, tlos, this, tok,
-                        modes=tuple(modes), hash_mode=bool(hash_mode),
-                        left_pad=bool(left_pad), direct=bool(direct),
-                        probe=probe)
+    with dispatch.launch("jit:join.probe"):
+        return _probe_count(sorted_keys, n_build, key_datas, key_valids, sel,
+                            los, strides, rngs, firsts,
+                            jnp.asarray(lo_packed, dtype=jnp.int64),
+                            jnp.asarray(rng_packed, dtype=jnp.int64),
+                            tkeys, tlos, this, tok,
+                            modes=tuple(modes), hash_mode=bool(hash_mode),
+                            left_pad=bool(left_pad), direct=bool(direct),
+                            probe=probe)
 
 
 # -- shared expand-position arithmetic --------------------------------------
@@ -436,15 +436,15 @@ def expand_tiles(start, count, real_count, cum, w0,
     """One fused dispatch emitting ``n_tiles`` output tiles of capacity
     ``tile_cap`` ([T, C] arrays — the partition.py streaming layout)
     starting at flat output offset ``w0``."""
-    dispatch.record(site="jit:join.expand")
-    return _expand_tiles(
-        start, count, real_count, cum, jnp.asarray(w0, dtype=jnp.int64),
-        tuple(probe_datas), tuple(probe_valids),
-        tuple(build_datas), tuple(build_valids),
-        n_tiles=int(n_tiles), tile_cap=int(tile_cap),
-        build_cap=int(build_cap), left=bool(left),
-        with_probe_row=bool(with_probe_row),
-        with_build_pos=bool(with_build_pos))
+    with dispatch.launch("jit:join.expand"):
+        return _expand_tiles(
+            start, count, real_count, cum, jnp.asarray(w0, dtype=jnp.int64),
+            tuple(probe_datas), tuple(probe_valids),
+            tuple(build_datas), tuple(build_valids),
+            n_tiles=int(n_tiles), tile_cap=int(tile_cap),
+            build_cap=int(build_cap), left=bool(left),
+            with_probe_row=bool(with_probe_row),
+            with_build_pos=bool(with_build_pos))
 
 
 # -- fragment-tier primitives (traced inside shard_map) ---------------------

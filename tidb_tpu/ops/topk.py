@@ -300,6 +300,6 @@ def merge_topk(state, key_pairs, payload_cols, sel, descs=None):
     """Standalone jitted merge (kernel tests / non-fused callers): the
     fused pipeline instead traces ``topk_merge`` inside its own
     ``cached_jit`` program, which counts its dispatches there."""
-    dispatch.record(site="jit:topk.merge")
-    return _merge_topk(state, tuple(key_pairs), tuple(payload_cols), sel,
-                       None if descs is None else tuple(descs))
+    with dispatch.launch("jit:topk.merge"):
+        return _merge_topk(state, tuple(key_pairs), tuple(payload_cols), sel,
+                           None if descs is None else tuple(descs))

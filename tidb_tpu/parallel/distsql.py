@@ -116,16 +116,23 @@ def make_agg_fragment(st: ShardedTable, stages: List, group_exprs, aggs,
     init_state, update, _ = make_segment_kernel(group_exprs, aggs, domains)
     types, mesh = dict(st.types), st.mesh
 
-    def per_shard(data, valid, sel, refs):
-        chunk = pipeline(_shard_chunk(types, data, valid, sel, uid_map,
-                                      refs))
-        return merge_state(update(init_state(), chunk))
+    # the function's name is the device program's (`jit_frag_scan_agg`
+    # in a profiler trace); the scopes name its stages in every op's
+    # metadata — no fusion changes, nothing at run time
+    def frag_scan_agg(data, valid, sel, refs):
+        with jax.named_scope("scan"):
+            chunk = pipeline(_shard_chunk(types, data, valid, sel, uid_map,
+                                          refs))
+        with jax.named_scope("agg.update"):
+            state = update(init_state(), chunk)
+        with jax.named_scope("agg.merge"):
+            return merge_state(state)
 
     # lint: disable=jit-hygiene -- signature-keyed: callers cache the
     # returned fn via ShardCache.get_fragment (plan/shape/type key);
     # the closure carries only schema metadata, never table arrays
     return jax.jit(jax.shard_map(
-        per_shard, mesh=mesh,
+        frag_scan_agg, mesh=mesh,
         in_specs=(_SPEC, _SPEC, _SPEC, P()), out_specs=P(),
         check_vma=False,
     ))
@@ -163,32 +170,35 @@ def repartition_by_key(arrays: Dict[str, jax.Array], sel: jax.Array,
     live = sel & key_valid
     dest = jnp.where(live, _hash_dest(key, n_parts), n_parts)  # P = drop lane
 
-    order = jnp.argsort(dest, stable=True)
-    sorted_dest = dest[order]
-    seg_start = jnp.searchsorted(sorted_dest, jnp.arange(n_parts + 1, dtype=sorted_dest.dtype))
-    pos = jnp.arange(R) - seg_start[jnp.clip(sorted_dest, 0, n_parts)]
-    in_cap = (pos < cap) & (sorted_dest < n_parts)
-    overflow = jnp.sum((pos >= cap) & (sorted_dest < n_parts))
+    with jax.named_scope("exchange.sort"):
+        order = jnp.argsort(dest, stable=True)
+        sorted_dest = dest[order]
+        seg_start = jnp.searchsorted(sorted_dest, jnp.arange(n_parts + 1, dtype=sorted_dest.dtype))
+        pos = jnp.arange(R) - seg_start[jnp.clip(sorted_dest, 0, n_parts)]
+        in_cap = (pos < cap) & (sorted_dest < n_parts)
+        overflow = jnp.sum((pos >= cap) & (sorted_dest < n_parts))
 
     # scatter row `order[i]` into send slot [sorted_dest[i], pos[i]];
     # dead/overflow rows land in a trash lane (row n_parts) that is sliced
     # off before the exchange — slot (0,0) must never see collisions
-    slot_d = jnp.where(in_cap, sorted_dest, n_parts)
-    slot_p = jnp.where(in_cap, pos, 0)
+    with jax.named_scope("exchange.scatter"):
+        slot_d = jnp.where(in_cap, sorted_dest, n_parts)
+        slot_p = jnp.where(in_cap, pos, 0)
 
-    def scatter(a):
-        buf = jnp.zeros((n_parts + 1, cap), dtype=a.dtype)
-        return buf.at[slot_d, slot_p].set(a[order])[:n_parts]
+        def scatter(a):
+            buf = jnp.zeros((n_parts + 1, cap), dtype=a.dtype)
+            return buf.at[slot_d, slot_p].set(a[order])[:n_parts]
 
-    sent_sel = (jnp.zeros((n_parts + 1, cap), dtype=jnp.bool_)
-                .at[slot_d, slot_p].set(True))[:n_parts]
-    sent_key = scatter(key)
-    sent = {name: scatter(a) for name, a in arrays.items()}
+        sent_sel = (jnp.zeros((n_parts + 1, cap), dtype=jnp.bool_)
+                    .at[slot_d, slot_p].set(True))[:n_parts]
+        sent_key = scatter(key)
+        sent = {name: scatter(a) for name, a in arrays.items()}
 
-    recv_sel = jax.lax.all_to_all(sent_sel, axes, 0, 0).reshape(-1)
-    recv_key = jax.lax.all_to_all(sent_key, axes, 0, 0).reshape(-1)
-    recv = {name: jax.lax.all_to_all(a, axes, 0, 0).reshape(-1)
-            for name, a in sent.items()}
+    with jax.named_scope("exchange.all_to_all"):
+        recv_sel = jax.lax.all_to_all(sent_sel, axes, 0, 0).reshape(-1)
+        recv_key = jax.lax.all_to_all(sent_key, axes, 0, 0).reshape(-1)
+        recv = {name: jax.lax.all_to_all(a, axes, 0, 0).reshape(-1)
+                for name, a in sent.items()}
     return recv, recv_sel, recv_key, overflow
 
 
@@ -198,12 +208,14 @@ def _local_join(build_key, build_sel, probe_key, probe_sel):
     Validity is a secondary sort key (valid rows first among equal keys),
     not an in-band sentinel — a legitimate INT64_MAX key still joins."""
     n = build_key.shape[0]
-    invalid = (~build_sel).astype(jnp.int32)
-    skeys, sinv, order = jax.lax.sort(
-        (build_key, invalid, jnp.arange(n)), num_keys=2)
-    pos = jnp.clip(jnp.searchsorted(skeys, probe_key), 0, n - 1)
-    hit = (skeys[pos] == probe_key) & (sinv[pos] == 0) & probe_sel
-    return order[pos], hit
+    with jax.named_scope("join.build_sort"):
+        invalid = (~build_sel).astype(jnp.int32)
+        skeys, sinv, order = jax.lax.sort(
+            (build_key, invalid, jnp.arange(n)), num_keys=2)
+    with jax.named_scope("join.probe"):
+        pos = jnp.clip(jnp.searchsorted(skeys, probe_key), 0, n - 1)
+        hit = (skeys[pos] == probe_key) & (sinv[pos] == 0) & probe_sel
+        return order[pos], hit
 
 
 def make_join_agg_fragment(
@@ -235,17 +247,19 @@ def make_join_agg_fragment(
     # capture metadata only — never the ShardedTables (see make_agg_fragment)
     probe_types, build_types = dict(probe.types), dict(build.types)
 
-    def per_shard(p_data, p_valid, p_sel, p_refs,
-                  b_data, b_valid, b_sel, b_refs):
-        pch = p_pipe(_shard_chunk(probe_types, p_data, p_valid, p_sel,
-                                  probe_uids, p_refs))
-        bch = b_pipe(_shard_chunk(build_types, b_data, b_valid, b_sel,
-                                  build_uids, b_refs))
+    # named like make_agg_fragment's program, and staged like it
+    def frag_join_agg(p_data, p_valid, p_sel, p_refs,
+                      b_data, b_valid, b_sel, b_refs):
+        with jax.named_scope("scan"):
+            pch = p_pipe(_shard_chunk(probe_types, p_data, p_valid, p_sel,
+                                      probe_uids, p_refs))
+            bch = b_pipe(_shard_chunk(build_types, b_data, b_valid, b_sel,
+                                      build_uids, b_refs))
 
-        pk, pkv = eval_expr(probe_key_ir, pch)
-        bk, bkv = eval_expr(build_key_ir, bch)
-        pk = pk.astype(jnp.int64)
-        bk = bk.astype(jnp.int64)
+            pk, pkv = eval_expr(probe_key_ir, pch)
+            bk, bkv = eval_expr(build_key_ir, bch)
+            pk = pk.astype(jnp.int64)
+            bk = bk.astype(jnp.int64)
 
         def flat(ch: Chunk):
             arrs = {}
@@ -261,31 +275,36 @@ def make_join_agg_fragment(
                                    type_=col.type_)
             return Chunk(cols, sel)
 
-        pr, pr_sel, pr_key, p_ovf = repartition_by_key(
-            flat(pch), pch.sel, pk, pkv, n_parts, growth)
-        br, br_sel, br_key, b_ovf = repartition_by_key(
-            flat(bch), bch.sel, bk, bkv, n_parts, growth)
+        with jax.named_scope("exchange.probe"):
+            pr, pr_sel, pr_key, p_ovf = repartition_by_key(
+                flat(pch), pch.sel, pk, pkv, n_parts, growth)
+        with jax.named_scope("exchange.build"):
+            br, br_sel, br_key, b_ovf = repartition_by_key(
+                flat(bch), bch.sel, bk, bkv, n_parts, growth)
 
         bidx, hit = _local_join(br_key, br_sel, pr_key, pr_sel)
-        joined_cols = dict(pr)
-        for uid, col in bch.columns.items():
-            joined_cols[uid + ".d"] = br[uid + ".d"][bidx]
-            joined_cols[uid + ".v"] = br[uid + ".v"][bidx] & hit
-        ref_cols = dict(pch.columns)
-        ref_cols.update(bch.columns)
-        ref = Chunk(ref_cols, pch.sel)  # types template only
-        joined = unflat(joined_cols, ref, hit)
+        with jax.named_scope("join.gather"):
+            joined_cols = dict(pr)
+            for uid, col in bch.columns.items():
+                joined_cols[uid + ".d"] = br[uid + ".d"][bidx]
+                joined_cols[uid + ".v"] = br[uid + ".v"][bidx] & hit
+            ref_cols = dict(pch.columns)
+            ref_cols.update(bch.columns)
+            ref = Chunk(ref_cols, pch.sel)  # types template only
+            joined = unflat(joined_cols, ref, hit)
 
-        joined = post_pipe(joined)
-        state = merge_state(update(init_state(), joined))
-        ovf = jax.lax.psum(p_ovf + b_ovf, _AXES)
+        with jax.named_scope("agg.update"):
+            state = update(init_state(), post_pipe(joined))
+        with jax.named_scope("agg.merge"):
+            state = merge_state(state)
+            ovf = jax.lax.psum(p_ovf + b_ovf, _AXES)
         return state, ovf
 
     # lint: disable=jit-hygiene -- signature-keyed via
     # ShardCache.get_fragment like make_agg_fragment; closure carries
     # plan metadata only (types/mesh/keys), never the ShardedTables
     return jax.jit(jax.shard_map(
-        per_shard, mesh=mesh,
+        frag_join_agg, mesh=mesh,
         in_specs=(_SPEC, _SPEC, _SPEC, P(), _SPEC, _SPEC, _SPEC, P()),
         out_specs=(P(), P()), check_vma=False,
     ))

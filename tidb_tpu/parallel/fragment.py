@@ -813,7 +813,7 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
         # (trace-time STATIC — callers key their fragment cache on it so
         # a knob flip can never serve a program traced for the other
         # strategy); None = the hash_probe process default
-        def frag(*args):
+        def frag_general(*args):
             env = {"scan": [], "bcast": [], "probe_mode": probe_mode}
             i = 0
             for _ in range(n_src):
@@ -842,7 +842,7 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
         # via ShardCache.get_fragment; the closure carries the compiled
         # plan description only — every array arrives as an argument
         return jax.jit(jax.shard_map(
-            frag, mesh=mesh, in_specs=in_specs, out_specs=(out_spec, P()),
+            frag_general, mesh=mesh, in_specs=in_specs, out_specs=(out_spec, P()),
             # pallas_call outputs carry no vma metadata; the fragment's
             # out_specs are the authority here
             check_vma=False,

@@ -26,6 +26,7 @@ from tidb_tpu.server import protocol as P
 from tidb_tpu.session import Session
 from tidb_tpu.session.sysvars import SysVarStore
 from tidb_tpu.storage.catalog import Catalog
+from tidb_tpu.utils import tracing
 
 __all__ = ["Server"]
 
@@ -289,29 +290,19 @@ class Server:
             stmt_id, params, types = P.parse_stmt_execute(
                 body, n_params, sess._stmt_types.get(stmt_id))
             sess._stmt_types[stmt_id] = types
-            # serving tier: admission control + micro-batching; the
-            # worker takes the catalog statement lock (this thread only
-            # parks on the result)
-            rs = self.scheduler.submit_prepared(sess, stmt_id, params)
         except TidbError as e:
             P.write_packet(conn, 1, P.err_packet(getattr(e, "code", 1105), str(e)))
             return
-        except Exception as e:  # engine bug — surface, don't kill the conn
+        except Exception as e:  # a packet we cannot decode: surface it
             traceback.print_exc()
             P.write_packet(conn, 1, P.err_packet(1105, f"internal error: {e}"))
             return
-        status = self._status(sess)
-        if rs is None:
-            P.write_packet(conn, 1, P.ok_packet(status=status))
-            return
-        types = rs.types or [None] * len(rs.names)
-        seq = P.write_packet(conn, 1, P.lenc_int(len(rs.names)))
-        for name, kind in zip(rs.names, types):
-            seq = P.write_packet(conn, seq, P.column_def41(name, P.binary_kind(kind)))
-        seq = P.write_packet(conn, seq, P.eof_packet(status=status))
-        for row in rs.rows:
-            seq = P.write_packet(conn, seq, P.binary_row(list(row), types))
-        P.write_packet(conn, seq, P.eof_packet(status=status))
+        # serving tier: admission control + micro-batching; the worker
+        # takes the catalog statement lock (this thread only parks on
+        # the result)
+        self._serve(conn, sess, ent[4] or "",
+                    lambda: self.scheduler.submit_prepared(sess, stmt_id, params),
+                    P.binary_kind, P.binary_row)
 
     @staticmethod
     def _status(sess: Session) -> int:
@@ -323,17 +314,39 @@ class Server:
         return status
 
     def _run_sql(self, conn: socket.socket, sess: Session, sql: str) -> None:
-        try:
-            # serving tier: bounded workers execute (and serialize on
-            # the catalog lock there); this thread does protocol I/O only
-            rs = self.scheduler.submit_query(sess, sql)
-        except TidbError as e:
-            P.write_packet(conn, 1, P.err_packet(getattr(e, "code", 1105), str(e)))
-            return
-        except Exception as e:  # engine bug — surface, don't kill the conn
-            traceback.print_exc()
-            P.write_packet(conn, 1, P.err_packet(1105, f"internal error: {e}"))
-            return
+        # serving tier: bounded workers execute (and serialize on the
+        # catalog lock there); this thread does protocol I/O only
+        self._serve(conn, sess, _text_digest(sql),
+                    lambda: self.scheduler.submit_query(sess, sql),
+                    P.mysql_type_of, lambda row, _types: P.text_row(row))
+
+    def _serve(self, conn, sess: Session, digest: str, submit,
+               column_type, encode_row) -> None:
+        """One decoded command, to its last result packet, under the
+        request's trace (root ``wire.stmt``): the scheduler's worker
+        records its spans into it, this thread the result's encoding
+        and writes."""
+        with sess.request_trace("wire.stmt", digest):
+            try:
+                rs = submit()
+            except TidbError as e:
+                err, code, msg = e, getattr(e, "code", 1105), str(e)
+            except Exception as e:  # engine bug — surface, don't kill the conn
+                traceback.print_exc()
+                err, code, msg = e, 1105, f"internal error: {e}"
+            else:
+                with tracing.span("wire.write"):
+                    self._write_result(conn, sess, rs, column_type, encode_row)
+                return
+            # the tail rule for errors: a statement that died in the
+            # session marked its trace there, under the same reason; one
+            # refused before it (admission, queue timeout) is marked here
+            tracing.keep(f"error:{type(err).__name__}")
+            with tracing.span("wire.write"):
+                P.write_packet(conn, 1, P.err_packet(code, msg))
+
+    def _write_result(self, conn, sess: Session, rs, column_type,
+                      encode_row) -> None:
         status = self._status(sess)
         if rs is None:
             P.write_packet(conn, 1, P.ok_packet(status=status))
@@ -341,8 +354,20 @@ class Server:
         types = rs.types or [None] * len(rs.names)
         seq = P.write_packet(conn, 1, P.lenc_int(len(rs.names)))
         for name, kind in zip(rs.names, types):
-            seq = P.write_packet(conn, seq, P.column_def41(name, P.mysql_type_of(kind)))
+            seq = P.write_packet(conn, seq, P.column_def41(name, column_type(kind)))
         seq = P.write_packet(conn, seq, P.eof_packet(status=status))
         for row in rs.rows:
-            seq = P.write_packet(conn, seq, P.text_row(list(row)))
+            seq = P.write_packet(conn, seq, encode_row(list(row), types))
         P.write_packet(conn, seq, P.eof_packet(status=status))
+
+
+def _text_digest(sql: str) -> str:
+    """The statement digest a text command's trace_id starts with (the
+    prepared path has it from prepare time). Bounded like the session's
+    own: megabyte bulk loads digest their raw text."""
+    from tidb_tpu.bindinfo import normalize_sql, sql_digest
+
+    try:
+        return sql_digest(sql if len(sql) > 16384 else normalize_sql(sql))
+    except Exception:  # noqa: BLE001 — a text the lexer refuses still
+        return ""      # runs, to its parse error, under an anon trace

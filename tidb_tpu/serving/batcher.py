@@ -33,6 +33,8 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from tidb_tpu.utils import tracing
+
 __all__ = ["Batcher", "BatchGroup", "Member"]
 
 
@@ -46,9 +48,9 @@ class _DmlFallback(Exception):
 class Member:
     """One admitted, coalescible statement waiting for its result."""
 
-    __slots__ = ("session", "stmt_id", "params", "info", "t0", "deadline",
-                 "group", "done", "result", "exc", "timed_out", "drop",
-                 "sql")
+    __slots__ = ("session", "stmt_id", "params", "info", "t0", "trace",
+                 "deadline", "group", "done", "result", "exc", "timed_out",
+                 "drop", "sql")
 
     def __init__(self, session, stmt_id: int, params: list, info,
                  deadline: Optional[float], sql: Optional[str] = None):
@@ -58,6 +60,7 @@ class Member:
         self.info = info                  # StmtInfo / DML spec from the probe
         self.sql = sql                    # text-protocol member (DML window)
         self.t0 = time.perf_counter()     # for the sched.queue span
+        self.trace = tracing.capture()    # the request's, for the worker
         self.deadline = deadline          # monotonic; None = unbounded
         self.group: Optional["BatchGroup"] = None
         self.done = threading.Event()
@@ -272,7 +275,9 @@ class Batcher:
         instantiation shape and one executor pipeline."""
         catalog = self.scheduler.catalog
         batch_id = next(self._seq)
+        claimed = time.perf_counter()
         with catalog.lock:
+            waits = (claimed, time.perf_counter())
             try:
                 shared = self._shared_pass(group, members)
             except Exception:  # noqa: BLE001 — ANY shared-pass failure
@@ -283,7 +288,7 @@ class Batcher:
             for i, m in enumerate(members):
                 runner = (None if shared is None
                           else self._member_runner(shared, i, n, batch_id, m))
-                self._finalize(m, runner)
+                self._finalize(m, runner, waits)
 
     def _shared_pass(self, group: BatchGroup, members: List[Member]):
         """The stacked-params pass. Returns a dict consumed by
@@ -395,7 +400,6 @@ class Batcher:
             if member.drop is not None:
                 raise member.drop
             from tidb_tpu.executor.base import ResultSet
-            from tidb_tpu.utils import tracing
 
             with tracing.span(f"sched.batch[n={n}]"):
                 tracing.annotate(f"batch:{batch_id} member:{i} "
@@ -428,11 +432,14 @@ class Batcher:
 
         return run
 
-    def _finalize(self, member: Member, runner) -> None:
+    def _finalize(self, member: Member, runner, waits) -> None:
         """Run one member through Session._execute_timed on this worker
         thread (the member's connection thread is parked on its done
         event). runner=None re-executes the statement singleton-style —
-        the shared-pass fallback."""
+        the shared-pass fallback. `waits`: when the worker claimed the
+        group and when it held the catalog lock — the member's trace
+        gets its share of both, and of the group's pass before its own
+        turn, as spans with their true starts."""
         import time as _time
 
         from tidb_tpu.errors import QueryKilledError, QueryTimeoutError
@@ -453,7 +460,16 @@ class Batcher:
             def runner(_stmt):  # noqa: F811 — fallback member, same drop
                 raise member.drop
         sess._stmt_runner = runner
-        sess._sched_queue_s = _time.perf_counter() - member.t0
+        if member.trace is not None:  # joined under a request's trace
+            trace, parent = member.trace
+            tracing.push(trace, parent)
+            claimed, locked = waits
+            for name, t0, t1 in (("sched.queue", member.t0, claimed),
+                                 ("sched.lock_wait", claimed, locked),
+                                 ("sched.batch_pass", locked,
+                                  _time.perf_counter())):
+                trace.add_complete(name, t0, t1 - t0,
+                                   parent_id=parent.span_id)
         try:
             if member.sql is not None:
                 res = sess.execute(member.sql)
@@ -464,8 +480,9 @@ class Batcher:
         else:
             member.finish(result=res)
         finally:
+            if member.trace is not None:
+                tracing.pop()
             sess._stmt_runner = None
-            sess._sched_queue_s = 0.0
 
     # -- group-commit DML (ISSUE 17) ------------------------------------
 
@@ -490,7 +507,9 @@ class Batcher:
         member re-executes singleton-style with its exact typed error."""
         catalog = self.scheduler.catalog
         batch_id = next(self._seq)
+        claimed = time.perf_counter()
         with catalog.lock:
+            waits = (claimed, time.perf_counter())
             try:
                 included = self._dml_pass(group, members)
             except Exception:  # noqa: BLE001 — ANY group-commit failure
@@ -501,7 +520,7 @@ class Batcher:
             for i, m in enumerate(members):
                 runner = (self._dml_runner(i, n, batch_id, m)
                           if included is not None and included[i] else None)
-                self._finalize(m, runner)
+                self._finalize(m, runner, waits)
 
     def _dml_pass(self, group: BatchGroup,
                   members: List[Member]) -> List[bool]:
@@ -612,8 +631,6 @@ class Batcher:
         def run(_stmt):
             if member.drop is not None:
                 raise member.drop
-            from tidb_tpu.utils import tracing
-
             with tracing.span(f"sched.batch[n={n}]"):
                 tracing.annotate(f"batch:{batch_id} member:{i} dml:applied")
                 return None

@@ -38,6 +38,7 @@ from tidb_tpu.errors import (
 )
 from tidb_tpu.serving.batcher import Batcher, BatchGroup
 from tidb_tpu.session.sysvars import SysVarStore
+from tidb_tpu.utils import tracing
 from tidb_tpu.utils.memory import MemTracker
 
 __all__ = ["StatementScheduler", "schedulers_alive"]
@@ -57,13 +58,17 @@ _QUEUED, _RUNNING, _DONE, _EVICTED = range(4)
 class _Task:
     """One queued singleton statement."""
 
-    __slots__ = ("session", "fn", "state", "t0", "done", "result", "exc")
+    __slots__ = ("session", "fn", "state", "t0", "trace", "done", "result",
+                 "exc")
 
     def __init__(self, session, fn):
         self.session = session
         self.fn = fn
         self.state = _QUEUED
         self.t0 = time.perf_counter()
+        # the request's trace rides to the worker (submit_* opened it,
+        # or the wire server around them)
+        self.trace = tracing.capture()
         self.done = threading.Event()
         self.result = None
         self.exc: Optional[BaseException] = None
@@ -201,38 +206,39 @@ class StatementScheduler:
         exactly as the thread-per-connection server did). Autocommit
         point writes may instead join a group-commit window (ISSUE 17)
         and ride one merged engine pass."""
-        self._admit(self._shed_digest(sess, sql=sql))
-        self._session_tracker(sess)
-        met = int(sess.sysvars.get("max_execution_time"))
-        deadline = (time.monotonic() + met / 1e3) if met > 0 else None
-        try:
-            member = self.batcher.try_join_dml(sess, sql, deadline)
-        except Exception:  # noqa: BLE001 — the probe must never lose a
-            member = None  # statement; singleton fallback handles it
-        if member is not None:
-            return self._await_member(member)
-        task = _Task(sess, lambda: sess.execute(sql))
-        self._enqueue_task(task)
-        return self._await_task(task)
+        return self._submit(
+            sess, self._shed_digest(sess, sql=sql),
+            lambda deadline: self.batcher.try_join_dml(sess, sql, deadline),
+            lambda: sess.execute(sql))
 
     def submit_prepared(self, sess, stmt_id: int, params: list):
         """Binary-protocol execution: coalescible statements join a
         batch group; everything else runs singleton."""
-        self._admit(self._shed_digest(sess, stmt_id=stmt_id))
-        self._session_tracker(sess)
-        met = int(sess.sysvars.get("max_execution_time"))
-        deadline = (time.monotonic() + met / 1e3) if met > 0 else None
-        try:
-            member = self.batcher.try_join(sess, stmt_id, list(params),
-                                           deadline)
-        except Exception:  # noqa: BLE001 — the probe must never lose a
-            member = None  # statement; singleton fallback handles it
-        if member is not None:
-            return self._await_member(member)
-        task = _Task(sess, lambda: sess.execute_prepared(stmt_id,
-                                                         list(params)))
-        self._enqueue_task(task)
-        return self._await_task(task)
+        return self._submit(
+            sess, self._shed_digest(sess, stmt_id=stmt_id),
+            lambda deadline: self.batcher.try_join(
+                sess, stmt_id, list(params), deadline),
+            lambda: sess.execute_prepared(stmt_id, list(params)))
+
+    def _submit(self, sess, shed_digest: str, try_join, run):
+        """Admit, then batch member or singleton task, and wait — under
+        the request's trace, which the task or member carries to the
+        worker: the wire server's, or (the scheduler driven without
+        one) a trace of this call's own, root ``sched.stmt``."""
+        with sess.request_trace("sched.stmt"):
+            self._admit(shed_digest)
+            self._session_tracker(sess)
+            met = int(sess.sysvars.get("max_execution_time"))
+            deadline = (time.monotonic() + met / 1e3) if met > 0 else None
+            try:
+                member = try_join(deadline)
+            except Exception:  # noqa: BLE001 — the probe must never lose
+                member = None  # a statement; singleton fallback handles it
+            if member is not None:
+                return self._await_member(member)
+            task = _Task(sess, run)
+            self._enqueue_task(task)
+            return self._await_task(task)
 
     # -- waiting ---------------------------------------------------------
 
@@ -323,17 +329,24 @@ class StatementScheduler:
                 return  # evicted by a queue timeout
             task.state = _RUNNING
         self._unqueue()
-        task.session._sched_queue_s = time.perf_counter() - task.t0
+        claimed = time.perf_counter()
+        trace, parent = task.trace
+        tracing.push(trace, parent)
         try:
+            trace.add_complete("sched.queue", task.t0, claimed - task.t0,
+                               parent_id=parent.span_id)
             # the storage layer is single-writer: statements across
             # sessions serialize on the catalog statement lock, exactly
-            # as the thread-per-connection server did
+            # as the thread-per-connection server did. The span is the
+            # wait for the lock, not the statement under it.
+            waiting = tracing.begin("sched.lock_wait")
             with self.catalog.lock:
+                tracing.finish(waiting)
                 task.result = task.fn()
         except BaseException as e:  # noqa: BLE001 — relayed verbatim to
             task.exc = e            # the submitting connection thread
         finally:
-            task.session._sched_queue_s = 0.0
+            tracing.pop()
             task.state = _DONE
             task.done.set()
 

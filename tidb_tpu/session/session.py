@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import List, Optional
 
@@ -343,9 +344,6 @@ class Session:
         # parent for per-statement MemTrackers (the scheduler's
         # session-level tracker, itself a child of the server tracker)
         self._mem_parent = None
-        # scheduler queue wait of the statement about to execute
-        # (seconds); _execute_timed consumes it into a sched.queue span
-        self._sched_queue_s = 0.0
         # statement deadline (monotonic seconds) armed per statement
         # from max_execution_time; None = unbounded
         self._stmt_deadline: Optional[float] = None
@@ -522,9 +520,11 @@ class Session:
         import time as _time
 
         from tidb_tpu.utils import metrics as M
+        from tidb_tpu.utils import tracing
 
         t0 = _time.perf_counter()
-        stmts = parse(sql)
+        with tracing.span("session.parse"):
+            stmts = parse(sql)
         M.PARSE_SECONDS.observe(_time.perf_counter() - t0)
         result = None
         for stmt in stmts:
@@ -532,10 +532,9 @@ class Session:
         return result
 
     def _execute_timed(self, stmt, sql: str) -> Optional[ResultSet]:
-        """Metrics + slow-query log + optional jax.profiler around one
+        """Metrics + slow-query log + the statement's spans around one
         statement (ref: the server-layer duration histograms and the
         slow-query log with per-phase durations)."""
-        import contextlib
         import time as _time
 
         from tidb_tpu.utils import metrics as M
@@ -569,12 +568,6 @@ class Session:
         self._current_t0 = _time.time()
         stype = type(stmt).__name__.removesuffix("Stmt").lower()
         self.catalog.plugins.statement_begin(self, sql, stype)
-        prof_dir = str(self.sysvars.get("tidb_profile_dir"))
-        ctx = contextlib.nullcontext()
-        if prof_dir:
-            import jax
-
-            ctx = jax.profiler.trace(prof_dir)
         self._stmt_trackers = []
         self._last_plan_digest = None
         self._plan_from_cache_stmt = False
@@ -600,8 +593,9 @@ class Session:
         # always-on tracing (utils/tracing.py): every statement RECORDS
         # a span tree; tail rules / head sampling decide at the end
         # whether it is kept. A statement arriving with a trace already
-        # installed (a DCN worker serving a traced RPC, Cluster.query
-        # inside a statement) nests instead of owning.
+        # installed (the wire server's or the scheduler's request trace,
+        # a DCN worker serving a traced RPC, Cluster.query inside a
+        # statement) nests instead of owning.
         from tidb_tpu.utils import tracing
 
         try:
@@ -615,15 +609,9 @@ class Session:
             tr = tracing.Trace(tracing.make_trace_id(digest_now),
                                sampled=tracing.head_sampled(rate))
             tracing.push(tr)
-        stmt_span = tracing.begin(f"stmt.{stype}")
-        q_s, self._sched_queue_s = self._sched_queue_s, 0.0
-        if q_s > 0 and tr is not None and stmt_span is not None:
-            # the scheduler queue wait happened BEFORE this trace
-            # existed; anchor the span at the trace start so offsets
-            # stay non-negative and the wait is still visible
-            qs = tr.add_complete("sched.queue", tr.t0_perf, q_s,
-                                 parent_id=stmt_span.span_id)
-            qs.notes.append(f"queued {int(q_s * 1e6)}us before execution")
+            stmt_span = tracing.begin(f"stmt.{stype}", trace_id=tr.trace_id)
+        else:
+            stmt_span = tracing.begin(f"stmt.{stype}")
         d0 = _dsp.count()
         f0 = _dsp.by_site().get("fragment", 0)
         from tidb_tpu.columnar.store import compact_counts as _cmp_counts
@@ -652,10 +640,9 @@ class Session:
         self.catalog.reader_enter()
         t0 = _time.perf_counter()
         try:
-            with ctx:
-                runner = self._stmt_runner
-                result = (self._execute_stmt(stmt) if runner is None
-                          else runner(stmt))
+            runner = self._stmt_runner
+            result = (self._execute_stmt(stmt) if runner is None
+                      else runner(stmt))
         except Exception as exc:
             dur = _time.perf_counter() - t0
             M.QUERY_TOTAL.inc(type=stype, status="error")
@@ -865,12 +852,16 @@ class Session:
         """Close the statement span; when this statement OWNS the trace,
         apply the tail rules (slow / error; retry-failover keeps were
         set where they happened), pop it off the thread, and store it if
-        kept. Returns the trace_id for the slow-log row."""
+        kept. Under a request's trace the owner applies them where the
+        root closes (request_trace); an error is marked here, where it
+        is known. Returns the trace_id for the slow-log row."""
         from tidb_tpu.utils import tracing
 
         try:
             tracing.finish(stmt_span)
             if not owns or tr is None:
+                if tr is not None and error is not None:
+                    tr.keep(f"error:{type(error).__name__}")
                 return tr.trace_id if tr is not None else ""
             return tracing.apply_tail_rules(
                 tr, dur_s,
@@ -879,6 +870,40 @@ class Session:
                 capacity=int(self.sysvars.get("tidb_trace_store_capacity")))
         except Exception:  # noqa: BLE001 — diagnostics never fail a stmt
             return ""
+
+    @contextlib.contextmanager
+    def request_trace(self, root: str, digest: str = ""):
+        """Own one request's trace on the calling thread, from here to
+        the end of the block, under the root span `root` — unless a
+        trace is installed already (the scheduler's submit under the
+        wire server's): then the block just runs under that one. The
+        tail rules run once, where the root closes, with this session's
+        thresholds: "slow" is judged on the request's whole time, queue
+        and lock waits and the result's write included, as the client
+        felt it."""
+        from tidb_tpu.utils import tracing
+
+        if tracing.current() is not None:
+            yield
+            return
+        rate = float(self.sysvars.get("tidb_trace_sample_rate"))
+        tr = tracing.Trace(tracing.make_trace_id(digest),
+                           sampled=tracing.head_sampled(rate))
+        tracing.push(tr)
+        span = tracing.begin(root, trace_id=tr.trace_id)
+        try:
+            yield
+        finally:
+            try:
+                tracing.finish(span)
+                tracing.apply_tail_rules(
+                    tr, span.dur_us / 1e6,
+                    int(self.sysvars.get("tidb_slow_log_threshold")),
+                    capacity=int(
+                        self.sysvars.get("tidb_trace_store_capacity")))
+            finally:
+                if tracing.current() is tr:
+                    tracing.pop()
 
     def _record_stmt(self, stmt, sql: str, stype: str, dur: float,
                      d0: int, f0: int, result, seg0=(0, 0),
@@ -2090,9 +2115,11 @@ class Session:
         import time as _time
 
         from tidb_tpu.utils import metrics as M
+        from tidb_tpu.utils import tracing
 
         t0 = _time.perf_counter()
-        stmts = parse(sql)
+        with tracing.span("session.parse"):
+            stmts = parse(sql)
         M.PARSE_SECONDS.observe(_time.perf_counter() - t0)
         if len(stmts) != 1:
             raise UnsupportedError("PREPARE requires exactly one statement")

@@ -102,8 +102,6 @@ _reg(
     # whether the previous SELECT's plan came from the plan cache
     # (read via @@last_plan_from_cache, like the reference)
     SysVar("last_plan_from_cache", False, SESSION, "bool"),
-    # non-empty: wrap query execution in jax.profiler.trace(dir)
-    SysVar("tidb_profile_dir", "", BOTH, "str"),
     # tables above this size stream through fixed [P,R] staging batches
     # instead of residing wholly in device memory (the >HBM path)
     SysVar("tidb_device_cache_bytes", 8 << 30, BOTH, "int",

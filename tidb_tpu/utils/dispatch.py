@@ -17,16 +17,18 @@ knob the reference gets from its coprocessor request counters
 
 from __future__ import annotations
 
+import contextlib
+import threading
 import time
 from typing import Callable
 
 import jax
 
-__all__ = ["record", "count", "counted_jit", "record_xfer", "xfer_bytes",
-           "record_fetch", "record_spill", "spill_bytes",
-           "compile_seconds"]
+from tidb_tpu.utils import tracing
 
-import threading
+__all__ = ["record", "launch", "count", "counted_jit", "record_xfer",
+           "xfer_bytes", "record_fetch", "device_get", "record_spill", "spill_bytes",
+           "compile_seconds"]
 
 # thread-local: the server runs each connection's queries on its own
 # thread, so per-operator EXPLAIN ANALYZE deltas must not absorb a
@@ -48,6 +50,25 @@ def record(n: int = 1, site: str = "other") -> None:
     from tidb_tpu.utils.metrics import DISPATCH_TOTAL
 
     DISPATCH_TOTAL.inc(n, site=site)
+
+
+class launch(tracing.span):
+    """``with launch(site)``: one device round trip, counted, and timed
+    as the span ``dispatch.<site>`` of the statement's trace — the
+    count and the span are made by the same line, so a statement's
+    ``dispatch.*`` spans number its dispatches. The span covers the
+    host's part (the launch is asynchronous); the wait for the device
+    is ``device.wait`` (``device_get``)."""
+
+    __slots__ = ("_site",)
+
+    def __init__(self, site: str):
+        super().__init__("dispatch." + site)
+        self._site = site
+
+    def __enter__(self):
+        record(site=self._site)
+        return super().__enter__()
 
 
 def event(site: str) -> None:
@@ -88,13 +109,26 @@ def xfer_bytes() -> int:
 
 def record_fetch(tree):
     """Record a COMPLETED device→host fetch's bytes (d2h) and return
-    the tree unchanged — wraps the sanctioned ``jax.device_get`` sites
-    (the arrays are host-resident by the time this sums nbytes, so the
-    accounting itself never blocks)."""
+    the tree unchanged (the arrays are host-resident by the time this
+    sums nbytes, so the accounting itself never blocks)."""
     n = sum(getattr(leaf, "nbytes", 0)
             for leaf in jax.tree_util.tree_leaves(tree))
     record_xfer(n, "d2h")
     return tree
+
+
+def device_get(tree, counted: bool = True):
+    """The one place where the host waits for the device:
+    ``jax.device_get`` (a whole pytree in one transfer) inside the span
+    ``device.wait``, the fetched bytes booked, and — `counted` — the
+    round trip as a ``fetch`` dispatch. Not `counted`: the per-chunk
+    fetches of the host tiers and the exchange-overflow scalar, which
+    the dispatch budget (O(1) a statement) has never held. The lint
+    passes know the fetch by this name, as they know jax's."""
+    with launch("fetch") if counted else contextlib.nullcontext():
+        with tracing.span("device.wait"):
+            host = jax.device_get(tree)
+        return record_fetch(host)
 
 
 def record_spill(nbytes: int) -> None:
@@ -115,8 +149,6 @@ def record_compile(kernel: str = "join") -> None:
     each operator to surface per-operator recompiles, and the statement
     trace (if one is active) gets the event as a span annotation."""
     _tls.compiles = getattr(_tls, "compiles", 0) + 1
-    from tidb_tpu.utils import tracing
-
     tracing.annotate(f"recompile:{kernel}")
 
 
@@ -151,18 +183,19 @@ def counted_jit(fn: Callable, site: str = "jit", **jit_kwargs) -> Callable:
     sizer = getattr(jitted, "_cache_size", None)
 
     def counted(*args, **kwargs):
-        record(site=site)
-        if sizer is None:
-            return jitted(*args, **kwargs)
-        # compile-seconds attribution (ISSUE 16): a growing executable
-        # cache means THIS invocation paid a trace+compile — charge its
-        # wall time to the triggering statement's thread. Warm calls
-        # pay two perf_counter reads and one C++ cache-size probe.
-        n0 = sizer()
-        t0 = time.perf_counter()
-        out = jitted(*args, **kwargs)
-        if sizer() > n0:
-            _record_compile_seconds(time.perf_counter() - t0)
-        return out
+        with launch(site):
+            if sizer is None:
+                return jitted(*args, **kwargs)
+            # compile-seconds attribution (ISSUE 16): a growing
+            # executable cache means THIS invocation paid a
+            # trace+compile — charge its wall time to the triggering
+            # statement's thread. Warm calls pay two perf_counter reads
+            # and one C++ cache-size probe.
+            n0 = sizer()
+            t0 = time.perf_counter()
+            out = jitted(*args, **kwargs)
+            if sizer() > n0:
+                _record_compile_seconds(time.perf_counter() - t0)
+            return out
 
     return counted

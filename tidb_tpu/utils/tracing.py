@@ -21,31 +21,46 @@ Building blocks:
     process-local span ids so one cross-process tree comes out.
   * ``TraceStore`` — capacity-bounded ring of KEPT traces, surfaced by
     the status port's ``/trace`` endpoint and
-    ``information_schema.cluster_trace``.
+    ``information_schema.cluster_trace``; beside it a second ring of
+    EVERY finished root trace, kept or not, for a reader that wants all
+    statements of a window (``benchmarks/program_spans.py``).
 
 Thread-local context: ``push``/``pop`` install a trace (plus current
 parent span) on the calling thread; ``span()``/``annotate()``/
-``current()`` read it. Code running on other threads (DCN dispatch
-fan-out) records spans directly on the Trace object with explicit
-parent ids instead.
+``current()`` read it. A request that crosses threads (connection
+thread -> scheduler worker) hands ``capture()``'s pair to the other
+thread, which ``push``es it. Code running on other threads (DCN
+dispatch fan-out) records spans directly on the Trace object with
+explicit parent ids instead.
+
+The profiler's clock: every span opened through ``begin``/``span()``
+opens and closes on one thread, so it is also a
+``jax.profiler.TraceAnnotation("tidb." + name)``. Outside a profiler
+session that is an inert check of one flag; inside one, the host plane
+of the ``.xplane.pb`` holds the span tree on the device ops' clock.
+Spans recorded after the fact (``add_complete``: they cross threads or
+were timed by other code) are on the host clock only.
 
 The off path must stay near-free: with no trace installed every hook is
-one thread-local read and a None check — the bench.py warm join
-microbench gates tracing overhead with sampling off at <= 2%.
+one thread-local read and a None check. What it costs when on, measured
+on the chip (PR 25, PERF.md section 6): with a profiler session open the
+TPC-H scan cell completed 136-137 statements in 35 s, as untraced and as
+the parent commit (137); no end-to-end metric moved.
 """
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import random
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "Trace", "TraceStore", "STORE", "current", "push",
-           "pop", "span", "begin", "finish", "annotate",
+           "pop", "span", "begin", "finish", "annotate", "capture",
            "current_span_id", "head_sampled", "make_trace_id", "keep",
            "current_trace_id"]
 
@@ -74,7 +89,7 @@ def head_sampled(rate: float) -> bool:
 
 class Span:
     __slots__ = ("span_id", "parent_id", "name", "start_us", "dur_us",
-                 "proc", "notes")
+                 "proc", "notes", "ann")
 
     def __init__(self, span_id: int, parent_id: Optional[int], name: str,
                  start_us: int):
@@ -85,6 +100,7 @@ class Span:
         self.dur_us = -1  # -1: still open
         self.proc = ""    # "" = this process; set on graft to the endpoint
         self.notes: List[str] = []
+        self.ann = None   # the open TraceAnnotation (begin .. finish)
 
 
 class _NullNotes(list):
@@ -230,8 +246,54 @@ class Trace:
         start = min((s.start_us for s in roots), default=0)
         return round((end - start) / 1e3, 3)
 
+    def root(self) -> Optional[Span]:
+        return next((s for s in self.spans if s.parent_id is None), None)
+
+    def interval_perf(self) -> Tuple[float, float]:
+        """(start, end) of the root span on ``time.perf_counter``'s
+        clock: where this trace lies among a driver's own timestamps."""
+        root = self.root()
+        if root is None:
+            return self.t0_perf, self.t0_perf
+        start = self.t0_perf + root.start_us / 1e6
+        return start, start + max(root.dur_us, 0) / 1e6
+
+    def self_us(self) -> Dict[int, int]:
+        """span_id -> self time: the span's duration minus the part of
+        it that its child spans cover (overlapping children count once).
+        In a tree recorded live the self times sum to the root's
+        duration; envelopes grafted afterwards (TRACE's ``executor.*``
+        operators, a worker's remote spans) overlap their siblings and
+        add to the sum."""
+        with self._lock:
+            spans = list(self.spans)
+        kids: Dict[Optional[int], List[Span]] = {}
+        for s in spans:
+            kids.setdefault(s.parent_id, []).append(s)
+        out = {}
+        for s in spans:
+            end = s.start_us + max(s.dur_us, 0)
+            covered, at = 0, s.start_us
+            for c in sorted(kids.get(s.span_id, ()),
+                            key=lambda c: c.start_us):
+                lo = max(c.start_us, at)
+                hi = min(c.start_us + max(c.dur_us, 0), end)
+                if hi > lo:
+                    covered += hi - lo
+                    at = hi
+            out[s.span_id] = end - s.start_us - covered
+        return out
+
+    def self_us_by_name(self) -> Dict[str, int]:
+        """Self time summed by span name (see ``self_us``)."""
+        by_id = self.self_us()
+        out: Dict[str, int] = {}
+        for s in list(self.spans):
+            out[s.name] = out.get(s.name, 0) + by_id.get(s.span_id, 0)
+        return out
+
     def summary(self) -> Dict:
-        root = next((s for s in self.spans if s.parent_id is None), None)
+        root = self.root()
         return {
             "trace_id": self.trace_id,
             "start": time.strftime("%Y-%m-%d %H:%M:%S",
@@ -248,11 +310,13 @@ class Trace:
         """Full JSON form: summary + the span TREE (children nested)."""
         with self._lock:
             spans = list(self.spans)
+        self_us = self.self_us()
         nodes = {}
         for s in spans:
             nodes[s.span_id] = {
                 "span_id": s.span_id, "name": s.name, "proc": s.proc,
                 "start_us": s.start_us, "duration_us": max(s.dur_us, 0),
+                "self_us": self_us.get(s.span_id, 0),
                 "annotations": list(s.notes), "children": [],
             }
         roots = []
@@ -276,14 +340,35 @@ def push(trace: Trace, span_: Optional[Span] = None) -> None:
     stack = getattr(_tls, "stack", None)
     if stack is None:
         stack = _tls.stack = []
-    stack.append((trace, [span_] if span_ is not None else []))
+    # the third field: how many spans of the list this thread inherited
+    # (another thread opened `span_` and will finish it)
+    stack.append((trace, [span_] if span_ is not None else [],
+                  int(span_ is not None)))
 
 
 def pop() -> Optional[Trace]:
+    """Uninstall the thread's current trace. Spans this thread opened
+    under it and never finished (a non-local exit) are closed here, so
+    an errored or killed statement leaves neither an open span nor an
+    open annotation behind."""
     stack = getattr(_tls, "stack", None)
     if not stack:
         return None
-    return stack.pop()[0]
+    trace, spans, inherited = stack.pop()
+    while len(spans) > inherited:
+        _leave(trace, spans.pop())
+    return trace
+
+
+def capture() -> Optional[Tuple[Trace, Optional[Span]]]:
+    """This thread's (trace, innermost open span), for another thread
+    to ``push(*pair)``: the spans it records then nest under the span
+    that handed the work over. None without a trace."""
+    stack = getattr(_tls, "stack", None)
+    if not stack:
+        return None
+    trace, spans, _ = stack[-1]
+    return trace, (spans[-1] if spans else None)
 
 
 def current() -> Optional[Trace]:
@@ -306,48 +391,63 @@ def current_trace_id() -> str:
     return tr.trace_id if tr is not None else ""
 
 
-def begin(name: str) -> Optional[Span]:
+def begin(name: str, **args) -> Optional[Span]:
     """Open a span under the thread's current trace and make it the
-    parent for subsequent spans. Pair with finish(); for block-scoped
-    spans prefer the span() context manager. None without a trace."""
+    parent for subsequent spans; the same interval goes to the profiler
+    as the annotation ``tidb.<name>`` with `args` (module doc). Pair
+    with finish() on this thread; for block-scoped spans prefer the
+    span() context manager. None without a trace."""
     stack = getattr(_tls, "stack", None)
     if not stack:
         return None
-    trace, spans = stack[-1]
+    trace, spans, _ = stack[-1]
     s = trace.begin(name, spans[-1].span_id if spans else None)
+    if s is not _DROPPED:
+        s.ann = TraceAnnotation("tidb." + name, **args)
+        s.ann.__enter__()
     spans.append(s)
     return s
+
+
+def _leave(trace: Trace, s: Span) -> None:
+    ann, s.ann = s.ann, None
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    trace.end(s)
 
 
 def finish(s: Optional[Span]) -> None:
     stack = getattr(_tls, "stack", None)
     if s is None or not stack:
         return
-    trace, spans = stack[-1]
+    trace, spans, _ = stack[-1]
     if s in spans:
         # pop through any child spans a non-local exit left open
         while spans and spans[-1] is not s:
-            trace.end(spans.pop())
+            _leave(trace, spans.pop())
         spans.pop()
-    trace.end(s)
+    _leave(trace, s)
 
 
-@contextlib.contextmanager
-def span(name: str):
-    """Span under the thread's current trace; no-op when none is
-    installed (the off path: one TLS read + None check)."""
-    stack = getattr(_tls, "stack", None)
-    if not stack:
-        yield None
-        return
-    trace, spans = stack[-1]
-    s = trace.begin(name, spans[-1].span_id if spans else None)
-    spans.append(s)
-    try:
-        yield s
-    finally:
-        spans.pop()
-        trace.end(s)
+class span:
+    """``with span(name) as s``: a span under the thread's current
+    trace, begun and finished by the block; no-op (`s` is None) when
+    none is installed — the off path: one TLS read + None check. A
+    class, not a generator: every kernel launch passes here, and a
+    generator's context manager costs a microsecond."""
+
+    __slots__ = ("_name", "_span")
+
+    def __init__(self, name: str):
+        self._name = name
+
+    def __enter__(self) -> Optional[Span]:
+        self._span = begin(self._name)
+        return self._span
+
+    def __exit__(self, *_exc) -> bool:
+        finish(self._span)
+        return False
 
 
 def annotate(note: str) -> None:
@@ -356,7 +456,7 @@ def annotate(note: str) -> None:
     stack = getattr(_tls, "stack", None)
     if not stack:
         return
-    trace, spans = stack[-1]
+    trace, spans, _ = stack[-1]
     target = spans[-1] if spans else (trace.spans[0] if trace.spans else None)
     if target is not None and target is not _DROPPED:
         target.notes.append(note)
@@ -375,12 +475,27 @@ def keep(reason: str) -> None:
 
 
 class TraceStore:
-    """Capacity-bounded ring of kept traces (newest wins)."""
+    """Capacity-bounded ring of kept traces (newest wins), and beside
+    it the ring of every finished root trace, kept or not."""
+
+    # a window of a benchmark run or an hour of a dashboard's traffic;
+    # at some twenty spans a trace, a few tens of MB at most
+    FINISHED_CAPACITY = 4096
 
     def __init__(self, capacity: int = 64):
         self.lock = threading.Lock()
         self.capacity = capacity
         self._ring: deque = deque()
+        self._finished: deque = deque(maxlen=self.FINISHED_CAPACITY)
+
+    def note_finished(self, trace: Trace) -> None:
+        # no lock: a bounded deque's append is atomic in CPython, and
+        # every statement passes here
+        self._finished.append(trace)
+
+    def finished(self) -> List[Trace]:
+        """Every root trace that finished lately, oldest first."""
+        return list(self._finished)
 
     def add(self, trace: Trace, capacity: Optional[int] = None) -> None:
         from tidb_tpu.utils.metrics import TRACE_KEPT_TOTAL
@@ -415,6 +530,7 @@ class TraceStore:
     def clear(self) -> None:
         with self.lock:
             self._ring.clear()
+        self._finished.clear()
 
     def __len__(self) -> int:
         with self.lock:
@@ -431,7 +547,8 @@ def apply_tail_rules(tr: Trace, dur_s: float, threshold_ms: int,
     """The ONE end-of-statement keep sequence, shared by
     Session._execute_timed and standalone Cluster.query (two copies
     would drift): error keep -> slow keep -> pop off the thread ->
-    head-sample keep -> store if kept. Returns the trace_id."""
+    head-sample keep -> the ring of finished traces -> store if kept.
+    Returns the trace_id."""
     if error is not None:
         tr.keep(f"error:{type(error).__name__}")
     if dur_s * 1e3 >= threshold_ms:
@@ -440,6 +557,7 @@ def apply_tail_rules(tr: Trace, dur_s: float, threshold_ms: int,
         pop()
     if tr.sampled:
         tr.keep("sampled")
+    STORE.note_finished(tr)
     if tr.kept:
         STORE.add(tr, capacity=capacity)
     return tr.trace_id
