@@ -331,43 +331,40 @@ def test_fused_scan_agg_program_compiles(one_chip, tpu_target, tiny_tpch):
         assert "tpu_custom_call" in c.as_text()  # the Pallas segment sum
 
 
-def _q1_fragment_args(catalog, mesh1):
-    """make_agg_fragment's arguments as the engine plans Q1 on a mesh."""
-    from tidb_tpu.parallel import executor as pe
-    from tidb_tpu.session import Session
-    from tidb_tpu.storage.tpch_queries import Q
+def _described_table(st, mesh, n_dev, rows):
+    """(`st` as [n_dev, rows] parts on `mesh`, the shapes of its data,
+    valid, sel and refs there): what a mesh fragment's maker and its
+    jitted function take for one table."""
+    import dataclasses
 
-    s = Session(catalog=catalog, mesh=mesh1)
-    with _capture(pe, "make_agg_fragment") as made:
-        s.query(Q["q1"][0])
-    assert made, "Q1 did not take the mesh scan->agg fragment"
-    return made[-1]
+    from tidb_tpu.parallel.distsql import _SPEC
+
+    sharded = _sds(NamedSharding(mesh, _SPEC))
+    repl = NamedSharding(mesh, P())
+    shapes = [{n: sharded((n_dev, rows), a.dtype) for n, a in st.data.items()},
+              {n: sharded((n_dev, rows), a.dtype) for n, a in st.valid.items()},
+              sharded((n_dev, rows), jnp.bool_),
+              {n: jax.ShapeDtypeStruct((), np.int64, sharding=repl)
+               for n in st.refs}]
+    return dataclasses.replace(st, mesh=mesh, n_parts=n_dev,
+                               rows_per_part=rows), shapes
 
 
 @pytest.mark.parametrize("n_dev", [1, 4], ids=["1x1", "1x4"])
 def test_mesh_q1_fragment_compiles(topo, tpu_target, tiny_tpch, n_dev):
     """Q1 as the mesh tier runs it: shard_map(scan -> filter -> segment
     agg -> psum merge) over the whole SF1 lineitem as [P, R/P]."""
-    import dataclasses
-
     from tidb_tpu.parallel import make_mesh
-    from tidb_tpu.parallel.distsql import _SPEC, make_agg_fragment
+    from tidb_tpu.parallel.distsql import make_agg_fragment
 
-    (st, stages, group_exprs, aggs, domains), kw = _q1_fragment_args(
-        tiny_tpch, make_mesh(devices=jax.devices()[:1]))
+    (st, stages, group_exprs, aggs, domains), kw, _ = _planned_fragment(
+        tiny_tpch, "make_agg_fragment")
     mesh = make_mesh(devices=topo.devices[:n_dev])
-    rows = -(-LINEITEM_SF1 // n_dev)
-    sharded = _sds(NamedSharding(mesh, _SPEC))
-    repl = NamedSharding(mesh, P())
-    described = dataclasses.replace(st, mesh=mesh, n_parts=n_dev,
-                                    rows_per_part=rows)
+    described, shapes = _described_table(st, mesh, n_dev,
+                                         -(-LINEITEM_SF1 // n_dev))
     fn = make_agg_fragment(described, stages, group_exprs, aggs, domains,
                            **kw)
-    data = {n: sharded((n_dev, rows), a.dtype) for n, a in st.data.items()}
-    valid = {n: sharded((n_dev, rows), a.dtype) for n, a in st.valid.items()}
-    refs = {n: jax.ShapeDtypeStruct((), np.int64, sharding=repl)
-            for n in st.refs}
-    c = _compile(fn, data, valid, sharded((n_dev, rows), jnp.bool_), refs)
+    c = _compile(fn, *shapes)
     text = c.as_text()
     assert "tpu_custom_call" in text
     if n_dev > 1:
@@ -380,12 +377,29 @@ STAGE_SCOPES = {
         "scan", "exchange.probe/exchange.sort", "exchange.probe/exchange.scatter",
         "exchange.probe/exchange.all_to_all", "exchange.build/exchange.sort",
         "exchange.build/exchange.scatter", "exchange.build/exchange.all_to_all",
-        "join.build_sort", "join.probe", "join.gather", "agg.update",
+        "join.sort", "join.probe", "join.unsort", "join.gather", "agg.update",
         "agg.merge"]),
 }
 # a build-side column above the join, so that the gather has work
 JOIN_SQL = ("select count(*), sum(l_quantity), max(o_totalprice) from lineitem"
             " join orders on l_orderkey = o_orderkey where o_totalprice > 100000")
+
+
+def _planned_fragment(catalog, maker):
+    """(arguments, keywords, sharded tables) of the engine's own call of
+    parallel/executor's `maker` for Q1 / JOIN_SQL on a 1x1 CPU mesh."""
+    from tidb_tpu.parallel import executor as pe
+    from tidb_tpu.parallel import make_mesh
+    from tidb_tpu.session import Session
+    from tidb_tpu.storage.tpch_queries import Q
+
+    s = Session(catalog=catalog, mesh=make_mesh(devices=jax.devices()[:1]))
+    s.execute("set tidb_device_engine_mode = 'force'")
+    with force_platform("cpu"), _capture(pe, maker) as made:
+        s.query(Q["q1"][0] if maker == "make_agg_fragment" else JOIN_SQL)
+    assert made, f"the statement did not take {maker}"
+    args, kw = made[-1]
+    return args, kw, [a for a in args if hasattr(a, "rows_per_part")]
 
 
 @pytest.mark.parametrize("maker", sorted(STAGE_SCOPES))
@@ -394,20 +408,12 @@ def test_fragment_program_is_named_and_its_stages_are_scoped(tiny_tpch, maker):
     for the fragment's kind (``jit_frag_join_agg``, not ``jit_per_shard``)
     and every op's metadata carries the stage that emitted it."""
     from tidb_tpu.parallel import executor as pe
-    from tidb_tpu.parallel import make_mesh
-    from tidb_tpu.session import Session
-    from tidb_tpu.storage.tpch_queries import Q
 
-    s = Session(catalog=tiny_tpch, mesh=make_mesh(devices=jax.devices()[:1]))
-    s.execute("set tidb_device_engine_mode = 'force'")
+    args, kw, tables = _planned_fragment(tiny_tpch, maker)
     # lowered for the CPU the statement ran on, whatever the module's
     # other tests trace for: names and scopes are the platform's no more
     # than the plan's
-    with force_platform("cpu"), _capture(pe, maker) as made:
-        s.query(Q["q1"][0] if maker == "make_agg_fragment" else JOIN_SQL)
-        assert made, f"the statement did not take {maker}"
-        args, kw = made[-1]
-        tables = [a for a in args if hasattr(a, "rows_per_part")]
+    with force_platform("cpu"):
         fn = getattr(pe, maker)(*args, **kw)
         text = fn.lower(*[x for st in tables for x in
                           (st.data, st.valid, st.sel, st.refs)]).as_text(
@@ -416,6 +422,49 @@ def test_fragment_program_is_named_and_its_stages_are_scoped(tiny_tpch, maker):
     assert f"module @jit_{name} " in text
     for scope in scopes:
         assert f"jit({name})/{scope}/" in text, scope
+
+
+@pytest.mark.parametrize("n_dev", [1, 4], ids=["1x1", "1x4"])
+def test_join_fragment_ranks_without_search(topo, tpu_target, tiny_tpch,
+                                            n_dev):
+    """What PR 26 bought, pinned in the chip's own compiled text: the
+    local join (`join.sort`, `join.probe`, `join.unsort`) holds sorts and
+    elementwise passes only — no `while` (a binary search kept as a loop,
+    as the four-chip program had it), no gather (the same loop unrolled,
+    as the one-chip program had it: 22-28 dependent rounds over every
+    probe slot, 80% of a 10.3 s statement) and no scatter (which this
+    compiler lowers through a sort of its own). The one gather a build
+    column above the join needs stays under `join.gather`. Shapes: 2,048
+    probe and 512 build rows a chip, where the compiler takes a sort in
+    seconds."""
+    import re
+
+    from tidb_tpu.parallel import make_mesh
+    from tidb_tpu.parallel.distsql import make_join_agg_fragment
+
+    args, kw, (probe, build) = _planned_fragment(tiny_tpch,
+                                                 "make_join_agg_fragment")
+    mesh = make_mesh(devices=topo.devices[:n_dev])
+    probe, p_shapes = _described_table(probe, mesh, n_dev, 2048)
+    build, b_shapes = _described_table(build, mesh, n_dev, 512)
+    fn = make_join_agg_fragment(probe, build, *args[2:], **kw)
+    text = _compile(fn, *p_shapes, *b_shapes).as_text()
+    by_scope = {}  # stage -> opcodes, fused computations' bodies included
+    for line in text.splitlines():
+        scope = re.search(
+            r'op_name="jit\(frag_join_agg\)/[^"]*?(join\.\w+)/', line)
+        op = re.search(r'= (?:\(.*?\)|\S+) ([a-z][\w-]*)\(', line)
+        if scope and op:
+            by_scope.setdefault(scope.group(1), set()).add(op.group(1))
+    assert {"join.sort", "join.probe", "join.unsort",
+            "join.gather"} <= set(by_scope), sorted(by_scope)
+    assert "sort" in by_scope["join.sort"] and "sort" in by_scope["join.unsort"]
+    for scope in ("join.sort", "join.probe", "join.unsort"):
+        assert not by_scope[scope] & {"while", "gather", "scatter"}, (
+            scope, by_scope[scope])
+    assert "gather" in by_scope["join.gather"]
+    if n_dev > 1:
+        assert "all-to-all" in text
 
 
 # -- general fragments (parallel/fragment.py compile_fragment) ---------------

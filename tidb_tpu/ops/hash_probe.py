@@ -1,10 +1,11 @@
 """Open-addressing hash probe for the device joins (Pallas).
 
-Both join tiers sort their build side and, pre-ISSUE 10, probed with
-two `jnp.searchsorted` calls — O(log Rb) dependent gather rounds per
-probe element, hostile to TPU (each round is an HBM gather the next
-round depends on). The reference's hash join probes an O(1)-expected
-hash table instead (ref: executor/'s HashJoinExec build+probe workers;
+The host-tier join (executor/join.py) and the general mesh fragment's
+join (parallel/fragment.py) sort their build side and, pre-ISSUE 10,
+probed with two `jnp.searchsorted` calls — O(log Rb) dependent gather
+rounds per probe element, hostile to TPU (each round is an HBM gather
+the next round depends on). The reference's hash join probes an
+O(1)-expected hash table instead (ref: executor/'s HashJoinExec build+probe workers;
 SURVEY.md:294-296 names this kernel as the planned fast path). This
 module supplies that table, consumed two ways: the fragment join
 (parallel/fragment.py) builds + probes it inside one shard_map program
@@ -13,7 +14,9 @@ it ONCE per join build (ops/join_kernels.build_hash_table) and probes
 it per chunk with the table arrays as kernel args. Strategy selection:
 `tidb_tpu_join_probe_mode` (off/auto/xla/pallas) through
 `resolve_mode` — auto picks the table exactly when the computation
-targets TPU.
+targets TPU. The mesh tier's unique-key join under a segment
+aggregate (parallel/distsql.py `_local_join`) never came through here:
+it ranks both sides by one sort (PR 26), with no table and no mode.
 
   * BUILD (XLA, inside the same jit): runs of equal values in the sorted
     hash array become (lo, hi) ranges; each run's FIRST row inserts

@@ -11,10 +11,12 @@ mocktikv coprocessor + MPP exchange) rebuilt as XLA collectives:
     [P, cap] send buffer (cap = growth * R / P), and overflow is counted
     and surfaced rather than silently dropped (static shapes: capacity
     overflow is the TPU analogue of the reference's spill trigger)
-  * local join per shard is sort + searchsorted probe (TPU-friendly; no
-    pointer-chasing hash table). Build side must be unique-key (PK-FK
-    joins — the reference's common HashJoinExec shape); many-many joins
-    stay on the host executor.
+  * local join per shard is a sort-merge: one sort of both sides' keys
+    together, a running maximum down each run of equal keys, a sort
+    back to slot order (no hash table, no binary search: on a TPU a
+    sort of all slots costs less than one gather round of them). Build
+    side must be unique-key (PK-FK joins — the reference's common
+    HashJoinExec shape); many-many joins stay on the host executor.
 """
 
 from __future__ import annotations
@@ -28,9 +30,11 @@ from jax.sharding import PartitionSpec as P
 
 from tidb_tpu.chunk.chunk import Chunk
 from tidb_tpu.chunk.column import Column
+from tidb_tpu.errors import ExecutionError
 from tidb_tpu.executor.aggregate import make_segment_kernel, merge_op_for
 from tidb_tpu.executor.scan import make_pipeline_fn
 from tidb_tpu.expression.compiler import eval_expr
+from tidb_tpu.ops.prefix import cummax
 from tidb_tpu.parallel.mesh import dcn_axis, shard_axis
 from tidb_tpu.parallel.partition import ShardedTable
 
@@ -202,20 +206,61 @@ def repartition_by_key(arrays: Dict[str, jax.Array], sel: jax.Array,
     return recv, recv_sel, recv_key, overflow
 
 
-def _local_join(build_key, build_sel, probe_key, probe_sel):
-    """Sort build keys, searchsorted-probe. Returns (build_idx, hit).
+_IDX_BITS = 29  # slot index bits under the 2-bit tag in the sort's int32 second key
 
-    Validity is a secondary sort key (valid rows first among equal keys),
-    not an in-band sentinel — a legitimate INT64_MAX key still joins."""
-    n = build_key.shape[0]
-    with jax.named_scope("join.build_sort"):
-        invalid = (~build_sel).astype(jnp.int32)
-        skeys, sinv, order = jax.lax.sort(
-            (build_key, invalid, jnp.arange(n)), num_keys=2)
+
+def _local_join(build_key, build_sel, probe_key, probe_sel):
+    """Sort-merge join of a unique-key build side. Returns (build_idx,
+    hit), one per probe slot: where hit[j], probe slot j joins build
+    slot build_idx[j]; elsewhere build_idx is 0.
+
+    ONE sort of both sides' keys together ranks every probe slot against
+    the build rows; a running maximum carries each run's build row to
+    the run's probe rows; a second sort, on the 32-bit slot index, puts
+    the answers back in slot order. No searching and no gather: on a v5e
+    a sort of the 15.0M slots of TPC-H SF1's lineitem-orders join costs
+    74 ms and the sort back 48 ms, where ONE gather of as many 64-bit
+    elements costs 226 ms and a binary search 22-24 such rounds (PERF.md
+    section 6, PR 26).
+
+    Validity is a sort key (tag 0 live build row, 1 live probe row, 2
+    dead slot of either side), never an in-band sentinel: the exchange's
+    dead slots carry key 0 beside a legitimate key 0, and INT64_MIN/MAX
+    still join. Keys compare at full width. Within a run of equal keys
+    the live build row, if any, sorts first (there is at most one: the
+    build key is unique).
+
+    Limit: build plus probe slots of one shard stay under 2**29
+    (536,870,912), so that the 2-bit tag sits clear of the slot index in
+    the sort's int32 second key; more raises ExecutionError when the
+    fragment is traced, before anything runs. (One v5e holds no such
+    shard: the keys alone would be 4.3 GB, the sort several times
+    that.)"""
+    nb, n = build_key.shape[0], build_key.shape[0] + probe_key.shape[0]
+    if n >= 1 << _IDX_BITS:
+        raise ExecutionError(
+            f"mesh join: {n} build+probe slots on one shard, "
+            f"limit {(1 << _IDX_BITS) - 1}; use more shards")
+    idx_mask = (1 << _IDX_BITS) - 1
+    with jax.named_scope("join.sort"):
+        key = jnp.concatenate([build_key, probe_key])
+        tag = jnp.concatenate([jnp.where(build_sel, 0, 2),
+                               jnp.where(probe_sel, 1, 2)]).astype(jnp.int32)
+        tagged = (tag << _IDX_BITS) | jnp.arange(n, dtype=jnp.int32)
+        skey, stagged = jax.lax.sort((key, tagged), num_keys=2)
     with jax.named_scope("join.probe"):
-        pos = jnp.clip(jnp.searchsorted(skeys, probe_key), 0, n - 1)
-        hit = (skeys[pos] == probe_key) & (sinv[pos] == 0) & probe_sel
-        return order[pos], hit
+        pos = jnp.arange(n, dtype=jnp.int64)
+        head = (pos == 0) | (skey != jnp.roll(skey, 1))
+        # a run's head, under its position so that the maximum is the
+        # nearest head at or before each row
+        carried = cummax(jnp.where(head, (pos << 32) | stagged, -1))
+        head_tagged = carried.astype(jnp.int32)  # the low 32 bits
+        hit = ((stagged >> _IDX_BITS) == 1) & ((head_tagged >> _IDX_BITS) == 0)
+        found = jnp.where(hit, head_tagged & idx_mask, -1)
+    with jax.named_scope("join.unsort"):
+        _, found = jax.lax.sort((stagged & idx_mask, found), num_keys=1)
+        found = found[nb:]  # the build side's own slots come first
+        return jnp.maximum(found, 0), found >= 0
 
 
 def make_join_agg_fragment(
