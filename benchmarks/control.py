@@ -9,7 +9,10 @@ later change would be tempted by). It has to come out NOT correct.
 
 For every seed: the cell's data at the cell's own scale, every menu
 entry answered in the lower precision, formatted as the wire would, and
-judged by the same ``check_answers`` the benchmark's runs use. Prints
+judged by the same ``check_answers`` the benchmark's runs use. In a cell
+with a writer the answers and the read-back are those after set-up's
+transactions and one of the window's, acknowledged before any statement
+was sent, so the control goes through the states the reference adds. Prints
 one line per seed with the numbers compared beside their limits. Needs
 no chip (numpy on the host); the benchmark's own runs never run it.
 """
@@ -26,7 +29,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np  # noqa: E402
 
 from benchmarks import reference, spec, tpch_datagen  # noqa: E402
-from benchmarks.run import check_answers  # noqa: E402
+from benchmarks.run import check_answers, versions  # noqa: E402
 
 
 def to_wire(rows: list) -> list:
@@ -42,12 +45,20 @@ def control_run(cell, seed: int, sf=None, lowp=np.float32) -> dict:
     """One control 'run': every menu entry once, answered in `lowp`."""
     scale = float(cell.config["scale_factor"] if sf is None else sf)
     data = reference.Data(tpch_datagen.generate(scale, seed))
-    records = []
-    for i, item in enumerate(cell.traffic["menu"]):
-        low = cell.statements[item["statement"]].reference(
-            data, item["params"], lowp=lowp)
-        records.append({"item": i, "rows": to_wire(low), "error": None})
-    checks = check_answers(cell, data, records)
+    low, w = versions(cell, data, scale, seed, lowp)
+    exact, _w = versions(cell, data, scale, seed)
+    k, written = 0, None
+    if w:  # one transaction of the window, acknowledged before any send
+        k = int(w.get("warm_transactions", 0)) + 1
+        written = {"first_k": k - 1, "writes": [
+            {"k": k - 1, "t_send": 0, "t_commit_send": 1, "t_ack": 2,
+             "error": None, "stmts": [(0, 2)]}],
+            "read_back": {table: to_wire([(low.rows(table, k), reference.Exact(
+                low.total(table, column, k), digits))])
+                for table, (column, digits) in low.write.READ_BACK.items()}}
+    records = [{"item": i, "rows": to_wire(low.answer(i, k)), "error": None,
+                "t_send": 3, "t_done": 4} for i in range(len(cell.traffic["menu"]))]
+    checks = check_answers(cell, exact, records, written)
     correct = all(v["value"] <= v["limit"]
                   for v in checks.values() if "limit" in v)
     return {"seed": seed, "lowp": np.dtype(lowp).name, "correct": correct,

@@ -18,6 +18,10 @@ partition what the client measured, less the loopback's two hops:
     exec_host    every other span: stmt.*, session.execute, dispatch.*,
                  fragment.* ...         host work under the lock
 
+In a cell with a writer these five stay the query streams' (they
+partition what those clients measured); the writer's statements have
+``writer_mean_ms``, per transaction.
+
 A program without the ring (the parent of the PR that brought it) gives
 ``None``: the readers then report nothing.
 """
@@ -37,24 +41,78 @@ def group_of(span_name: str) -> str:
     return _BY_NAME.get(span_name, "exec_host")
 
 
-def window_traces(ctx):
-    """The request traces of the window's statements: those whose root
-    opened between the window's first send and its last completion. (By
-    the root's start, not its end: the connection thread closes the
-    root a few microseconds after the client has its last packet.)"""
+def _split(ctx):
+    """(the query streams' request traces, the writer's): the traces
+    whose root opened between the stream's first send and its last
+    completion in the window. (By the root's start, not its end: the
+    connection thread closes the root a few microseconds after the client
+    has its last packet.) None without the ring.
+
+    A trace names no connection, and under the catalog lock a writer's
+    statement and a query can share their interval to the millisecond,
+    so in a cell with a writer the clock cannot tell them apart. The
+    statement digest a ``trace_id`` begins with can: the menu's digests
+    are those of the traces set-up's warm passes left (``ctx.warm``: one
+    statement at a time), and the writer's traces are the others."""
     from tidb_tpu.utils import tracing
 
     finished = getattr(tracing.STORE, "finished", None)
     if finished is None or not ctx.records:
         return None
+    roots = [(tr, tr.interval_perf()[0]) for tr in finished()
+             if tr.root() is not None and tr.root().name == "wire.stmt"]
     lo = min(r["t_send"] for r in ctx.records) / 1e9
     hi = max(r["t_done"] for r in ctx.records) / 1e9
-    out = []
-    for tr in finished():
-        root = tr.root()
-        if root is not None and root.name == "wire.stmt" \
-                and lo <= tr.interval_perf()[0] <= hi:
-            out.append(tr)
+    writes = getattr(ctx, "writes", ())
+    if not writes:
+        return [tr for tr, t0 in roots if lo <= t0 <= hi], []
+    digest = lambda tr: tr.trace_id.rpartition("-")[0]  # noqa: E731
+    menu = {digest(tr) for tr, t0 in roots
+            if any(a / 1e9 <= t0 <= b / 1e9 for a, b in ctx.warm)}
+    if not menu:
+        return None
+    w_lo = min(w["t_send"] for w in writes) / 1e9
+    w_hi = max((w["stmts"][-1][1] for w in writes if w["stmts"]),
+               default=0) / 1e9
+    return ([tr for tr, t0 in roots if lo <= t0 <= hi and digest(tr) in menu],
+            [tr for tr, t0 in roots if w_lo <= t0 <= w_hi and digest(tr) not in menu])
+
+
+def window_traces(ctx):
+    """The request traces of the window's query statements."""
+    split = _split(ctx)
+    return None if split is None else split[0]
+
+
+def writer_mean_ms(ctx, group: str):
+    """Mean ms of `group` per acknowledged transaction of the window,
+    summed over the transaction's statements; None without a writer."""
+    acked = sum(1 for w in getattr(ctx, "writes", ()) if w["t_ack"] is not None)
+    split = _split(ctx) if acked else None
+    if not split or not split[1]:
+        return None
+    return sum(us for tr in split[1] for name, us in tr.self_us_by_name().items()
+               if group_of(name) == group) / 1e3 / acked
+
+
+def by_name_ms(ctx) -> dict:
+    """Self time by span name, for the window's stdout line: mean ms per
+    query statement and, in a cell with a writer, per acknowledged
+    transaction. Information, not a metric."""
+    split = _split(ctx)
+    if not split:
+        return {}
+    acked = sum(1 for w in getattr(ctx, "writes", ()) if w["t_ack"] is not None)
+    out = {}
+    for key, traces, n in (("query", split[0], len(split[0])),
+                           ("writer", split[1], acked)):
+        total = {}
+        for tr in traces:
+            for name, us in tr.self_us_by_name().items():
+                total[name] = total.get(name, 0) + us
+        if n:
+            out[key] = {name: round(us / 1e3 / n, 3) for name, us in sorted(
+                total.items(), key=lambda kv: -kv[1])}
     return out
 
 

@@ -55,6 +55,21 @@ class Data:
     def rows(self, table: str) -> int:
         return len(next(iter(self.tables[table][0].values())))
 
+    def plus(self, rows: dict) -> "Data":
+        """These tables with `rows` (``{table: (arrays, pools)}``, codes
+        into the same pools) appended: what a committed insert leaves."""
+        tables = dict(self.tables)
+        for name, (arrays, _pools) in rows.items():
+            mine, pools = self.tables[name]
+            tables[name] = ({c: np.concatenate([a, arrays[c]])
+                             for c, a in mine.items()}, pools)
+        return Data(tables)
+
+    def empty(self) -> "Data":
+        """The same tables and pools with no row in them."""
+        return Data({name: ({c: a[:0] for c, a in arrays.items()}, pools)
+                     for name, (arrays, pools) in self.tables.items()})
+
     def decode(self, table: str, name: str, code: int) -> str:
         return self.tables[table][1][name][int(code)]
 
@@ -120,3 +135,74 @@ def compare_rows(got, want) -> dict:
 
 def answer_ok(cmp: dict) -> bool:
     return cmp["exact_mismatches"] == 0 and cmp["float_rel_gap"] <= FLOAT_REL_LIMIT
+
+
+class Versions:
+    """The reference in a cell that writes: the answer to every menu
+    entry after the first `k` transactions of the writer, k = 0, 1, 2 ...
+
+    A statement whose module has ``state`` and ``rows`` (sums and counts
+    by group: Q1, Q6) is answered from the loaded rows' state plus each
+    transaction's own, added exactly; any other from the arrays with the
+    transactions' rows appended (a pass over the whole table for each k).
+    `write` is the writer's statement module, `refresh` what its
+    ``source`` gave: the same rows the transaction's SQL carries."""
+
+    def __init__(self, data: Data, menu: list, statements: dict, write=None,
+                 refresh=None, lowp=None):
+        self.menu, self.statements, self.lowp = menu, statements, lowp
+        self.write, self.refresh = write, refresh
+        self._data = [data]   # _data[k]: the arrays after k transactions
+        self._none = data.empty()
+        self._new = []        # _new[k]: transaction k's rows alone, as Data
+        self._states = {}     # item -> [state after 0, 1, ... transactions]
+        self._answers = {}
+
+    def new_rows(self, k: int) -> Data:
+        while len(self._new) <= k:
+            self._new.append(self.write.apply(
+                self._none, self.refresh, len(self._new)))
+        return self._new[k]
+
+    def data(self, k: int) -> Data:
+        while len(self._data) <= k:
+            self._data.append(self.write.apply(
+                self._data[-1], self.refresh, len(self._data) - 1))
+        return self._data[k]
+
+    def rows(self, table: str, k: int = 0) -> int:
+        return self._data[0].rows(table) + sum(
+            self.new_rows(i).rows(table) for i in range(k))
+
+    def total(self, table: str, column: str, k: int) -> int:
+        """SUM of an integer column after `k` transactions."""
+        return total(self._data[0].col(table, column), self.lowp) + sum(
+            total(self.new_rows(i).col(table, column), self.lowp)
+            for i in range(k))
+
+    def answer(self, item: int, k: int = 0) -> list:
+        if (item, k) not in self._answers:
+            entry = self.menu[item]
+            mod = self.statements[entry["statement"]]
+            if k and hasattr(mod, "state"):
+                states = self._states.setdefault(item, [mod.state(
+                    self._data[0], entry["params"], lowp=self.lowp)])
+                while len(states) <= k:
+                    states.append(add_states(states[-1], mod.state(
+                        self.new_rows(len(states) - 1), entry["params"],
+                        lowp=self.lowp)))
+                rows = mod.rows(states[k], self._data[0])
+            else:
+                rows = mod.reference(self.data(k), entry["params"],
+                                     lowp=self.lowp)
+            self._answers[(item, k)] = rows
+        return self._answers[(item, k)]
+
+
+def add_states(a: dict, b: dict) -> dict:
+    """Two aggregation states ``{group: (sums and counts ...)}``, added."""
+    out = dict(a)
+    for group, terms in b.items():
+        out[group] = (tuple(x + y for x, y in zip(out[group], terms))
+                      if group in out else terms)
+    return out

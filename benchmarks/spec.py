@@ -64,7 +64,7 @@ class Cell:
         with open(tpath) as f:
             self.traffic = json.load(f)
         self.statements = {}
-        for item in self.traffic["menu"]:
+        for item in self.traffic["menu"] + self.traffic.get("writers", []):
             s = item["statement"]
             if s not in self.statements:
                 self.statements[s] = _module("statements", s, self.here)

@@ -28,7 +28,10 @@ def sql(p: dict) -> str:
         "order by l_returnflag, l_linestatus")
 
 
-def reference(data, p: dict, lowp=None) -> list:
+def state(data, p: dict, lowp=None) -> dict:
+    """Per group the five sums and the count: states of disjoint row sets
+    add (``reference.add_states``), so the reference follows a writer
+    without a pass over the table for every commit."""
     c = {n: data.col("lineitem", n) for n in COLUMNS["lineitem"]}
     m = c["l_shipdate"] <= data.days("1998-12-01") - int(p["delta"])
     rf, ls = c["l_returnflag"][m], c["l_linestatus"][m]
@@ -37,18 +40,25 @@ def reference(data, p: dict, lowp=None) -> list:
     cast = (lambda a: a) if lowp is None else (lambda a: a.astype(lowp))
     dp = cast(e) * cast(100 - d)
     ch = dp * cast(100 + t)
-    rows = []
-    for f in np.unique(rf):  # codes into sorted pools: code order is text order
+    out = {}
+    for f in np.unique(rf):
         for s in np.unique(ls):
             g = (rf == f) & (ls == s)
             n = int(g.sum())
-            if not n:
-                continue
-            sq, se, sd = (total(a[g], lowp) for a in (q, e, d))
-            rows.append((
-                data.decode("lineitem", "l_returnflag", f),
-                data.decode("lineitem", "l_linestatus", s),
-                Exact(sq, 2), Exact(se, 2), Exact(total(dp[g], lowp), 4),
-                Exact(total(ch[g], lowp), 6),
-                sq / n / 100, se / n / 100, sd / n / 100, n))
-    return rows
+            if n:
+                out[(int(f), int(s))] = tuple(
+                    total(a[g], lowp) for a in (q, e, dp, ch, d)) + (n,)
+    return out
+
+
+def rows(state: dict, data) -> list:
+    # codes into sorted pools: code order is text order
+    return [(data.decode("lineitem", "l_returnflag", f),
+             data.decode("lineitem", "l_linestatus", s),
+             Exact(sq, 2), Exact(se, 2), Exact(sdp, 4), Exact(sch, 6),
+             sq / n / 100, se / n / 100, sd / n / 100, n)
+            for (f, s), (sq, se, sdp, sch, sd, n) in sorted(state.items())]
+
+
+def reference(data, p: dict, lowp=None) -> list:
+    return rows(state(data, p, lowp), data)

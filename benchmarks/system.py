@@ -1,8 +1,9 @@
-"""The system under test: the one module of the benchmark that imports
-the program. It assembles the server as ``tidb_tpu.__main__.boot`` does
-(device, mesh, catalog, data, ``Server.start()``) — by hand only because
-``boot()`` cannot be given data — opens wire clients, and reads the
-program's counters. Everything else under ``benchmarks/`` (traffic,
+"""The system under test: beside ``program_spans.py`` (the program's
+spans) the one module of the benchmark that imports the program. It
+assembles the server as ``tidb_tpu.__main__.boot`` does (device, mesh,
+catalog, data, ``Server.start()``) — by hand only because ``boot()``
+cannot be given data — opens wire clients, and reads the program's
+counters. Everything else under ``benchmarks/`` (traffic,
 reference, comparison, reduction, peaks, bytes) stays clear of it.
 """
 

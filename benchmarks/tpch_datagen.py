@@ -19,6 +19,11 @@ columns draw from small pools, and it is not dbgen's random stream.
 Every seed gives the same row counts (lineitem is SF x 6,001,215
 exactly, as the spec's table of cardinalities has it), so every seed
 drives the same device shapes; what the seed changes is every value.
+
+``refresh_set`` gives the rows of one transaction of the refresh stream
+(clause 2.5.2's RF1: new orders with their lineitems), by the same
+rules. There the lines an order are drawn and NOT nudged to a total: a
+table that is being written never repeats its row counts.
 """
 
 from __future__ import annotations
@@ -165,9 +170,21 @@ def generate(sf: float, seed: int) -> dict:
         "ps_comment": ccode(nps)}, {"ps_comment": COMMENTS})
 
     no, nl = n["orders"], n["lineitem"]
-    nclerk = max(1, int(1000 * sf))
     lines = _lines_per_order(rng, no, nl)
-    okeys = np.arange(1, no + 1)
+    out["lineitem"], out["orders"] = _orders_with_lines(
+        rng, np.arange(1, no + 1), lines, sf, n)
+    return out
+
+
+def _orders_with_lines(rng, okeys: np.ndarray, lines: np.ndarray, sf: float,
+                       n: dict) -> tuple:
+    """(lineitem, orders) for the orders `okeys` with `lines` lineitems
+    each, by clause 4.2.3's rules: the loaded tables and the refresh
+    stream's transactions both come from here."""
+    ccode = lambda m: rng.integers(0, len(COMMENTS), m)  # noqa: E731
+    no, nl = len(okeys), int(lines.sum())
+    ns, nc, npart = n["supplier"], n["customer"], n["part"]
+    nclerk = max(1, int(1000 * sf))
     odate = rng.integers(START, END - 151 + 1, no)
     first = np.concatenate([[0], np.cumsum(lines)[:-1]])
     l_order = np.repeat(okeys, lines)
@@ -182,7 +199,7 @@ def generate(sf: float, seed: int) -> dict:
     returned = receipt <= CURRENT
     shipped = ship <= CURRENT
     n_f = np.add.reduceat(shipped.astype(np.int64), first)
-    out["lineitem"] = ({
+    lineitem = ({
         "l_orderkey": l_order, "l_partkey": l_part,
         "l_suppkey": (l_part + rng.integers(0, 4, nl) * (ns // 4 + 1)) % ns + 1,
         "l_linenumber": np.arange(nl) - np.repeat(first, lines) + 1,
@@ -198,7 +215,7 @@ def generate(sf: float, seed: int) -> dict:
         "l_returnflag": ["A", "N", "R"], "l_linestatus": ["F", "O"],
         "l_shipinstruct": INSTRUCT, "l_shipmode": SHIPMODES,
         "l_comment": COMMENTS})
-    out["orders"] = ({
+    orders = ({
         "o_orderkey": okeys, "o_custkey": rng.integers(1, nc + 1, no),
         # sorted pool F O P: all lines shipped, none, some
         "o_orderstatus": np.where(n_f == lines, 0, np.where(n_f == 0, 1, 2)),
@@ -211,4 +228,19 @@ def generate(sf: float, seed: int) -> dict:
         "o_orderstatus": ["F", "O", "P"], "o_orderpriority": PRIORITIES,
         "o_clerk": [f"Clerk#{k + 1:09d}" for k in range(nclerk)],
         "o_comment": COMMENTS})
-    return out
+    return lineitem, orders
+
+
+def refresh_set(sf: float, seed: int, k: int, orders: int) -> dict:
+    """Transaction `k` (0, 1, 2 ...) of the refresh stream: `orders` new
+    orders and their lineitems, ``{table: (arrays, pools)}`` as `generate`
+    gives them. Order keys go on densely from the loaded ones (at SF1 from
+    1,500,001), so no key ever meets a loaded one or another
+    transaction's; every order has 1 to 7 lineitems drawn from the seed,
+    so a transaction's row count differs from the next one's."""
+    rng = np.random.default_rng([int(seed), 0x7C5, int(k)])
+    n = sizes(sf)
+    okeys = n["orders"] + int(k) * int(orders) + np.arange(1, int(orders) + 1)
+    lineitem, new_orders = _orders_with_lines(
+        rng, okeys, rng.integers(1, 8, int(orders)), sf, n)
+    return {"orders": new_orders, "lineitem": lineitem}

@@ -64,6 +64,32 @@ def test_result_line_has_the_contracts_keys(results, name):
     json.dumps(res)
 
 
+@pytest.mark.parametrize("name", CELLS)
+def test_a_cell_without_a_writer_prints_the_checks_it_always_did(results, name):
+    """Letter for letter what the harness printed before it carried a
+    writer (PR 26's tree): the same names in the same order, no other."""
+    _cell, res = results[name]
+    checks = dict(res["checks"], float_rel_gap={"value": 0.0, "limit": 1e-12})
+    n, cells = (res["checks"]["compared"][k] for k in ("statements", "cells"))
+    assert json.dumps(checks) == (
+        '{"exact_mismatches": {"value": 0, "limit": 0}, '
+        '"float_rel_gap": {"value": 0.0, "limit": 1e-12}, '
+        '"missing_answers": {"value": 0, "limit": 0}, '
+        '"wrong_statements": {"value": 0, "limit": 0}, '
+        '"compared": {"statements": %d, "cells": %d}}' % (n, cells))
+    assert res["attempted"] == n and "refresh_p50_ms" not in res["metrics"]
+
+
+@pytest.mark.parametrize("mix,seed,orders", [
+    ("scan", 2**31 + 11, [[1, 3, 0, 2], [2, 1, 0, 3]]),
+    ("scan", 5, [[2, 1, 3, 0], [1, 0, 3, 2]]),
+    ("join", 2**31 + 11, [[0]]),
+])
+def test_stream_orders_are_the_parents_for_a_fixed_seed(mix, seed, orders):
+    with open(os.path.join(ROOT, "benchmarks", "traffic", mix + ".json")) as f:
+        assert traffic.stream_orders(json.load(f), seed) == orders
+
+
 def test_p95_is_reported_only_where_the_benchmark_lists_it(results):
     assert "stmt_p95_ms" in results["tpch_sf1.scan"][1]["metrics"]
     assert "stmt_p95_ms" not in results["tpch_sf1.join"][1]["metrics"]
@@ -273,6 +299,12 @@ def test_a_cell_a_mix_a_statement_and_a_metric_are_added_as_files_only(tmp_path)
                    "menu": [{"statement": "orders_before", "params": {"before": "1995-01-01"}},
                             {"statement": "q6", "params": {"date": "1993-01-01",
                                                            "discount": 5, "quantity": 24}}]}, f)
+    with open(os.path.join(here, "traffic", "tiny_written.json"), "w") as f:  # a mix with a writer
+        json.dump({"loop": "closed", "streams": 1, "warm_passes": 1, "trace_seconds": 1,
+                   "menu": [{"statement": "orders_before", "params": {"before": "1995-01-01"}},
+                            {"statement": "q1", "params": {"delta": 75}}],
+                   "writers": [{"statement": "rf1", "params": {"orders_per_transaction": 20},
+                                "warm_transactions": 0}]}, f)
     with open(os.path.join(here, "configs", "tpch_sf1.json")) as f:
         cfg = json.load(f)
     cfg["name"] = "throwaway"
@@ -285,13 +317,15 @@ def test_a_cell_a_mix_a_statement_and_a_metric_are_added_as_files_only(tmp_path)
                              "reduced": ["scale_factor"], "why": "test"})
     bench["workloads"].append({"name": "throwaway.tiny", "config": "throwaway",
                                "traffic": "tiny", "chips": 1, "why": "test"})
+    bench["workloads"].append({"name": "throwaway.tiny_written", "config": "throwaway",
+                               "traffic": "tiny_written", "chips": 1, "why": "test"})
     bench["per_layer"].append({"name": "stmts_in_window", "unit": "count", "better": "higher",
                                "source": "program_counter", "layer": "wire",
                                "moves": "rows_per_s", "workloads": ["throwaway.tiny"]})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     after = {os.path.join(d, f) for d, _s, fs in os.walk(root) for f in fs}
-    assert len(after - before) == 5  # four new files and BENCHMARK.json; none edited
+    assert len(after - before) == 6  # five new files and BENCHMARK.json; none edited
 
     cell, res = rehearse("throwaway.tiny", trace=True, root=root)
     assert res["correct"] is True and res["attempted"] >= 2
@@ -299,6 +333,12 @@ def test_a_cell_a_mix_a_statement_and_a_metric_are_added_as_files_only(tmp_path)
     assert "scan_agg_roofline" not in res["metrics"]  # not this cell's, by its workloads key
     _cell, res = rehearse("throwaway.tiny", root=root)
     assert res["correct"] is True and res["metrics"]["rows_per_s"]["value"] > 0
+    # the mix with a writer: files and one entry; a statement with no
+    # `state` (orders_before) is answered from the appended arrays
+    _cell, res = rehearse("throwaway.tiny_written", seconds=3.0, root=root)
+    assert res["correct"] is True and res["checks"]["unread_acknowledged_rows"]["value"] == 0
+    did = res["checks"]["compared"]
+    assert did["first_k"] == 0 and did["transactions"] >= 1 and max(did["answers_by_k"]) >= 1
     with pytest.raises(spec.SpecError):
         spec.Cell("no.such.cell", root=root)
 
