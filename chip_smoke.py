@@ -11,7 +11,9 @@ Statements: TPC-H Q6 and Q1 and the lineitem-orders join statement on the
 mesh tier (the two hand-written fragments of parallel/distsql.py), TPC-H
 Q18's inner aggregate (GROUP BY l_orderkey, 1.5M groups) through the
 general fragment compiler (parallel/fragment.py: sort-reduce, partial
-groups repartitioned over all_to_all), a transaction (insert, aggregate,
+groups repartitioned over all_to_all; one program and one launch cold,
+its group table sized from the key's distinct count as the bulk load
+sketched it), a transaction (insert, aggregate,
 ORDER BY LIMIT) on the fused segment-store tier with read-back on the
 other connection, and a point get. The whole of TPC-H Q3 and Q18 is NOT
 covered: the general fragments their joins compile to (20+ whole-table
@@ -169,6 +171,17 @@ Q18_INNER_SQL = ("select l_orderkey, sum(l_quantity) as q from lineitem "
                  "order by l_orderkey")
 TOPN_SQL = ("select l_extendedprice, l_orderkey from lineitem "
             "order by l_extendedprice desc limit 10")
+
+
+def check_one_launch(cold: dict) -> None:
+    """The group table is sized from the key's distinct count, which the
+    bulk load sketched (Table._seed_key_sketches): the cold statement
+    compiles its general fragment once and launches it once. A second
+    launch is the overflow retry: the estimate fell short of the data."""
+    launches = {k: v for k, v in cold.items() if k.startswith("fragment:")}
+    check(launches == {"fragment:general_generic": 1},
+          "Q18's inner aggregate launched its fragment more than once cold "
+          f"(a capacity knob overflowed): {launches}")
 
 
 # ---------------------------------------------------------------------------
@@ -412,6 +425,7 @@ def _drive(args, server, boot_s) -> dict:
             check("fragment:general_generic" in obs.delta(c1, c2),
                   "Q18's inner aggregate did not run as a general fragment "
                   f"(parallel/fragment.py): {obs.delta(c1, c2)}")
+            check_one_launch(obs.delta(c0, c1))
         if name == "q6":
             emit(phase="hbm", after="connection A first analytic statement",
                  hbm=hbm())
@@ -555,6 +569,7 @@ def _drive_mesh4(args, server, boot_s) -> dict:
         if name == "q18_inner":
             check("fragment:general_generic" in warm_d,
                   f"Q18's inner aggregate ran no general fragment: {warm_d}")
+            check_one_launch(obs.delta(c0, c1))
 
     # placement: a quarter of every column on each device, HBM balanced
     (sess,) = server.sessions.values()

@@ -585,7 +585,10 @@ class DistFragmentExec(HashAggExec):
         knobs double; "expand"/"compact" jump to the reported required
         factor in one recompile (skewed joins can demand 100x+ at once).
         Returns (out, growths) or (None, growths) past the ceilings.
-        Every attempt is one launch (``fragment.<kind>[parts=N]``)."""
+        Every attempt is one launch (``fragment.<kind>[parts=N]``); what
+        sends it round again is counted by knob (FRAGMENT_RETRY_TOTAL)."""
+        from tidb_tpu.utils.metrics import FRAGMENT_RETRY_TOTAL
+
         # the statement's resolved probe mode becomes a trace-time
         # static of the fragment program: it joins the cache key (a
         # knob flip must not serve a program traced for the other
@@ -607,11 +610,14 @@ class DistFragmentExec(HashAggExec):
             ovf = dsp.device_get(ovf, counted=False)
             if not (ovf > 0).any():
                 return out, growths
+            # once per re-launch and kind of knob that blew in this one
+            for knob in {k for o, k in zip(ovf, prog.growth_kinds) if o > 0}:
+                FRAGMENT_RETRY_TOTAL.inc(kind=kind, knob=knob)
             new = []
-            for g, o, kind in zip(growths, ovf, prog.growth_kinds):
+            for g, o, knob in zip(growths, ovf, prog.growth_kinds):
                 if o <= 0:
                     new.append(g)
-                elif kind in ("expand", "compact"):
+                elif knob in ("expand", "compact"):
                     factor = int(o) + 1
                     mult = 1
                     while mult < factor:
@@ -772,19 +778,23 @@ class DistFragmentExec(HashAggExec):
         any cardinality (the 10^7-group host-merge hotspot the round-2
         review flagged)."""
         from tidb_tpu.executor.agg_device import table_to_host_partial
-        host = dsp.device_get(out)
-        nk = len(self.group_exprs)
-        cap = self.ctx.chunk_capacity
-        partials = [table_to_host_partial(t, nk, self.aggs)
-                    for _p, t in self._iter_host_parts(host)]
-        if not partials:
-            self._out = []  # no groups anywhere
-            return
-        if nk == 0:
-            # keyless partials are not disjoint — exact merge instead
-            self._emit_merged(self._merge_partials(partials), cap)
-            return
-        self._emit_merged(self._concat_partials(partials), cap)
+        from tidb_tpu.utils import tracing
+
+        # the fetch is this span's device.wait child; its self time is
+        # the host's decode, concatenation and cut into chunks
+        with tracing.span("fragment.finalize"):
+            host = dsp.device_get(out)
+            nk = len(self.group_exprs)
+            cap = self.ctx.chunk_capacity
+            partials = [table_to_host_partial(t, nk, self.aggs)
+                        for _p, t in self._iter_host_parts(host)]
+            if not partials:
+                self._out = []  # no groups anywhere
+            elif nk == 0:
+                # keyless partials are not disjoint — exact merge instead
+                self._emit_merged(self._merge_partials(partials), cap)
+            else:
+                self._emit_merged(self._concat_partials(partials), cap)
 
 
 def _try_dist_agg(plan: PHashAgg, cache: ShardCache) -> Optional[Executor]:

@@ -200,13 +200,19 @@ class _Compiler:
         self.growth_kinds.append(kind)
         return idx
 
-    def _compact_knob(self, est_rows: float) -> Tuple[int, int]:
+    # headroom over an estimate: twice a guess; a quarter over a distinct
+    # count read from the data (three sigma of the NDV sketch are 9.4%)
+    HEADROOM, NDV_HEADROOM = 2.0, 1.25
+
+    def _compact_knob(self, est_rows: float,
+                      headroom: float = HEADROOM) -> Tuple[int, int]:
         """Estimate-sized compaction target: a "compact" knob plus its
-        base capacity (~2x the per-shard cardinality estimate, floor 64).
-        The base is part of the fragment signature — a stats change that
-        moves an estimate must not hit a cached fragment compiled with
-        the old capacities."""
-        base = max(64, int(np.ceil(2.0 * max(est_rows, 1.0) / self.n_parts)))
+        base capacity (`headroom` times the per-shard cardinality
+        estimate, floor 64). The base is part of the fragment signature —
+        a stats change that moves an estimate must not hit a cached
+        fragment compiled with the old capacities."""
+        base = max(64, int(np.ceil(
+            headroom * max(est_rows, 1.0) / self.n_parts)))
         idx = self._add_growth(1.0, "compact")
         self.sig.append(f"cap{idx}:{base}")
         return idx, base
@@ -328,13 +334,14 @@ class _Compiler:
             # (pruned) scan schema. Encoded columns decode here, inside
             # the compiled program (stored + ref, widened to the device
             # repr), so only the narrow payload crossed the host boundary
-            cols = {}
-            for name in uid_of:
-                t = type_of[name]
-                d = decode_for(data[name][0], refs.get(name), t.np_dtype)
-                cols[uid_of[name]] = Column(data=d, valid=valid[name][0],
-                                            type_=t)
-            return pipe(Chunk(cols, sel[0])), []
+            with jax.named_scope("scan"):
+                cols = {}
+                for name in uid_of:
+                    t = type_of[name]
+                    d = decode_for(data[name][0], refs.get(name), t.np_dtype)
+                    cols[uid_of[name]] = Column(
+                        data=d, valid=valid[name][0], type_=t)
+                return pipe(Chunk(cols, sel[0])), []
 
         return emit
 
@@ -719,54 +726,67 @@ class _Compiler:
         # estimate-sized shrink targets (see _compact): the partial sort
         # pays for input capacity and the exchange pays for table slots
         g_in, in_base = self._compact_knob(agg.child.est_rows)
-        g_tab, tab_base = self._compact_knob(agg.est_rows)
+        # every slot of the table is exchanged and sorted again by every
+        # statement: where the groups are bounded by the keys' distinct
+        # count, the table takes the smaller headroom
+        g_tab, tab_base = self._compact_knob(
+            agg.est_rows,
+            self.NDV_HEADROOM if agg.est_from_ndv else self.HEADROOM)
         self.sig.append(f"genagg:{agg.group_exprs!r}:{agg.aggs!r}")
 
         def emit(env, growths):
             chunk, ovfs = child_emit(env, growths)
             capI = int(np.ceil(growths[g_in] * in_base))
             if capI < chunk.capacity:
-                chunk, o = _compact_chunk(chunk, capI)
-                ovfs.append((g_in, pmax(o, _AXES)))
-            table = partial(chunk)  # local dedup before the exchange
-            S = table["k0.d"].shape[0]
-            capT = int(np.ceil(growths[g_tab] * tab_base))
-            if capT < S:
-                # groups are dense in [0, n): slicing the slot arrays is
-                # free and shrinks everything the exchange must carry
-                factor = (table["n"] + capT - 1) // capT
-                ovfs.append((g_tab, pmax(jnp.maximum(factor - 1, 0), _AXES)))
-                table = {k: (v if k == "n" else v[:capT])
-                         for k, v in table.items()}
-                S = capT
-            live = jnp.arange(S) < table["n"]
-            kd = [table[f"k{i}.d"] for i in range(nk)]
-            kv = [table[f"k{i}.v"] for i in range(nk)]
-            khash = _mix_hash([_key_bits(d, v) for d, v in zip(kd, kv)])
+                with jax.named_scope("agg.compact"):
+                    chunk, o = _compact_chunk(chunk, capI)
+                    ovfs.append((g_in, pmax(o, _AXES)))
+            with jax.named_scope("agg.partial"):
+                table = partial(chunk)  # local dedup before the exchange
+                S = table["k0.d"].shape[0]
+                capT = int(np.ceil(growths[g_tab] * tab_base))
+                if capT < S:
+                    # groups are dense in [0, n): slicing the slot arrays
+                    # is free and shrinks everything the exchange must
+                    # carry
+                    factor = (table["n"] + capT - 1) // capT
+                    ovfs.append(
+                        (g_tab, pmax(jnp.maximum(factor - 1, 0), _AXES)))
+                    table = {k: (v if k == "n" else v[:capT])
+                             for k, v in table.items()}
+                    S = capT
+            with jax.named_scope("exchange.agg"):
+                live = jnp.arange(S) < table["n"]
+                kd = [table[f"k{i}.d"] for i in range(nk)]
+                kv = [table[f"k{i}.v"] for i in range(nk)]
+                khash = _mix_hash([_key_bits(d, v) for d, v in zip(kd, kv)])
 
-            arrays = {}
-            for i in range(nk):
-                arrays[f"k{i}.d"] = kd[i]
-                arrays[f"k{i}.v"] = kv[i]
-            for name, _ in layout:
-                arrays[name] = table[name]
-            recv, recv_sel, _, ovf = repartition_by_key(
-                arrays, live, khash, jnp.ones_like(live), n_parts,
-                growths[g_agg])
-            ovfs.append((g_agg, jax.lax.psum(ovf, _AXES)))
+                arrays = {}
+                for i in range(nk):
+                    arrays[f"k{i}.d"] = kd[i]
+                    arrays[f"k{i}.v"] = kv[i]
+                for name, _ in layout:
+                    arrays[name] = table[name]
+                recv, recv_sel, _, ovf = repartition_by_key(
+                    arrays, live, khash, jnp.ones_like(live), n_parts,
+                    growths[g_agg])
+                ovfs.append((g_agg, jax.lax.psum(ovf, _AXES)))
 
-            rkd = [recv[f"k{i}.d"] for i in range(nk)]
-            rkv = [recv[f"k{i}.v"] for i in range(nk)]
-            rbits = [_key_bits(d, v) for d, v in zip(rkd, rkv)]
-            payload = [recv[name] for name, _ in layout]
-            ops = [op for _, op in layout]
-            # exact mode: the emitted tables are duplicate-free, so the
-            # host finalize is a straight per-part conversion — no merge
-            n, fk, fkv, red = _sort_reduce(rbits, rkv, rkd, recv_sel,
-                                           payload, ops, exact=True)
-            red = _normalize_red_limbs(red, layout, agg.aggs)
+            with jax.named_scope("agg.final"):
+                rkd = [recv[f"k{i}.d"] for i in range(nk)]
+                rkv = [recv[f"k{i}.v"] for i in range(nk)]
+                rbits = [_key_bits(d, v) for d, v in zip(rkd, rkv)]
+                payload = [recv[name] for name, _ in layout]
+                ops = [op for _, op in layout]
+                # exact mode: the emitted tables are duplicate-free, so
+                # the host finalize is a straight per-part conversion —
+                # no merge
+                n, fk, fkv, red = _sort_reduce(rbits, rkv, rkd, recv_sel,
+                                               payload, ops, exact=True)
+                red = _normalize_red_limbs(red, layout, agg.aggs)
             if topn_fn is not None:
-                n, fk, fkv, red = topn_fn(n, fk, fkv, red)
+                with jax.named_scope("agg.topn"):
+                    n, fk, fkv, red = topn_fn(n, fk, fkv, red)
             out = {"n": n[None]}
             for i in range(nk):
                 out[f"k{i}.d"] = fk[i]

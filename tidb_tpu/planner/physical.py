@@ -400,6 +400,10 @@ class PHashAgg(PhysicalPlan):
     group_uids: List[str] = field(default_factory=list)
     aggs: List[AggSpec] = field(default_factory=list)
     strategy: str = "generic"  # "segment" (packed small key space) | "generic"
+    # est_rows is the keys' distinct count as the data gave it (a bound,
+    # off by the sketch's error), not a guess: the mesh tier sizes the
+    # device's group table with a quarter of headroom instead of twice
+    est_from_ndv: bool = False
 
     def op_name(self):
         return "HashAgg"
@@ -718,6 +722,26 @@ def eq_join_rows(left: LogicalPlan, right: LogicalPlan, eq_conds,
     return out
 
 
+def _estimate_groups(plan: "LAggregate") -> Tuple[float, bool]:
+    """(groups an aggregate is estimated to emit, whether that number is
+    read from the data). With a distinct count for every key (ANALYZE's,
+    or the sketch a bulk load or the inserts keep: `column_ndv`) the
+    groups are bounded by the product of the keys' NDVs, and where that
+    product and not the child's row estimate is the smaller, the
+    estimate is a bound that errs by the sketch's error alone; without,
+    it is the guess n ** 0.75."""
+    n = _estimate(plan.child)
+    if not plan.group_exprs:
+        return 1.0, True
+    prod = 1.0
+    for g in plan.group_exprs:
+        ndv = _eq_ndv(plan.child, g, n)
+        if ndv is None:
+            return max(min(n, n ** 0.75), 1.0), False
+        prod = min(prod * ndv, 1e18)
+    return max(min(n, prod), 1.0), prod <= n
+
+
 def _estimate(plan: LogicalPlan) -> float:
     from tidb_tpu.statistics import scan_selectivity, table_stats
 
@@ -748,21 +772,7 @@ def _estimate(plan: LogicalPlan) -> float:
     if isinstance(plan, LSelection):
         return max(_estimate(plan.child) * _SEL_FILTER, 1.0)
     if isinstance(plan, LAggregate):
-        n = _estimate(plan.child)
-        if not plan.group_exprs:
-            return 1.0
-        # with stats: groups bounded by the product of key NDVs
-        prod = 1.0
-        known = True
-        for g in plan.group_exprs:
-            ndv = _eq_ndv(plan.child, g, n)
-            if ndv is None:
-                known = False
-                break
-            prod = min(prod * ndv, 1e18)
-        if known:
-            return max(min(n, prod), 1.0)
-        return max(min(n, n ** 0.75), 1.0)
+        return _estimate_groups(plan)[0]
     if isinstance(plan, LJoin):
         l = _estimate(plan.children[0])
         r = _estimate(plan.children[1])
@@ -824,7 +834,10 @@ def _segment_domain(agg: LAggregate) -> Optional[List[int]]:
 # ---------------------------------------------------------------------------
 
 def lower(plan: LogicalPlan) -> PhysicalPlan:
-    est = _estimate(plan)
+    if isinstance(plan, LAggregate):
+        est, est_from_ndv = _estimate_groups(plan)
+    else:
+        est = _estimate(plan)
 
     if isinstance(plan, LScan):
         return PScan(
@@ -856,6 +869,7 @@ def lower(plan: LogicalPlan) -> PhysicalPlan:
             schema=plan.schema, children=[lower(plan.child)], est_rows=est,
             group_exprs=plan.group_exprs, group_uids=plan.group_uids,
             aggs=plan.aggs, strategy=strategy,
+            est_from_ndv=est_from_ndv,
         )
         if sizes is not None:
             node.segment_sizes = sizes

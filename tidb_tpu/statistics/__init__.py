@@ -96,10 +96,16 @@ class NDVSketch:
     so between analyzes the estimate is an (approximate) upper bound on
     live NDV, which is the safe direction for join estimates. Ref
     counterpart: the sketch-based NDV the reference maintains between
-    full analyzes (statistics/ CMSketch family)."""
+    full analyzes (statistics/ CMSketch family).
+
+    Stated error: relative standard error 1/sqrt(K-2), 3.1% at K=1024
+    (REL_ERROR is three of them). The estimate sizes device group
+    tables (fragment.py _compact_knob), where an over-estimate is slots
+    sorted by every statement: K=256 read 1.5M dense keys as 1.79M."""
 
     __slots__ = ("mins",)
-    K = 256
+    K = 1024
+    REL_ERROR = 3.0 / (K - 2) ** 0.5
 
     def __init__(self, mins: Optional[np.ndarray] = None):
         self.mins = (np.empty(0, dtype=np.uint64)
@@ -116,8 +122,26 @@ class NDVSketch:
             h = h[h < self.mins[-1]]
             if len(h) == 0:
                 return
+        elif len(h) > 64 * self.K:
+            h = self._smallest(h)
         merged = np.union1d(self.mins, h)
         self.mins = merged[: self.K]
+
+    @classmethod
+    def _smallest(cls, h: np.ndarray) -> np.ndarray:
+        """The distinct hashes of a bulk batch (a loaded column) that can
+        be among its K smallest, without sorting the batch: hashes are
+        uniform, so a cut 64 times above where the Kth of len(h) distinct
+        ones would fall keeps a few thousand; a column of few distinct
+        values has fewer than K under it, and the cut widens until K are
+        found or every hash is under it."""
+        cut = (64 * cls.K << 64) // len(h)
+        while cut < 1 << 64:
+            under = np.unique(h[h < np.uint64(cut)])
+            if len(under) >= cls.K:
+                return under
+            cut *= 64
+        return np.unique(h)
 
     def estimate(self) -> float:
         k = len(self.mins)
@@ -139,7 +163,8 @@ def hash_column_values(vals: np.ndarray, dic) -> np.ndarray:
 
 
 def _seed_sketch(table, col_name: str, vals: np.ndarray) -> None:
-    """Seed the per-column NDV sketch from ANALYZE's value pass."""
+    """Seed the per-column NDV sketch from a pass over every value:
+    ANALYZE's, or a bulk load's over its key columns."""
     sk = NDVSketch()
     if len(vals):
         sk.update(hash_column_values(vals, table.dicts.get(col_name)))

@@ -441,6 +441,12 @@ GC_RECLAIMED = Counter("tidb_tpu_gc_reclaimed_rows_total",
 CONN_GAUGE = Gauge("tidb_tpu_connections", "Open server connections")
 FRAGMENT_DISPATCH = Counter("tidb_tpu_fragment_dispatch_total",
                             "Distributed fragment executions, by kind")
+FRAGMENT_RETRY_TOTAL = Counter(
+    "tidb_tpu_fragment_retry_total",
+    "Fragment launches thrown away because a capacity knob overflowed "
+    "(the fragment is compiled and launched anew with the knob grown), "
+    "by fragment kind and the kind of knob that blew (compact / expand "
+    "/ exch; a launch in which two kinds blew counts under both)")
 EXTERNAL_AGG = Counter("tidb_tpu_external_agg_total",
                        "Key-range external aggregation merges (group "
                        "state exceeded the memory budget)")
