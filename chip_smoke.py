@@ -10,8 +10,10 @@ answers correctly on the TPU.
 Statements: TPC-H Q6 and Q1 and the lineitem-orders join statement on the
 mesh tier (the two hand-written fragments of parallel/distsql.py), TPC-H
 Q18's inner aggregate (GROUP BY l_orderkey, 1.5M groups) through the
-general fragment compiler (parallel/fragment.py: sort-reduce, partial
-groups repartitioned over all_to_all; one program and one launch cold,
+general fragment compiler (parallel/fragment.py: sort-reduce; on four
+chips the partial groups repartitioned over all_to_all and reduced
+again, on one chip the partial table is the final one; one program and
+one launch cold,
 its group table sized from the key's distinct count as the bulk load
 sketched it), a transaction (insert, aggregate,
 ORDER BY LIMIT) on the fused segment-store tier with read-back on the
@@ -164,8 +166,8 @@ Q6_SHAPED = ("select sum(l_extendedprice * l_discount) as revenue "
              "and l_discount between 0.05 and 0.07 and l_quantity < 24")
 # TPC-H Q18's inner aggregate: one group per order (1.5M groups at SF1)
 # is no segment aggregation — the general fragment compiler's generic
-# path takes it (per-shard sort-reduce, partial groups repartitioned by
-# key over all_to_all, merged where they land)
+# path takes it (per-shard sort-reduce; across chips the partial groups
+# repartitioned by key over all_to_all and merged where they land)
 Q18_INNER_SQL = ("select l_orderkey, sum(l_quantity) as q from lineitem "
                  "group by l_orderkey having sum(l_quantity) > 300 "
                  "order by l_orderkey")

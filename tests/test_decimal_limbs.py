@@ -102,13 +102,27 @@ def test_ten_billion_row_equivalent_magnitude():
     assert Decimal(s.query("select sum(p) from d")[0][0]) == Decimal(total).scaleb(-2)
 
 
-def test_mesh_fragment_limbs(devices8):
+@pytest.mark.parametrize("n_dcn, n_shards", [(2, 4), (1, 1)],
+                         ids=["2x4", "1x1"])
+def test_mesh_fragment_limbs(devices8, monkeypatch, n_dcn, n_shards):
     """Distributed generic fragment path: limb states exchange + merge
-    across shards exactly."""
+    across shards exactly. On a mesh of one part nothing is exchanged
+    and the partial table is the final one: its limbs are
+    carry-normalised all the same (lo in [0, 2^32): the TopN's limb sort
+    keys and the host finalize count on it)."""
+    from tidb_tpu.parallel import executor as pe
     from tidb_tpu.parallel import make_mesh
 
-    mesh = make_mesh(n_shards=4, n_dcn=2, devices=devices8)
+    mesh = make_mesh(n_shards=n_shards, n_dcn=n_dcn, devices=devices8)
     s = Session(chunk_capacity=2048, mesh=mesh)
+    # a one-device CPU mesh routes generic aggregation to the host engine
+    # unless asked
+    s.execute("set tidb_device_engine_mode = 'force'")
+    emitted = []
+    real = pe.DistFragmentExec._finalize_generic_tables
+    monkeypatch.setattr(
+        pe.DistFragmentExec, "_finalize_generic_tables",
+        lambda self, out: (emitted.append(out), real(self, out))[1])
     s.execute("create table d (g bigint, p decimal(18,2))")
     rng = np.random.default_rng(13)
     oracle = {}
@@ -121,6 +135,9 @@ def test_mesh_fragment_limbs(devices8):
     for st in range(0, 4000, 500):
         s.execute("insert into d values " + ", ".join(vals[st:st + 500]))
     got = dict(s.query("select g, sum(p) from d group by g"))
+    (table,) = emitted  # the mesh tier answered, with whole limbs
+    lo, hi = np.asarray(table["a0.sum"]), np.asarray(table["a0.sumhi"])
+    assert ((lo >= 0) & (lo < 1 << 32)).all() and hi.any()
     assert set(got) == set(oracle)
     for g, tot in oracle.items():
         assert Decimal(got[g]) == Decimal(tot).scaleb(-2), g

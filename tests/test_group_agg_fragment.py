@@ -1,6 +1,8 @@
 """The general fragment's generic aggregate (parallel/fragment.py
-compile_agg: compaction, per-shard partial sort-reduce, repartition of
-the partial groups, exact final sort-reduce) as TPC-H Q18's inner block
+compile_agg: compaction, per-shard partial sort-reduce, and on a mesh of
+several parts the repartition of the partial groups and the exact final
+sort-reduce; on one part the partial table is the final table) as TPC-H
+Q18's inner block
 drives it — GROUP BY a key of many values, HAVING on the sum, ORDER BY
 the key — against a plain dict oracle on 1 and several parts of the CPU
 mesh; the overflow retry and its counter; the rule that sizes the group
@@ -169,13 +171,17 @@ def test_a_bulk_load_sketches_its_key_and_the_group_table_follows(devices8, ndv,
 SCOPES = ["agg.partial", "exchange.agg/exchange.sort",
           "exchange.agg/exchange.scatter", "exchange.agg/exchange.all_to_all",
           "agg.final"]
+# what each mesh compiles: one part owns every key, so its partial table
+# is its final table and nothing is exchanged
+SCOPES_OF = {4: SCOPES, 1: ["agg.partial"]}
 
 
-@pytest.fixture(scope="module")
-def hlo_by_scope(devices8):
-    """The opcodes of the compiled general fragment of the statement, by
-    the scope in each op's ``op_name`` (one program per variant: without
-    and with the compaction and top-n stages)."""
+@pytest.fixture(scope="module", params=sorted(SCOPES_OF), ids="1x{}".format)
+def hlo_by_scope(request, devices8):
+    """(parts, the opcodes of the compiled general fragment of the
+    statement on a mesh of so many parts, by the scope in each op's
+    ``op_name``): one program per variant, without and with the
+    compaction and top-n stages."""
     from tidb_tpu.parallel import executor as pe
 
     rng = np.random.default_rng(9)
@@ -194,7 +200,7 @@ def hlo_by_scope(devices8):
 
         pe.DistFragmentExec._dispatch_retry = spy
         try:
-            session(catalog, devices8, 4).query(sql)
+            session(catalog, devices8, request.param).query(sql)
         finally:
             pe.DistFragmentExec._dispatch_retry = real
         (prog, args, growths), = seen
@@ -206,14 +212,23 @@ def hlo_by_scope(devices8):
             if name and op:
                 by_scope.setdefault(name.group(1), set()).add(op.group(1))
         out[variant] = by_scope
-    return out
+    return request.param, out
 
 
 @pytest.mark.parametrize("scope", SCOPES)
 def test_each_stage_of_the_generic_aggregate_has_its_scope(hlo_by_scope, scope):
-    ops = {op for name, found in hlo_by_scope["plain"].items()
+    n_parts, by_variant = hlo_by_scope
+    ops = {op for name, found in by_variant["plain"].items()
            if f"/{scope}/" in f"/{name}/" for op in found}
-    assert ops, sorted(hlo_by_scope["plain"])
+    if scope not in SCOPES_OF[n_parts]:
+        # one part: no exchange of the groups and no second sort-reduce
+        assert not ops, (scope, sorted(ops))
+        assert not any("exchange." in n or "agg.final" in n
+                       for v in by_variant.values() for n in v)
+        assert "all-to-all" not in set().union(
+            *(o for v in by_variant.values() for o in v.values()))
+        return
+    assert ops, sorted(by_variant["plain"])
     if scope in ("agg.partial", "agg.final", "exchange.agg/exchange.sort"):
         assert "sort" in ops
     if scope == "exchange.agg/exchange.all_to_all":
@@ -225,17 +240,23 @@ def test_the_optional_stages_have_their_scopes_too(hlo_by_scope, scope):
     """A filter is the scan's (a bare scan hands its columns on and
     leaves no op); a filtered input is compacted to its estimate before
     the partial sort; a pushed-down ORDER BY ... LIMIT keeps each shard's
-    top groups."""
-    assert not any(f"/{scope}/" in f"/{n}/" for n in hlo_by_scope["plain"])
-    assert any(f"/{scope}/" in f"/{n}/" for n in hlo_by_scope["filtered_topn"]), \
-        sorted(hlo_by_scope["filtered_topn"])
+    top groups (the planner pushes one down only onto a mesh: one part's
+    groups are all the groups, and the root's TopN ranks them)."""
+    n_parts, by_variant = hlo_by_scope
+    assert not any(f"/{scope}/" in f"/{n}/" for n in by_variant["plain"])
+    there = any(f"/{scope}/" in f"/{n}/" for n in by_variant["filtered_topn"])
+    assert there == (scope != "agg.topn" or n_parts > 1), \
+        sorted(by_variant["filtered_topn"])
 
 
 def test_no_heavy_op_of_the_generic_aggregate_is_left_without_a_scope(hlo_by_scope):
     """Outside every scope lie the program's parameters and the assembly
-    of the overflow vector, nothing that moves rows."""
-    every = SCOPES + ["scan", "exchange.agg", "agg.compact", "agg.topn"]
-    for variant, by_scope in hlo_by_scope.items():
+    of the overflow vector, nothing that moves rows; on one part every
+    sort and scatter is `agg.*`'s."""
+    n_parts, by_variant = hlo_by_scope
+    every = SCOPES_OF[n_parts] + ["scan", "agg.compact", "agg.topn"] + (
+        ["exchange.agg"] if n_parts > 1 else [])
+    for variant, by_scope in by_variant.items():
         bare = set().union(*(ops for n, ops in by_scope.items() if not any(
             f"/{s}/" in f"/{n}/" for s in every)))
         assert not bare & {"sort", "scatter", "gather", "all-to-all", "while",

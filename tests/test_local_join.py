@@ -1,8 +1,9 @@
 """The mesh tier's local join (parallel/distsql.py `_local_join`): one
 sort of both sides ranks every probe slot against a unique-key build
 side. Pinned against a dictionary join in numpy on the slot layouts the
-exchange really hands it, and through the served statement on a 1x1 and
-a 1x4 CPU mesh."""
+exchange really hands it, and through the served statement on a 1x1 (no
+exchange: the local join takes the rows as the scan left them), a 1x4
+and a 1x8 CPU mesh."""
 
 import zlib
 
@@ -170,7 +171,7 @@ def oracle(catalog):
     return mirror_to_sqlite(catalog)
 
 
-@pytest.fixture(scope="module", params=[1, 4], ids=["1x1", "1x4"])
+@pytest.fixture(scope="module", params=[1, 4, 8], ids=["1x1", "1x4", "1x8"])
 def served(request, catalog):
     s = Session(catalog=catalog,
                 mesh=make_mesh(devices=jax.devices()[:request.param]))

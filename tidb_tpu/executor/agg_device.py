@@ -190,8 +190,14 @@ def _state_layout(aggs: List[AggSpec]) -> List[Tuple[str, str]]:
     return layout
 
 
-def make_partial_kernel(group_exprs, aggs: List[AggSpec]):
-    """fn(chunk) -> group table dict {"n", "k{i}.d", "k{i}.v", state...}."""
+def make_partial_kernel(group_exprs, aggs: List[AggSpec],
+                        exact: bool = False):
+    """fn(chunk) -> group table dict {"n", "k{i}.d", "k{i}.v", state...}.
+
+    `exact` (see _sort_reduce): the table holds every group once, also
+    where several keys' mixed hashes collide — for a consumer that emits
+    the table as it is (the fragment tier on a mesh of one part). The
+    host executor merges its tables by exact key and leaves it off."""
     layout = _state_layout(aggs)
 
     def partial(chunk: Chunk):
@@ -239,7 +245,8 @@ def make_partial_kernel(group_exprs, aggs: List[AggSpec]):
                 payload.append(jnp.where(ok, d, _ident_max(dt)).astype(dt))
                 ops.append("max")
 
-        n, rk, rkv, red = _sort_reduce(kbits, kvalids, kdatas, sel, payload, ops)
+        n, rk, rkv, red = _sort_reduce(kbits, kvalids, kdatas, sel, payload,
+                                       ops, exact=exact)
         table = {"n": n}
         for i in range(len(group_exprs)):
             table[f"k{i}.d"] = rk[i]

@@ -10,7 +10,11 @@ mocktikv coprocessor + MPP exchange) rebuilt as XLA collectives:
     lax.all_to_all — rows hash to a destination shard, take a slot in a
     [P, cap] send buffer (cap = growth * R / P), and overflow is counted
     and surfaced rather than silently dropped (static shapes: capacity
-    overflow is the TPU analogue of the reference's spill trigger)
+    overflow is the TPU analogue of the reference's spill trigger).
+    A mesh of ONE part exchanges nothing: every row is already on the
+    part that owns its key, so the rows are handed on as they are — no
+    sort, no send buffer, no collective, no `exchange.*` scope in the
+    program (`exchange_steps` says how many a program holds)
   * local join per shard is a sort-merge: one sort of both sides' keys
     together, a running maximum down each run of equal keys, a sort
     back to slot order (no hash table, no binary search: on a TPU a
@@ -21,6 +25,7 @@ mocktikv coprocessor + MPP exchange) rebuilt as XLA collectives:
 
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -45,6 +50,7 @@ __all__ = [
     "dist_agg_fragment",
     "dist_join_agg_fragment",
     "repartition_by_key",
+    "exchange_steps",
 ]
 
 _HASH_MULT = np.int64(-7046029254386353131)  # 0x9E3779B97F4A7C15 as int64
@@ -159,6 +165,20 @@ def _hash_dest(key: jax.Array, n_parts: int) -> jax.Array:
     return ((h % n_parts) + n_parts) % n_parts
 
 
+def exchange_steps(n_parts: int, repartitions: int) -> int:
+    """How many exchange steps a program that calls `repartition_by_key`
+    `repartitions` times over `n_parts` parts really holds: on one part
+    none (what FRAGMENT_EXCHANGE_STEPS counts at every launch)."""
+    return repartitions if n_parts > 1 else 0
+
+
+def _exchange_scope(name: str, n_parts: int):
+    """`jax.named_scope(name)` around a repartition, where there is one
+    to name: on one part the rows are handed on and no op is the
+    exchange's."""
+    return jax.named_scope(name) if n_parts > 1 else contextlib.nullcontext()
+
+
 def repartition_by_key(arrays: Dict[str, jax.Array], sel: jax.Array,
                        key: jax.Array, key_valid: jax.Array, n_parts: int,
                        growth: float = 2.0,
@@ -168,10 +188,19 @@ def repartition_by_key(arrays: Dict[str, jax.Array], sel: jax.Array,
     arrays: name -> [R]; returns (arrays', sel', key', overflow_count) with
     [n_parts * cap] shapes where cap = ceil(growth * R / n_parts).
     NULL keys never join, so such rows are dropped here (sel'=False).
+
+    On ONE part (`n_parts` is a static Python int, the mesh's shape when
+    the fragment is traced) the exchange is the identity: the arrays and
+    the key come back as they were given, at [R] slots, sel' is
+    sel & key_valid and the overflow a constant 0. Dead slots keep
+    whatever key they held; `_local_join` and `_sort_reduce` take
+    validity as a sort key, so they need no sentinel.
     """
+    live = sel & key_valid
+    if n_parts == 1:
+        return arrays, live, key, jnp.zeros((), dtype=jnp.int64)
     R = sel.shape[0]
     cap = int(np.ceil(growth * R / n_parts))
-    live = sel & key_valid
     dest = jnp.where(live, _hash_dest(key, n_parts), n_parts)  # P = drop lane
 
     with jax.named_scope("exchange.sort"):
@@ -274,9 +303,9 @@ def make_join_agg_fragment(
     """Compile hash-repartition join + partial agg, all on device.
 
     Pipeline per shard: scan probe/build -> fused FoR decode -> pushed
-    filters -> eval join keys -> all_to_all exchange both sides -> local
-    unique-build-key join -> post-join filter/project -> partial segment
-    agg -> collective merge.
+    filters -> eval join keys -> all_to_all exchange both sides (none on
+    a mesh of one part) -> local unique-build-key join -> post-join
+    filter/project -> partial segment agg -> collective merge.
 
     Returns a jitted fn(p_data, p_valid, p_sel, p_refs, b_data, b_valid,
     b_sel, b_refs) -> (state, overflow) — state is the merged [G] dict;
@@ -320,10 +349,10 @@ def make_join_agg_fragment(
                                    type_=col.type_)
             return Chunk(cols, sel)
 
-        with jax.named_scope("exchange.probe"):
+        with _exchange_scope("exchange.probe", n_parts):
             pr, pr_sel, pr_key, p_ovf = repartition_by_key(
                 flat(pch), pch.sel, pk, pkv, n_parts, growth)
-        with jax.named_scope("exchange.build"):
+        with _exchange_scope("exchange.build", n_parts):
             br, br_sel, br_key, b_ovf = repartition_by_key(
                 flat(bch), bch.sel, bk, bkv, n_parts, growth)
 

@@ -33,7 +33,11 @@ from tidb_tpu.executor.builder import build_executor, peel_stages, scan_stages_f
 from tidb_tpu.executor.base import Executor, raise_if_cancelled
 from tidb_tpu.executor.scan import ProjectionExec, SelectionExec
 from tidb_tpu.executor.sort import LimitExec, SortExec, TopNExec
-from tidb_tpu.parallel.distsql import make_agg_fragment, make_join_agg_fragment
+from tidb_tpu.parallel.distsql import (
+    exchange_steps,
+    make_agg_fragment,
+    make_join_agg_fragment,
+)
 from tidb_tpu.parallel.fragment import BROADCAST_LIMIT, compile_fragment
 from tidb_tpu.parallel.mesh import dcn_axis, shard_axis
 from tidb_tpu.parallel.partition import ShardedTable, shard_table
@@ -53,7 +57,7 @@ __all__ = ["ShardCache", "build_dist_executor", "DistAggExec", "DistJoinAggExec"
 
 
 @contextlib.contextmanager
-def _fragment_launch(kind: str, n_parts: int):
+def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0):
     """One fragment launch: the span ``fragment.<kind>[parts=N]`` on the
     statement's trace and the FRAGMENT_SECONDS collector for /metrics
     (with a trace_id exemplar). Wall time covers the launch plus any
@@ -61,14 +65,20 @@ def _fragment_launch(kind: str, n_parts: int):
     async): whoever needs the result waits in ``device.wait``
     (utils/dispatch.py). One launch is one fragment execution, so the
     dispatch counter lives here too — the count and the histogram can
-    never desynchronize."""
+    never desynchronize — and beside it the `exchanges` the launched
+    program holds (FRAGMENT_EXCHANGE_STEPS; 0 on a mesh of one part)."""
     from tidb_tpu.utils import tracing
-    from tidb_tpu.utils.metrics import FRAGMENT_DISPATCH, FRAGMENT_SECONDS
+    from tidb_tpu.utils.metrics import (
+        FRAGMENT_DISPATCH,
+        FRAGMENT_EXCHANGE_STEPS,
+        FRAGMENT_SECONDS,
+    )
 
     t0 = time.perf_counter()
     with tracing.span(f"fragment.{kind}[parts={n_parts}]"):
         yield
     FRAGMENT_DISPATCH.inc(kind=kind)
+    FRAGMENT_EXCHANGE_STEPS.inc(exchanges, kind=kind)
     FRAGMENT_SECONDS.observe(time.perf_counter() - t0, kind=kind)
 
 
@@ -370,7 +380,8 @@ class DistJoinAggExec(HashAggExec):
                     growth=growth,
                 ),
             )
-            with _fragment_launch("join_agg", probe_st.n_parts):
+            with _fragment_launch("join_agg", probe_st.n_parts,
+                                  exchange_steps(probe_st.n_parts, 2)):
                 state, ovf = fn(probe_st.data, probe_st.valid,
                                 probe_st.sel, probe_st.refs,
                                 build_st.data, build_st.valid,
@@ -603,7 +614,7 @@ class DistFragmentExec(HashAggExec):
                    probe_mode)
             fn = self._cache.get_fragment(
                 key, lambda: prog.build_fn(growths, probe_mode=probe_mode))
-            with _fragment_launch(kind, n_parts):
+            with _fragment_launch(kind, n_parts, prog.n_exchange):
                 out, ovf = fn(*args)
             # host-sync: the per-knob overflow vector (a few int64s)
             # gates the capacity-retry loop — one fetch per dispatch
@@ -776,7 +787,9 @@ class DistFragmentExec(HashAggExec):
         reduce is EXACT (sorts by hash + full key bits), so parts are
         disjoint and duplicate-free — no cross-part host merge exists at
         any cardinality (the 10^7-group host-merge hotspot the round-2
-        review flagged)."""
+        review flagged). A mesh of one part exchanges nothing: its one
+        reduce is the exact one, and its table is `capT` slots, not
+        `n_parts * cap` received ones."""
         from tidb_tpu.executor.agg_device import table_to_host_partial
         from tidb_tpu.utils import tracing
 

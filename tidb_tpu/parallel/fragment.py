@@ -26,6 +26,12 @@ Every fixed-capacity buffer (exchange buckets, join expansion slots)
 counts its overflow instead of dropping rows; the driving executor
 doubles the blown growth factor and re-runs — the static-shape analogue
 of the reference's spill/split retry.
+
+On a mesh of ONE part nothing is exchanged (`n_parts` is a static int
+when the fragment is compiled): a join's sides go to the local join as
+they are, the generic aggregate's partial table — reduced exactly — is
+its final table, and neither adds an "exch" knob. FragmentProgram's
+`n_exchange` says how many repartitions a program holds.
 """
 
 from __future__ import annotations
@@ -119,8 +125,9 @@ def _mix_hash(bits: List[jax.Array]) -> jax.Array:
 
 def _normalize_red_limbs(red, layout, aggs):
     """Carry-normalize (lo, hi) decimal-sum limb pairs in a reduced
-    payload list (post-exchange reduce), keeping lo in [0, 2^32) for
-    the TopN limb sort keys and the host finalize."""
+    payload list (the post-exchange reduce's; on one part the partial
+    reduce's, which is the last), keeping lo in [0, 2^32) for the TopN
+    limb sort keys and the host finalize."""
     from tidb_tpu.executor.aggregate import normalize_limbs
 
     idx_of = {name: i for i, (name, _) in enumerate(layout)}
@@ -156,6 +163,7 @@ class FragmentProgram:
     sources: List[_Source]
     broadcasts: List[_Broadcast]
     n_growth: int                      # number of growth knobs
+    n_exchange: int                    # repartitions compiled in (0 on one part)
     sig: str
     build_fn: Callable                 # (growths tuple) -> per-shard program
     out_kind: str                      # "segment" | "generic"
@@ -192,6 +200,8 @@ class _Compiler:
         self.growth_kinds: List[str] = []
         self.sig: List[str] = []
         self.stream_unsafe: set = set()
+        # repartitions compiled into the program (0 on one part)
+        self.n_exchange = 0
 
     def _add_growth(self, default: float, kind: str) -> int:
         idx = self.n_growth
@@ -402,7 +412,10 @@ class _Compiler:
             self.stream_unsafe.update(
                 range(n_before_build, len(self.sources)))
 
-        exchange = not build_is_bcast
+        # one part owns every key: nothing to exchange, no knob for it
+        exchange = not build_is_bcast and self.n_parts > 1
+        if exchange:
+            self.n_exchange += 2
         g_exch = self._add_growth(2.0, "exch") if exchange else None
         g_expand = self._add_growth(1.0, "expand")
         # estimate-sized compaction targets (overflow-retried): selective
@@ -716,47 +729,43 @@ class _Compiler:
 
         if not agg.group_exprs:
             raise _Unsupported("generic global agg")  # planner uses segment
-        partial = make_partial_kernel(agg.group_exprs, agg.aggs)
+        n_parts = self.n_parts
+        # one part owns every key, so its partial table is the final
+        # table: no exchange of the groups, no second sort-reduce, no
+        # knob for either. What the second pass guaranteed is asked of
+        # the first: a duplicate-free table (`exact`: several keys order
+        # by their mixed hash, and a collision may split a group) with
+        # carry-normalised limbs
+        one_part = n_parts == 1
+        partial = make_partial_kernel(agg.group_exprs, agg.aggs,
+                                      exact=one_part)
         layout = _state_layout(agg.aggs)
         nk = len(agg.group_exprs)
         topn_fn = (self._topn_select(topn[0], nk, layout, topn[1], agg.aggs)
                    if topn is not None else None)
-        g_agg = self._add_growth(2.0, "exch")
-        n_parts = self.n_parts
+        if one_part:
+            g_agg = None
+        else:
+            g_agg = self._add_growth(2.0, "exch")
+            self.n_exchange += 1
         # estimate-sized shrink targets (see _compact): the partial sort
         # pays for input capacity and the exchange pays for table slots
         g_in, in_base = self._compact_knob(agg.child.est_rows)
-        # every slot of the table is exchanged and sorted again by every
-        # statement: where the groups are bounded by the keys' distinct
-        # count, the table takes the smaller headroom
+        # every slot of the table is exchanged and sorted again (fetched
+        # and decoded, on one part) by every statement: where the groups
+        # are bounded by the keys' distinct count, the table takes the
+        # smaller headroom
         g_tab, tab_base = self._compact_knob(
             agg.est_rows,
             self.NDV_HEADROOM if agg.est_from_ndv else self.HEADROOM)
-        self.sig.append(f"genagg:{agg.group_exprs!r}:{agg.aggs!r}")
+        self.sig.append(
+            f"genagg:{agg.group_exprs!r}:{agg.aggs!r}:exch{not one_part}")
 
-        def emit(env, growths):
-            chunk, ovfs = child_emit(env, growths)
-            capI = int(np.ceil(growths[g_in] * in_base))
-            if capI < chunk.capacity:
-                with jax.named_scope("agg.compact"):
-                    chunk, o = _compact_chunk(chunk, capI)
-                    ovfs.append((g_in, pmax(o, _AXES)))
-            with jax.named_scope("agg.partial"):
-                table = partial(chunk)  # local dedup before the exchange
-                S = table["k0.d"].shape[0]
-                capT = int(np.ceil(growths[g_tab] * tab_base))
-                if capT < S:
-                    # groups are dense in [0, n): slicing the slot arrays
-                    # is free and shrinks everything the exchange must
-                    # carry
-                    factor = (table["n"] + capT - 1) // capT
-                    ovfs.append(
-                        (g_tab, pmax(jnp.maximum(factor - 1, 0), _AXES)))
-                    table = {k: (v if k == "n" else v[:capT])
-                             for k, v in table.items()}
-                    S = capT
+        def exchange_and_reduce(table, growths, ovfs):
+            """The partial groups to the parts that own their keys, and
+            there reduced again: (n, keys, key validity, states)."""
             with jax.named_scope("exchange.agg"):
-                live = jnp.arange(S) < table["n"]
+                live = jnp.arange(table["k0.d"].shape[0]) < table["n"]
                 kd = [table[f"k{i}.d"] for i in range(nk)]
                 kv = [table[f"k{i}.v"] for i in range(nk)]
                 khash = _mix_hash([_key_bits(d, v) for d, v in zip(kd, kv)])
@@ -783,7 +792,35 @@ class _Compiler:
                 # no merge
                 n, fk, fkv, red = _sort_reduce(rbits, rkv, rkd, recv_sel,
                                                payload, ops, exact=True)
-                red = _normalize_red_limbs(red, layout, agg.aggs)
+                return n, fk, fkv, _normalize_red_limbs(red, layout, agg.aggs)
+
+        def emit(env, growths):
+            chunk, ovfs = child_emit(env, growths)
+            capI = int(np.ceil(growths[g_in] * in_base))
+            if capI < chunk.capacity:
+                with jax.named_scope("agg.compact"):
+                    chunk, o = _compact_chunk(chunk, capI)
+                    ovfs.append((g_in, pmax(o, _AXES)))
+            with jax.named_scope("agg.partial"):
+                table = partial(chunk)  # local dedup before the exchange
+                capT = int(np.ceil(growths[g_tab] * tab_base))
+                if capT < table["k0.d"].shape[0]:
+                    # groups are dense in [0, n): slicing the slot arrays
+                    # is free and shrinks everything the exchange must
+                    # carry (on one part: everything the host fetches)
+                    factor = (table["n"] + capT - 1) // capT
+                    ovfs.append(
+                        (g_tab, pmax(jnp.maximum(factor - 1, 0), _AXES)))
+                    table = {k: (v if k == "n" else v[:capT])
+                             for k, v in table.items()}
+                if one_part:
+                    n = table["n"]
+                    fk = [table[f"k{i}.d"] for i in range(nk)]
+                    fkv = [table[f"k{i}.v"] for i in range(nk)]
+                    red = _normalize_red_limbs(
+                        [table[name] for name, _ in layout], layout, agg.aggs)
+            if not one_part:
+                n, fk, fkv, red = exchange_and_reduce(table, growths, ovfs)
             if topn_fn is not None:
                 with jax.named_scope("agg.topn"):
                     n, fk, fkv, red = topn_fn(n, fk, fkv, red)
@@ -870,7 +907,8 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
 
     return FragmentProgram(
         agg=agg, sources=c.sources, broadcasts=c.broadcasts,
-        n_growth=c.n_growth, sig="|".join(c.sig), build_fn=build_fn,
+        n_growth=c.n_growth, n_exchange=c.n_exchange, sig="|".join(c.sig),
+        build_fn=build_fn,
         out_kind=out_kind, domains=domains,
         growth_defaults=tuple(c.growth_defaults),
         growth_kinds=tuple(c.growth_kinds),

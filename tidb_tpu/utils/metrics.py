@@ -441,6 +441,13 @@ GC_RECLAIMED = Counter("tidb_tpu_gc_reclaimed_rows_total",
 CONN_GAUGE = Gauge("tidb_tpu_connections", "Open server connections")
 FRAGMENT_DISPATCH = Counter("tidb_tpu_fragment_dispatch_total",
                             "Distributed fragment executions, by kind")
+FRAGMENT_EXCHANGE_STEPS = Counter(
+    "tidb_tpu_fragment_exchange_steps_total",
+    "Exchange steps (repartitions of rows by key over the mesh) compiled "
+    "into the fragment programs launched, by fragment kind: a launch adds "
+    "its program's count (the mesh join 2, a generic aggregate 1, each "
+    "repartitioned join of a general fragment 2; on a mesh of one part 0, "
+    "where nothing is exchanged)")
 FRAGMENT_RETRY_TOTAL = Counter(
     "tidb_tpu_fragment_retry_total",
     "Fragment launches thrown away because a capacity knob overflowed "
