@@ -126,27 +126,8 @@ def test_probe_ranges_xla_compiles(one_chip, tpu_target):
     """The open-addressing table build + window-scan probe (what
     tidb_tpu_join_probe_mode=auto resolves to on a TPU)."""
     s = _sds(one_chip)
-    _compile(hp.probe_ranges, s((1 << 18,), jnp.int64), s((R,), jnp.int64),
-             use_pallas=False)
-
-
-@pytest.mark.xfail(strict=True, raises=ValueError,
-                   reason=hp.PALLAS_PROBE_REFUSAL)
-def test_probe_pallas_is_refused(one_chip, tpu_target):
-    """hash_probe._probe_pallas does not lower for the chip
-    (`k = keys_ref[pos]` is a vector gather from a VMEM ref). Strict:
-    when it starts compiling, ROADMAP D3 decides to keep it."""
-    s = _sds(one_chip)
-    _compile(hp.probe_ranges, s((1 << 12,), jnp.int64),
-             s((1 << 14,), jnp.int64), use_pallas=True)
-
-
-def test_probe_mode_pallas_raises_typed_on_tpu(tpu_target):
-    from tidb_tpu.errors import UnsupportedError
-
-    with pytest.raises(UnsupportedError, match=hp.PALLAS_PROBE_REFUSAL):
-        hp.resolve_mode("pallas")
-    assert hp.resolve_mode("auto") == "xla"  # never picked automatically
+    _compile(hp.probe_ranges, s((1 << 18,), jnp.int64), s((R,), jnp.int64))
+    assert hp.resolve_mode("auto") == "xla"
 
 
 @pytest.mark.parametrize("kernel", [
@@ -321,8 +302,8 @@ def test_fused_scan_agg_program_compiles(one_chip, tpu_target, tiny_tpch):
         stages, col_types, group_exprs, aggs, domains, seg_cap),
         donate_argnums=0)
     sd = _sds(one_chip)
-    # the served chunk (one segment per batch) and bench.py's 1<<20
-    # packed batch (16 segments through the program's internal scan)
+    # the served chunk (one segment per batch) and a 1<<20 packed
+    # batch (16 segments through the program's internal scan)
     for n in (seg_cap, R):
         data = {u: sd((n,), a.dtype) for u, a in data0.items()}
         valid = {u: sd((n,), a.dtype) for u, a in valid0.items()}

@@ -150,8 +150,8 @@ class TestStore:
 
 class TestApdDecision:
     """The measured push-vs-no-push protocol, driven synthetically so
-    the choice is deterministic (the Q18 bench carries the real-scale
-    acceptance: perf_check asserts chosen_by_feedback)."""
+    the choice is deterministic (at real scale: not measured, no cell
+    reaches the fused tier)."""
 
     def test_protocol(self):
         st = fb.PlanFeedbackStore()

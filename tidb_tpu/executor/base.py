@@ -121,7 +121,7 @@ class ExecContext:
     # (tidb_tpu_join_tiles_per_dispatch sysvar)
     join_tiles: int = 8
     # probe strategy for the device join: off = searchsorted, auto =
-    # hash table on TPU / searchsorted on CPU, xla/pallas force the
+    # hash table on TPU / searchsorted on CPU, xla forces the
     # open-addressing table (tidb_tpu_join_probe_mode sysvar)
     join_probe_mode: str = "auto"
     # rows above which a fragment build side refuses to replicate and
@@ -158,9 +158,6 @@ class ExecContext:
     # byte budget of the cross-statement device buffer cache; 0 = off
     # (tidb_tpu_device_buffer_cache_bytes)
     device_buffer_cache_bytes: int = 256 << 20
-    # stage fragment inputs FoR-encoded in narrow dtypes, decoded inside
-    # the fragment program (tidb_tpu_stage_encoded)
-    stage_encoded: bool = True
 
     def __post_init__(self):
         if self.mem_tracker is None:

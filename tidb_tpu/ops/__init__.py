@@ -5,8 +5,9 @@ kernels for the few primitives XLA lowers poorly — today the segment
 aggregation scatter-add (ref: SURVEY.md §7.4's "Pallas hash-table /
 segment kernel as the optimized path"). Every kernel has an XLA
 reference implementation; `pallas_enabled()` gates dispatch (TPU
-backend only, overridable for benchmarks), and ops/SEGSUM_BENCH.json
-records the microbenchmark that justifies the default.
+backend only; `set_pallas_enabled` overrides it for the tier-1 tests).
+What the kernels cost on the chip is the scan cell's ledger line
+(ROADMAP B3).
 """
 
 from tidb_tpu.ops.segment_sum import (

@@ -1,4 +1,4 @@
-"""Open-addressing hash probe for the device joins (Pallas).
+"""Open-addressing hash probe for the device joins.
 
 The host-tier join (executor/join.py) and the general mesh fragment's
 join (parallel/fragment.py) sort their build side and, pre-ISSUE 10,
@@ -12,11 +12,11 @@ module supplies that table, consumed two ways: the fragment join
 via `probe_for_join`, and the main single-chip join (ISSUE 10) builds
 it ONCE per join build (ops/join_kernels.build_hash_table) and probes
 it per chunk with the table arrays as kernel args. Strategy selection:
-`tidb_tpu_join_probe_mode` (off/auto/xla/pallas) through
-`resolve_mode` — auto picks the table exactly when the computation
-targets TPU. The mesh tier's unique-key join under a segment
-aggregate (parallel/distsql.py `_local_join`) never came through here:
-it ranks both sides by one sort (PR 26), with no table and no mode.
+`tidb_tpu_join_probe_mode` (off/auto/xla) through `resolve_mode` —
+auto picks the table exactly when the computation targets TPU. The
+mesh tier's unique-key join under a segment aggregate
+(parallel/distsql.py `_local_join`) never came through here: it ranks
+both sides by one sort (PR 26), with no table and no mode.
 
   * BUILD (XLA, inside the same jit): runs of equal values in the sorted
     hash array become (lo, hi) ranges; each run's FIRST row inserts
@@ -26,10 +26,11 @@ it ranks both sides by one sort (PR 26), with no table and no mode.
     arbitration). `placed` tracks success — if any run needs more than
     MAX_PROBES displacements the whole probe falls back to searchsorted
     THROUGH lax.cond, so results never depend on table luck.
-  * PROBE (Pallas): the table lives in VMEM (the kernel targets
-    dimension-sized build sides; capacity is capped so three i32 tables
-    fit comfortably), each probe element scans its MAX_PROBES window
-    with vectorized selects — no data-dependent loop, no divergence.
+  * PROBE (XLA): each probe element scans its MAX_PROBES window with
+    vectorized selects over gathers of the table — no data-dependent
+    loop, no divergence. Not a Pallas kernel over a VMEM-resident
+    table: the v5e compiler refuses a gather by a vector of positions
+    inside a kernel ("Cannot do int indexing on TPU").
 
 Correctness envelope: every inserted run sits within MAX_PROBES slots
 of its home (else the searchsorted branch runs), so a probe that scans
@@ -40,46 +41,34 @@ searchsorted — pinned by tests against it (tests/test_ops_probe.py).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tidb_tpu.errors import UnsupportedError
-from tidb_tpu.ops.segment_sum import pallas_enabled, pallas_interpret
-from tidb_tpu.utils.device import target_platform
+from tidb_tpu.ops.segment_sum import pallas_enabled
 
 __all__ = ["probe_ranges", "xla_probe_ranges", "probe_for_join",
            "set_mode", "resolve_mode", "table_capacity", "MAX_CAPACITY"]
 
-import os
-
 # "off": always searchsorted; "auto" (default): hash table when the
 # computation targets TPU (trace-time force_platform aware, like
-# segment_sum); "xla": hash table everywhere (window-scan probe);
-# "pallas": hash table with the Pallas VMEM kernel — CPU interpret only:
-# the TPU compiler refuses the kernel (_refuse_pallas_on_tpu), so no
-# automatic choice ever picks it.
-#
-# Auto keeps CPU on searchsorted because it measures faster there
-# (bench.py bench_probe — 32 fixed window rounds vs ~2*log2(Rb)
-# cache-friendly binary rounds) while TPU gets the VMEM-resident table
-# instead of O(log Rb) dependent HBM gather rounds per element.
+# segment_sum), searchsorted elsewhere; "xla": hash table everywhere.
+# Which of the two is faster on either platform is not measured on the
+# chip (no cell reaches this module: ROADMAP D3).
 # Sessions thread tidb_tpu_join_probe_mode PER STATEMENT through
 # ExecContext/fragment args (ISSUE 12 — the old per-statement set_mode
 # write raced concurrent sessions); this global is only the default
-# for offline tools and bare fragments, seeded by the env var.
-_mode = os.environ.get("TIDB_HASH_PROBE", "auto")
+# for bare fragments.
+_mode = "auto"
 
 
 def set_mode(m: str) -> None:
-    """Seed the PROCESS-WIDE default probe mode. Offline tools and bare
-    fragments only: engine statements thread the session's resolved
-    mode per-statement (ExecContext.join_probe_mode -> fragment args,
-    ISSUE 12), so concurrent sessions never race this global. The
-    sanitizer's shared-mutable-global witness flags any write that
-    lands while a statement is in flight."""
+    """Seed the PROCESS-WIDE default probe mode. Bare fragments only:
+    engine statements thread the session's resolved mode per-statement
+    (ExecContext.join_probe_mode -> fragment args, ISSUE 12), so
+    concurrent sessions never race this global. The sanitizer's
+    shared-mutable-global witness flags any write that lands while a
+    statement is in flight."""
     global _mode
     from tidb_tpu.analysis import sanitizer as _san
 
@@ -88,34 +77,16 @@ def set_mode(m: str) -> None:
     _mode = m
 
 
-PALLAS_PROBE_REFUSAL = "Cannot do int indexing on TPU"
-
-
-def _refuse_pallas_on_tpu() -> None:
-    """'pallas' asked for on a TPU: raise the compiler's refusal typed,
-    at plan time, instead of interpreting the kernel or quietly probing
-    another way. _probe_pallas gathers table slots by a vector of
-    positions (keys_ref[pos]), which Mosaic does not lower;
-    tests/test_chip_compile.py pins the compiler's own error."""
-    if target_platform() == "tpu":
-        raise UnsupportedError(
-            "tidb_tpu_join_probe_mode=pallas cannot run on a TPU: the "
-            "chip's compiler refuses hash_probe._probe_pallas "
-            f"(ValueError: {PALLAS_PROBE_REFUSAL}); use auto, xla or off")
-
-
 def resolve_mode(mode: str = None) -> str:
-    """Concrete probe strategy — 'sorted' | 'xla' | 'pallas' — for the
-    platform the CURRENT computation targets (trace-time, so mesh
-    fragments under force_platform resolve against the mesh's devices).
-    `mode` defaults to the module global the session sysvar wires."""
+    """Concrete probe strategy — 'sorted' | 'xla' — for the platform
+    the CURRENT computation targets (trace-time, so mesh fragments
+    under force_platform resolve against the mesh's devices). `mode`
+    defaults to the module global the session sysvar wires."""
     m = _mode if mode is None else mode
     if m == "off":
         return "sorted"
     if m == "auto":
         return "xla" if pallas_enabled() else "sorted"
-    if m == "pallas":
-        _refuse_pallas_on_tpu()
     return m
 
 
@@ -125,20 +96,16 @@ def probe_for_join(sorted_hashes: jax.Array, probes: jax.Array,
     sorted build hashes via the configured strategy. ``mode`` is the
     per-statement value threaded from ExecContext through the fragment
     builder (ISSUE 12 — the trace-time global read raced concurrent
-    sessions); None falls back to the process default for offline
-    tools and bare fragments."""
-    m = _mode if mode is None else mode
-    if m == "off" or (m == "auto" and not pallas_enabled()):
+    sessions); None falls back to the process default for bare
+    fragments."""
+    if resolve_mode(mode) == "sorted":
         lo, hi = xla_probe_ranges(sorted_hashes, probes)
         return lo.astype(jnp.int64), hi.astype(jnp.int64)
-    if m == "pallas":
-        _refuse_pallas_on_tpu()
-    return probe_ranges(sorted_hashes, probes,
-                        use_pallas=(m == "pallas"))
+    return probe_ranges(sorted_hashes, probes)
 
 MAX_PROBES = 32
-# three int32 tables of this capacity ~= 6 MiB of VMEM: dimension-sized
-# build sides (the star-join case) qualify; big fact-fact joins keep the
+# three int32 tables of this capacity ~= 6 MiB: dimension-sized build
+# sides (the star-join case) qualify; big fact-fact joins keep the
 # searchsorted path
 MAX_CAPACITY = 1 << 19
 
@@ -181,7 +148,7 @@ def _next_pow2(n: int) -> int:
 def table_capacity(n_build: int):
     """Open-addressing table capacity for an `n_build`-row build side,
     or None when the table is ineligible (load factor would exceed 1/2
-    within the VMEM cap, or the build is empty). One definition shared
+    within MAX_CAPACITY, or the build is empty). One definition shared
     by probe_ranges (fragment tier, in-jit) and the main join's
     build-time table construction (ops/join_kernels.build_hash_table)."""
     if n_build == 0:
@@ -240,8 +207,8 @@ def _build_table(sh: jax.Array, cap: int):
 
 
 def _probe_xla(keys, los, his, sh, probes, cap):
-    """Window-scan probe expressed in plain XLA (the same arithmetic the
-    Pallas kernel runs; also the interpret-mode/CPU executable path)."""
+    """Window-scan probe: MAX_PROBES rounds of vectorized selects over
+    gathers of the table."""
     mask = cap - 1
     home = _mix32(probes) & mask
     fp = _mix32(probes, salt=_FP_SALT)
@@ -272,69 +239,8 @@ def _probe_xla(keys, los, his, sh, probes, cap):
     return lo.astype(jnp.int64), hi.astype(jnp.int64)
 
 
-def _probe_pallas(keys, los, his, sh, probes, cap):
-    """VMEM-resident table scan: one grid step per probe tile, the three
-    [cap] tables mapped whole into VMEM, MAX_PROBES vectorized rounds."""
-    from jax.experimental import pallas as pl
-
-    T = 2048
-    Rp = probes.shape[0]
-    n_tiles = (Rp + T - 1) // T
-    pad = n_tiles * T - Rp
-    probes_p = jnp.concatenate(
-        [probes, jnp.full(pad, -1, dtype=probes.dtype)]) if pad else probes
-    mask = cap - 1
-    home = (_mix32(probes_p) & mask).astype(jnp.int32)
-    fp = _mix32(probes_p, salt=_FP_SALT)
-    # probe-side hash identity check runs on the table's lo -> sh lookup;
-    # precompute sh as int32 pair to keep the kernel i32-only
-    sh_hi = (sh >> 32).astype(jnp.int32)
-    sh_lo = sh.astype(jnp.int32)
-    pr_hi = (probes_p >> 32).astype(jnp.int32)
-    pr_lo = probes_p.astype(jnp.int32)
-
-    def kernel(home_ref, fp_ref, prhi_ref, prlo_ref, keys_ref, los_ref,
-               his_ref, shhi_ref, shlo_ref, lo_ref, hi_ref):
-        h = home_ref[...]
-        f = fp_ref[...]
-        phi = prhi_ref[...]
-        plo = prlo_ref[...]
-        lo = jnp.zeros_like(h)
-        hi = jnp.zeros_like(h)
-        found = jnp.zeros(h.shape, dtype=jnp.bool_)
-        Rb = shhi_ref.shape[0]
-        for r in range(MAX_PROBES):
-            pos = (h + r) & mask
-            k = keys_ref[pos]
-            cand = los_ref[pos]
-            ci = jnp.clip(cand, 0, Rb - 1)
-            hit = ((~found) & (k == f)
-                   & (shhi_ref[ci] == phi) & (shlo_ref[ci] == plo))
-            lo = jnp.where(hit, cand, lo)
-            hi = jnp.where(hit, his_ref[pos], hi)
-            found = found | hit
-        lo_ref[...] = lo
-        hi_ref[...] = hi
-
-    grid = (n_tiles,)
-    tile = pl.BlockSpec((T,), lambda i: (i,))
-    whole_cap = pl.BlockSpec((cap,), lambda i: (0,))
-    whole_rb = pl.BlockSpec((sh.shape[0],), lambda i: (0,))
-    lo32, hi32 = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[tile, tile, tile, tile, whole_cap, whole_cap, whole_cap,
-                  whole_rb, whole_rb],
-        out_specs=[tile, tile],
-        out_shape=[jax.ShapeDtypeStruct((n_tiles * T,), jnp.int32)] * 2,
-        interpret=pallas_interpret(),
-    )(home, fp, pr_hi, pr_lo, keys, los, his, sh_hi, sh_lo)
-    return lo32[:Rp].astype(jnp.int64), hi32[:Rp].astype(jnp.int64)
-
-
-@functools.partial(jax.jit, static_argnames=("use_pallas",))
-def probe_ranges(sorted_hashes: jax.Array, probes: jax.Array,
-                 use_pallas: bool = False):
+@jax.jit
+def probe_ranges(sorted_hashes: jax.Array, probes: jax.Array):
     """(lo, hi) per probe element over the sorted build hashes —
     numerically identical to searchsorted left/right wherever the join
     consumes them (hi - lo counts and lo + k positions). Falls back to
@@ -345,13 +251,12 @@ def probe_ranges(sorted_hashes: jax.Array, probes: jax.Array,
     _note_trace("hash_probe")  # trace-time only: joins the retrace guard
     cap = table_capacity(sorted_hashes.shape[0])
     if cap is None:
-        # load factor would exceed 1/2 (or VMEM): stay on searchsorted
+        # load factor would exceed 1/2 within MAX_CAPACITY: stay on
+        # searchsorted
         return xla_probe_ranges(sorted_hashes, probes)
     keys, los, his, ok = _build_table(sorted_hashes, cap)
 
     def fast(_):
-        if use_pallas:
-            return _probe_pallas(keys, los, his, sorted_hashes, probes, cap)
         return _probe_xla(keys, los, his, sorted_hashes, probes, cap)
 
     def slow(_):

@@ -272,7 +272,7 @@ def probe_ranges_any(sorted_keys, n_build, packed, firsts, lo_packed,
     and the fused scan→probe program so the two cannot drift. Strategy
     is static: 'direct' wins when the dense-domain index exists (two
     O(1) gathers beat any hash walk), else the open-addressing table
-    ('xla' window scan / 'pallas' VMEM kernel) with the in-jit lax.cond
+    ('xla': the window scan) with the in-jit lax.cond
     searchsorted fallback when the build overflowed its displacement
     bound, else plain searchsorted. Ranges clamp to n_build so the
     NULL/dead/padding sentinel tail can never produce a match."""
@@ -287,9 +287,8 @@ def probe_ranges_any(sorted_keys, n_build, packed, firsts, lo_packed,
         return jnp.take(firsts, idxc), jnp.take(firsts, idxc + 1), in_range
     if probe != "sorted":
         def fast(_):
-            fn = hp._probe_pallas if probe == "pallas" else hp._probe_xla
-            return fn(tkeys, tlos, this, sorted_keys, packed,
-                      tkeys.shape[0])
+            return hp._probe_xla(tkeys, tlos, this, sorted_keys, packed,
+                                 tkeys.shape[0])
 
         def slow(_):
             lo = jnp.searchsorted(sorted_keys, packed, side="left")
@@ -340,7 +339,7 @@ def probe_count(sorted_keys, n_build, key_datas, key_valids, sel,
     ok, matched). ``total`` is the only value a caller syncs to the
     host (to size the expansion). ``table`` is the prebuilt
     open-addressing table (build_hash_table) consulted when ``probe``
-    is 'xla'/'pallas'; 'sorted' takes placeholder args and the
+    is 'xla'; 'sorted' takes placeholder args and the
     searchsorted branch."""
     from tidb_tpu.utils.metrics import JOIN_PROBE_MODE_TOTAL
 

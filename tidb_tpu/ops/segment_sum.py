@@ -1,13 +1,20 @@
-"""Segment aggregation as tiled one-hot MXU matmuls (Pallas).
+"""Segment aggregation as a tiled one-hot multiply-and-sum (Pallas).
 
 XLA lowers `acc.at[seg].add(vals)` to a serialized scatter on TPU; the
-MXU-native formulation is a one-hot matmul per tile:
+kernels here reduce each tile of 1,024 rows against a one-hot of its
+segment ids instead. It is NOT a matmul: the one-hot is built and
+contracted on the VPU (a broadcast multiply and a sum over the tile),
+the MXU is not used:
 
-    onehot[t, g] = (seg[t] == g)          # [TILE, G] built from iota
-    partial[g]   = vals[1, TILE] @ onehot # one MXU pass
-    out[g]      += partial                # accumulated across the grid
+    onehot[s, l, g] = (seg[s, l] == g)            # [8, 128, Gp] from iota
+    partial[g]      = sum_{s,l} vals[s, l] * onehot[s, l, g]
+    out[g]         += partial                     # accumulated across the grid
 
-Exactness: f32 matmul accumulation is integer-exact below 2^24, so
+On the chip the int64 kernel is 96% of the scan cell's device time at
+0.15% of the HBM roofline (`tpch_sf1.scan`, `scan_agg_roofline`
+0.14618, ledger, PR 29; 29.4 ms a call over 6.0M rows): ROADMAP B3.
+
+Exactness: f32 accumulation is integer-exact below 2^24, so
   * segment_count is EXACT for any chunk up to 2^24 rows (per-tile
     partial <= TILE, total <= R) — counts dispatch to Pallas on TPU;
   * segment_sum_f32 matches XLA f32 summation to reordering — used for
@@ -62,8 +69,8 @@ def pallas_interpret() -> bool:
     compiles the kernel: one the chip's compiler refuses raises, it is
     never run interpreted and never handed to the XLA reference. On the
     CPU a Pallas kernel is reached only when the caller asked for it
-    explicitly (set_pallas_enabled(True), probe mode 'pallas' — the
-    tier-1 tests), and there it interprets. The choice goes to the
+    explicitly (set_pallas_enabled(True) — the tier-1 tests), and
+    there it interprets. The choice goes to the
     placement log when chip_smoke.py has switched that on."""
     p = target_platform()
     if p == "tpu":
@@ -239,8 +246,10 @@ def segment_count(mask: jax.Array, seg: jax.Array, G: int) -> jax.Array:
     """Count mask-true rows per segment, EXACT (counts < 2^24), int64.
 
     The hottest accumulator shape in segment aggregation: occ + one cnt
-    per aggregate function all reduce a boolean through this. 10-13x
-    faster than the XLA int64 scatter on TPU v5e (ops/SEGSUM_BENCH.json)."""
+    per aggregate function all reduce a boolean through this. Against
+    the XLA int64 scatter on the chip: not measured (the f32 kernel
+    takes 6.9 ms a call over 6.0M rows in the one-chip join's
+    `agg.update`, PERF.md section 5, PR 29)."""
     if (not pallas_enabled() or G > _MAX_PALLAS_G
             or mask.shape[0] >= (1 << 24)):  # f32 exactness bound
         return xla_segment_sum(mask.astype(jnp.int64), seg, G)

@@ -202,10 +202,6 @@ def _types_sig(st: ShardedTable) -> str:
     return repr(sorted((n, t) for n, t in st.types.items()))
 
 
-# single-CPU-backend routing threshold: fragments whose largest base
-# table is below this run on the device path even without an accelerator
-# (XLA fusion amortizes); above it, sort-bound joins/generic aggs go to
-# the numpy host engine, which wins 2-3x there
 def _collapse_to_scan(plan: PhysicalPlan):
     """Fuse Selection/Projection chain onto a single scan; return
     (scan, stages) or None if the subtree isn't a pushable pipeline."""
@@ -281,9 +277,8 @@ class DistAggExec(HashAggExec):
         sig = repr((self._stages, self.group_exprs, self.aggs, domains))
         state = None
         fn = None
-        enc = bool(getattr(self.ctx, "stage_encoded", True))
         for st in stream_batches(table, mesh, scan_cols,
-                                 self.STREAM_ROWS_PER_PART, encode=enc):
+                                 self.STREAM_ROWS_PER_PART, encode=True):
             raise_if_cancelled(self.ctx)  # see _run_fragment_streaming
             if fn is None:
                 key = ("agg", sig, st.n_parts, st.rows_per_part,
@@ -676,7 +671,6 @@ class DistFragmentExec(HashAggExec):
         # the STREAMED source stages encoded (its bytes move every
         # batch); resident co-sources stay raw like every other
         # resident sharding
-        enc = bool(getattr(self.ctx, "stage_encoded", True))
         sts = {}
         for i, s2 in enumerate(prog.sources):
             if i != stream_idx:
@@ -696,7 +690,7 @@ class DistFragmentExec(HashAggExec):
         gen_parts = None  # part index -> [host partial dicts]
         nk = len(self.group_exprs)
         for batch in stream_batches(table, mesh, scan_cols, rows_per_part,
-                                    encode=enc):
+                                    encode=True):
             # a KILL or deadline expiry must interrupt a >HBM streamed
             # fragment between batches, not only at the root chunk loop
             # (which never runs until every batch has been merged)
@@ -922,10 +916,9 @@ def build_dist_executor(plan: PhysicalPlan, cache: ShardCache,
         if not full:
             # single-CPU backend: keep segment scan-aggs on device
             # (linear scatter-adds win) but run joins and generic
-            # aggregation on the vectorized host engine at EVERY size —
-            # XLA:CPU's sort-based join fragments measured 2.7x slower
-            # than the host engine even at 75k rows (TPC-DS Q95 SF0.5),
-            # and the gap only widens with input size (BASELINE.md).
+            # aggregation on the vectorized host engine at EVERY size:
+            # XLA:CPU's sort-based fragments lose to the numpy engine
+            # there, which is why `full=False` exists.
             if plan.strategy == "segment":
                 frag = _collapse_to_scan(plan.child)
                 if frag is not None:

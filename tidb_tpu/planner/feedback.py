@@ -27,8 +27,8 @@ compared against reality). The store closes that loop:
       (b) the eager-agg push-down decision becomes measured: when a
           digest's default plan carries an eager partial, the
           alternative (no-push, fusible) plan is explored once and the
-          warm-measured faster variant wins — the Q18 bench no longer
-          pins ``tidb_opt_agg_push_down=0``;
+          warm-measured faster variant wins (no statement needs a
+          ``tidb_opt_agg_push_down=0`` pin);
       (c) fused-probe tile sizing: observed overflow rates raise the
           statement's ``join_tiles`` so dup-heavy probes expand in
           fewer dispatches.

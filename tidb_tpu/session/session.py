@@ -1090,7 +1090,6 @@ class Session:
                 self.sysvars.get("tidb_tpu_pipeline_prefetch_depth")),
             device_buffer_cache_bytes=int(
                 self.sysvars.get("tidb_tpu_device_buffer_cache_bytes")),
-            stage_encoded=bool(self.sysvars.get("tidb_tpu_stage_encoded")),
             cancel_check=self.cancel_reason,
         )
         if self._fb_enabled():

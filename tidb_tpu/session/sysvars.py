@@ -115,15 +115,14 @@ _reg(
     # match ranges over the sorted build keys. off = searchsorted always;
     # auto = open-addressing hash table when the computation targets TPU
     # (trace-time force_platform aware, like segment_sum), searchsorted
-    # on CPU where its cache-friendly binary rounds measure faster;
-    # xla/pallas force the table everywhere (window-scan probe / Pallas
-    # VMEM kernel). Dense packed-key domains keep the O(1) direct-address
-    # index regardless. Threaded per-statement through ExecContext into
-    # BOTH tiers (fragment programs take it as a trace-time static in
-    # their cache key) — the hash_probe process global is only the
-    # offline default (ISSUE 12 fixed the set_mode race).
+    # on CPU; xla forces the table everywhere (window-scan probe). Dense
+    # packed-key domains keep the O(1) direct-address index regardless.
+    # Threaded per-statement through ExecContext into BOTH tiers
+    # (fragment programs take it as a trace-time static in their cache
+    # key) — the hash_probe process global is only the default of bare
+    # fragments (ISSUE 12 fixed the set_mode race).
     SysVar("tidb_tpu_join_probe_mode", "auto", BOTH, "enum",
-           enum_values=("off", "auto", "xla", "pallas")),
+           enum_values=("off", "auto", "xla")),
     # -- runtime invariant sanitizer (ISSUE 12) ------------------------
     # debug mode: wrap the registered locks in the runtime order
     # witness, audit tracker/pin balances at statement end, count
@@ -235,9 +234,6 @@ _reg(
     # like the plan cache); 0 disables it. GLOBAL: one cache per process
     SysVar("tidb_tpu_device_buffer_cache_bytes", 256 << 20, GLOBAL, "int",
            min_=0, max_=1 << 40),
-    # stage fragment inputs as frame-of-reference-encoded narrow arrays
-    # (decode fused into the fragment program) instead of raw int64
-    SysVar("tidb_tpu_stage_encoded", True, BOTH, "bool"),
     # fixed device batch capacity (ref: tidb_max_chunk_size)
     SysVar("tidb_max_chunk_size", 1 << 16, BOTH, "int", min_=1 << 10, max_=1 << 24),
     # per-query host-side memory budget in bytes (ref: tidb_mem_quota_query)

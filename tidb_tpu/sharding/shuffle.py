@@ -4,9 +4,9 @@ Generalizes the fragment tier's all_to_all repartition
 (``parallel/distsql.repartition_by_key``) to workers in separate
 processes: the sender partitions its live rows by the join/placement
 key with the SAME hash the device exchange uses, encodes each
-destination's batch frame-of-reference compressed (the PR 9
-``tidb_tpu_stage_encoded`` format — ``columnar.encoding.encode_column``
-is the one encoder), and ships it over the DCN codec (numpy arrays are
+destination's batch frame-of-reference compressed (the streamed
+staging format of ``parallel/partition.shard_table(encode=True)`` —
+``columnar.encoding.encode_column`` is the one encoder), and ships it over the DCN codec (numpy arrays are
 first-class there). The receiver reassembles batches into staged
 chunks through a ``ShuffleInbox`` whose bytes are charged to a
 MemTracker — backpressure is a typed OOM on the sender's stage RPC,

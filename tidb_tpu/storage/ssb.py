@@ -1,5 +1,5 @@
 """Star Schema Benchmark: schema, generator, and the 13 queries
-(BASELINE.md eval config "SSB Q3.x SF100 — 4-way star hash join").
+(BASELINE.json eval config "SSB Q3.x star-join SF100").
 
 SSB is TPC-H refactored into one fact table (lineorder) plus four
 dimensions (customer, supplier, part, date), specifically to exercise

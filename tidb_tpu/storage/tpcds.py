@@ -1,5 +1,5 @@
-"""TPC-DS subset for the Q95 eval config (BASELINE.md: "TPC-DS Q95
-SF100 — semi-join / correlated subquery, MPP exchange").
+"""TPC-DS subset for the Q95 eval config (BASELINE.json: "TPC-DS Q95
+SF100 (semi-join / correlated subquery ...)").
 
 Q95 counts web orders shipped from more than one warehouse AND
 returned (both IN-subqueries must hold), within a date window and

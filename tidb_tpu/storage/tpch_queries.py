@@ -1,7 +1,7 @@
 """The 22 TPC-H queries (validation parameter values from the spec's
 examples), in the dialect the front-end supports. Used by tests and by
-bench.py; sqlite-oracle variants differ only where date arithmetic /
-EXTRACT syntax diverges.
+chip_smoke.py; sqlite-oracle variants differ only where date
+arithmetic / EXTRACT syntax diverges.
 
 Each entry: name -> (engine_sql, sqlite_sql or None if identical).
 """

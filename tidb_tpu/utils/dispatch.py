@@ -44,9 +44,9 @@ def record(n: int = 1, site: str = "other") -> None:
         by = _tls.by_site = {}
     by[site] = by.get(site, 0) + n
     # process-wide mirror: /metrics exposes dispatch totals so external
-    # drivers (bench.py) read the engine's own figure instead of
-    # re-deriving it — the thread-local stays the per-query source for
-    # EXPLAIN ANALYZE deltas
+    # drivers (benchmarks/program_counters.py: dispatches_per_stmt) read
+    # the engine's own figure instead of re-deriving it — the
+    # thread-local stays the per-query source for EXPLAIN ANALYZE deltas
     from tidb_tpu.utils.metrics import DISPATCH_TOTAL
 
     DISPATCH_TOTAL.inc(n, site=site)

@@ -459,9 +459,9 @@ EXTERNAL_AGG = Counter("tidb_tpu_external_agg_total",
                        "state exceeded the memory budget)")
 
 # -- distributed-execution telemetry (fragments, DCN, memory) ---------------
-# The engine-reported side of what bench.py used to measure externally:
-# per-dispatch accounting, fragment wall time, DCN traffic, and
-# memory-quota events all render on /metrics.
+# Per-dispatch accounting, fragment wall time, DCN traffic, and
+# memory-quota events all render on /metrics; the benchmark's
+# program_counter metrics read their window deltas.
 
 DISPATCH_TOTAL = Counter(
     "tidb_tpu_device_dispatch_total",
@@ -507,8 +507,8 @@ JOIN_COMPILE_TOTAL = Counter(
 JOIN_PROBE_MODE_TOTAL = Counter(
     "tidb_tpu_join_probe_mode_total",
     "Probe chunks resolved per strategy, by mode: sorted (searchsorted "
-    "range lookup), xla / pallas (open-addressing hash table, window-"
-    "scan / VMEM kernel), direct (dense-domain direct-address index), "
+    "range lookup), xla (open-addressing hash table, window scan), "
+    "direct (dense-domain direct-address index), "
     "host (numpy tier), fused_* (same strategies inside a fused "
     "scan->probe program) — captures show which path actually ran")
 JOIN_PROBE_SECONDS = Histogram(
