@@ -177,11 +177,11 @@ SCOPES_OF = {4: SCOPES, 1: ["agg.partial"]}
 
 
 @pytest.fixture(scope="module", params=sorted(SCOPES_OF), ids="1x{}".format)
-def hlo_by_scope(request, devices8):
-    """(parts, the opcodes of the compiled general fragment of the
-    statement on a mesh of so many parts, by the scope in each op's
-    ``op_name``): one program per variant, without and with the
-    compaction and top-n stages."""
+def hlo_ops(request, devices8):
+    """(parts, per variant the ops of the compiled general fragment of
+    the statement on a mesh of so many parts, each as (the scope in its
+    ``op_name``, opcode, result type)): one program per variant, without
+    and with the compaction and top-n stages."""
     from tidb_tpu.parallel import executor as pe
 
     rng = np.random.default_rng(9)
@@ -205,14 +205,26 @@ def hlo_by_scope(request, devices8):
             pe.DistFragmentExec._dispatch_retry = real
         (prog, args, growths), = seen
         text = prog.build_fn(growths).lower(*args).compile().as_text()
-        by_scope = {}
+        ops = []
         for line in text.splitlines():
             name = re.search(r'op_name="jit\(frag_general\)/([^"]*)"', line)
-            op = re.search(r'= (?:\(.*?\)|\S+) ([a-z][\w-]*)\(', line)
+            op = re.search(r'= (\(.*?\)|\S+) ([a-z][\w-]*)\(', line)
             if name and op:
-                by_scope.setdefault(name.group(1), set()).add(op.group(1))
-        out[variant] = by_scope
+                ops.append((name.group(1), op.group(2), op.group(1)))
+        out[variant] = ops
     return request.param, out
+
+
+@pytest.fixture(scope="module")
+def hlo_by_scope(hlo_ops):
+    """(parts, per variant the opcodes by scope)."""
+    n_parts, ops_by_variant = hlo_ops
+    out = {}
+    for variant, ops in ops_by_variant.items():
+        by_scope = out.setdefault(variant, {})
+        for name, opcode, _ in ops:
+            by_scope.setdefault(name, set()).add(opcode)
+    return n_parts, out
 
 
 @pytest.mark.parametrize("scope", SCOPES)
@@ -261,6 +273,83 @@ def test_no_heavy_op_of_the_generic_aggregate_is_left_without_a_scope(hlo_by_sco
             f"/{s}/" in f"/{n}/" for s in every)))
         assert not bare & {"sort", "scatter", "gather", "all-to-all", "while",
                            "reduce", "dynamic-update-slice"}, (variant, sorted(bare))
+
+
+@pytest.mark.parametrize("scope", ["agg.partial", "agg.final"])
+def test_a_sort_reduce_sums_in_row_order_and_scatters_one_narrow_array(hlo_ops, scope):
+    """PR 31: the statement's payloads (a count and an integer sum) are
+    read off running totals at the run ends, so the one scatter left in
+    a sort-reduce is the run ends' row numbers, 32 bits wide, where the
+    parent scattered the key, its validity and every payload in 64 (five
+    scatters a sort-reduce); and the parts of `_sort_reduce` are scopes
+    of their own under whichever stage calls it."""
+    n_parts, ops_by_variant = hlo_ops
+    mine = [(name, opcode, type_) for name, opcode, type_ in ops_by_variant["plain"]
+            if f"/{scope}/" in f"/{name}/"]
+    if scope not in SCOPES_OF[n_parts]:
+        assert not mine
+        return
+    scatters = [(name, type_) for name, opcode, type_ in mine if opcode == "scatter"]
+    assert len(scatters) == 1, scatters
+    (name, type_), = scatters
+    assert f"{scope}/runs/" in name and type_.startswith("s32["), scatters
+    subs = {part for name, _, _ in mine
+            for part in name.split(f"{scope}/", 1)[1].split("/")[:1]}
+    assert {"sort", "gather", "runs", "reduce", "keys"} <= subs, sorted(subs)
+    # two gathers a sort-reduce, each of a stack of rows: the payloads
+    # after the sort's permutation, and everything a group keeps (its
+    # key, the running totals) from its run's last row
+    gathers = [name.split(f"{scope}/", 1)[1].split("/")[0]
+               for name, opcode, _ in mine if opcode == "gather"]
+    assert sorted(gathers) == ["gather", "reduce"], gathers
+    # one sort a sort-reduce, as before, and in its scope
+    assert [name.split(f"{scope}/", 1)[1].split("/")[0]
+            for name, opcode, _ in mine if opcode == "sort"] == ["sort"]
+
+
+@pytest.mark.parametrize("n_parts", [1, 4])
+def test_a_launch_counts_its_payloads_by_how_they_are_reduced(devices8, n_parts):
+    """FRAGMENT_REDUCE_PAYLOADS{kind, path}: Q18's inner aggregate (a
+    count and the two limbs of a decimal sum) adds 3 to `runs` and 0 to
+    `scatter` a launch on one part; a MAX beside it adds its own count
+    to `runs` and its extreme to `scatter`. Several parts reduce twice."""
+    from tidb_tpu.types import decimal_type
+    from tidb_tpu.utils.metrics import FRAGMENT_REDUCE_PAYLOADS
+
+    def by_path():
+        got = {"runs": 0.0, "scatter": 0.0}
+        for labels, v in FRAGMENT_REDUCE_PAYLOADS.samples():
+            if labels.get("kind") == "general_generic":
+                got[labels["path"]] += v
+        return got
+
+    rng = np.random.default_rng(31)
+    n = 3000
+    catalog = Catalog()
+    cols = [ColumnInfo("k", BIGINT, not_null=True), ColumnInfo("line", BIGINT, not_null=True),
+            ColumnInfo("q", decimal_type(15, 2)), ColumnInfo("w", BIGINT)]
+    table = catalog.create_table("test", TableSchema("t", cols, primary_key=["k", "line"]))
+    k = np.sort(rng.integers(0, 700, n))
+    q = rng.integers(1, 51, n) * 100  # quantity 1..50, scale 2
+    w = rng.integers(-9, 9, n)
+    table.ingest_encoded({"k": k, "line": np.arange(n, dtype=np.int64), "q": q, "w": w}, {})
+    s = session(catalog, devices8, n_parts)
+    twice = 1 if n_parts == 1 else 2
+    for sql, want, rows in (
+            ("select k, sum(q) from t group by k having sum(q) > 300 order by k",
+             {"runs": 3, "scatter": 0},
+             [(int(g), int(q[k == g].sum())) for g in np.unique(k)
+              if q[k == g].sum() > 30000]),
+            ("select k, sum(q), max(w) from t group by k order by k",
+             {"runs": 4, "scatter": 1},
+             [(int(g), int(q[k == g].sum()), int(w[k == g].max())) for g in np.unique(k)])):
+        l0, p0 = launches(), by_path()
+        got = s.query(sql)
+        assert launches() - l0 == 1
+        assert {p: v - p0[p] for p, v in by_path().items()} == {
+            p: v * twice for p, v in want.items()}
+        assert [(r[0],) + tuple(int(round(float(c) * 100)) if i == 0 else int(c)
+                                for i, c in enumerate(r[1:])) for r in got] == rows
 
 
 def test_a_served_statements_trace_names_the_finalize(devices8):

@@ -7,8 +7,17 @@ TPU redesign is SURVEY.md §7.4's sort-based grouping). Hash tables
 scatter poorly on TPU; `lax.sort` tiles well, so grouping is:
 
   per chunk:  multi-key sort (key bits + validity, dead rows last)
-              -> segment boundaries (adjacent inequality) -> segment ids
-              -> segment_sum / segment_min / segment_max partial states
+              -> run boundaries (adjacent inequality) -> run ids, and
+              the row at which each run ends
+              -> partial states: an integer sum (count, integer and
+              decimal sums, every limb) is one running total over the
+              sorted rows, read at each run's end and differenced — the
+              rows of a group are contiguous, so no addition needs an
+              address (PR 31: on the chip a 64-bit scatter-add of 6.0M
+              rows costs 535-743 ms, a blocked prefix sum and a gather
+              of the run ends a fraction of it; PERF.md section 6);
+              float sums and min / max stay segment_sum / segment_min /
+              segment_max
               -> a dense "group table": slot i < n holds group i's key
               values and mergeable agg states, all [capacity]-shaped.
 
@@ -62,6 +71,13 @@ def _bits64(data: jax.Array, valid: jax.Array) -> jax.Array:
     return jnp.where(valid, b, 0)
 
 
+def _from_bits64(bits: jax.Array, dtype) -> jax.Array:
+    """A key's data from its `_bits64` (NULL reads as the bits' 0)."""
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jax.lax.bitcast_convert_type(bits, jnp.float64).astype(dtype)
+    return bits.astype(dtype)
+
+
 def _group_hash(kbits: List[jax.Array], kvalids: List[jax.Array]) -> jax.Array:
     """One i64 ordering hash over all key components (validity folded in
     so a NULL key and a live 0 key land in different runs)."""
@@ -72,91 +88,177 @@ def _group_hash(kbits: List[jax.Array], kvalids: List[jax.Array]) -> jax.Array:
     return h
 
 
-def _sort_reduce(kbits: List[jax.Array], kvalids: List[jax.Array],
-                 kdatas: List[jax.Array], live: jax.Array,
-                 payload: List[jax.Array], reduce_ops: List[str],
-                 exact: bool = False):
-    """Shared core: sort rows by (dead, key identity), find segment
-    boundaries, reduce payload arrays into dense per-group slots.
+def _reduced_in_row_order(op: str, dtype) -> bool:
+    """Whether `_sort_reduce` reads this payload's group sums off one
+    running total: exact only for an integer sum (two's-complement
+    addition wraps, so the difference of two readings is the run's sum
+    modulo 2^64 — what an int64 scatter-add returns — whatever the total
+    over all rows does). A difference of running FLOAT totals is not the
+    group's sum, and a running extreme is its own scan: those keep
+    `segment_sum` / `segment_min` / `segment_max`."""
+    return op == "sum" and jnp.issubdtype(dtype, jnp.integer)
 
-    Only (dead, order-key, iota) go through the sorting network; key
-    values and payloads are gathered by the resulting permutation —
-    lax.sort carries every operand through its whole comparison network,
-    so this is ~(2+nk*3+npayload)/4 less data movement than sorting the
-    carried arrays directly. Single-key inputs order by the exact key
-    bits; multi-key inputs order by a mixed 64-bit hash with exact-key
-    boundary detection, so a hash collision can only SPLIT a group into
-    two partial slots (never merge two groups) — consumers dedup by
-    exact key at finalize (host _merge_partials), keeping results exact.
+
+def _sort_reduce(kdatas: List[jax.Array], kvalids: List[jax.Array],
+                 live: jax.Array, payload: List[jax.Array],
+                 reduce_ops: List[str], exact: bool = False,
+                 slots: int = None):
+    """Shared core: sort rows by (dead, key identity: `_bits64`), find
+    the runs of equal keys, reduce payload arrays into dense per-group
+    slots.
+
+    Single-key inputs order by the exact key bits; multi-key inputs
+    order by a mixed 64-bit hash with exact-key boundary detection, so a
+    hash collision can only SPLIT a group into two partial slots (never
+    merge two groups) — consumers dedup by exact key at finalize (host
+    _merge_partials), keeping results exact; `exact` adds the key bits
+    as tie-breaks, and no group is split.
+
+    After the sort a group's rows are CONTIGUOUS, and the reduction uses
+    that (PR 31). An integer sum is the difference of one running total
+    (`prefix.cumsum`) read at the end of the group's run and at the end
+    of the run before; the group's key is the key of that same row,
+    turned back from its bits into the key's type (`_from_bits64`: a
+    NULL key's data reads 0). The only scatter left is 32 bits wide: the
+    run-end rows' numbers into their groups' slots (`end_row`). Float
+    sums and min / max keep their segment ops (`_reduced_in_row_order`).
+
+    Only (dead, order-key, iota) go through the sorting network. What
+    follows the sort goes by two gathers, each of a STACK of int64 rows:
+    the summed payloads (and, ordered by the hash alone, the key bits)
+    by the sort's permutation; then the key bits, their validity and
+    the running totals by `end_row`, `slots` columns of them.
+
+    What the chip measured at 6.0M rows into 1.5M groups, 1.87M slots
+    (PERF.md section 6, PRs 26, 28 and 31): `segment_sum` as a 64-bit
+    scatter-add 543 ms a payload, whatever it is told of its indices,
+    and a 64-bit scatter of the keys 514; against a blocked prefix sum
+    11 ms, the 32-bit scatter 36, a gather of 1.87M int64 30. A gather
+    is paid by the index and hardly by what an index fetches: 6.0M
+    int64 by a permutation 86 ms, three such arrays in one program 331,
+    a [3, R] stack 65 — hence the stacks. Carrying the payloads through
+    the sort as operands instead costs the sort 37 ms more and its
+    COMPILE 160 s more (100 -> 260 s with three operands added; the
+    cell's cold set-up 143 -> 248 s): not taken.
+
+    `slots` (static; default: one per row) is how many group slots the
+    caller keeps: the gathers run over no more. Groups past it are
+    dropped, and `ngroups` still counts them (the caller's overflow
+    test). Slots at and past `ngroups` hold zero / False (min / max:
+    the identity).
 
     Returns (ngroups, rep_kdatas, rep_kvalids, reduced_payloads) — all
     slot arrays with groups dense in [0, ngroups)."""
     R = live.shape[0]
+    S = R if slots is None else min(int(slots), R)
+    nk = len(kdatas)
+    kbits = [_bits64(d, v) for d, v in zip(kdatas, kvalids)]
     dead = (~live).astype(jnp.int32)
     iota = jnp.arange(R, dtype=jnp.int32)
-    if len(kbits) == 1:
-        # exact: equal bits tie-break on validity (NULL run != live-0 run)
-        out = jax.lax.sort(
-            (dead, kbits[0], kvalids[0].astype(jnp.int32), iota), num_keys=3)
-    elif exact:
-        # hash first (cheap comparisons), exact bits as tie-breaks: equal
-        # keys are guaranteed contiguous, so the output table can never
-        # hold a collision-split duplicate — consumers may emit it
-        # directly without a dedup pass. kvalids must join the tie-break:
-        # _bits64 zeroes NULL bits, so a NULL key and a live 0 share bits
-        # and differ only in validity — without it a hash collision could
-        # interleave the two groups
-        keys = ((dead, _group_hash(kbits, kvalids)) + tuple(kbits)
-                + tuple(v.astype(jnp.int32) for v in kvalids) + (iota,))
-        out = jax.lax.sort(keys, num_keys=len(keys) - 1)
-    else:
-        out = jax.lax.sort(
-            (dead, _group_hash(kbits, kvalids), iota), num_keys=2)
-    perm = out[-1]
+    with jax.named_scope("sort"):
+        if nk == 1:
+            # exact: equal bits tie-break on validity (NULL run != live-0 run)
+            out = jax.lax.sort(
+                (dead, kbits[0], kvalids[0].astype(jnp.int32), iota), num_keys=3)
+            s_kbits, s_kvalids = [out[1]], [out[2] != 0]
+        elif exact:
+            # hash first (cheap comparisons), exact bits as tie-breaks: equal
+            # keys are guaranteed contiguous, so the output table can never
+            # hold a collision-split duplicate — consumers may emit it
+            # directly without a dedup pass. kvalids must join the tie-break:
+            # _bits64 zeroes NULL bits, so a NULL key and a live 0 share bits
+            # and differ only in validity — without it a hash collision could
+            # interleave the two groups
+            keys = ((dead, _group_hash(kbits, kvalids)) + tuple(kbits)
+                    + tuple(v.astype(jnp.int32) for v in kvalids) + (iota,))
+            out = jax.lax.sort(keys, num_keys=len(keys) - 1)
+            s_kbits = list(out[2:2 + nk])
+            s_kvalids = [v != 0 for v in out[2 + nk:2 + 2 * nk]]
+        else:
+            out = jax.lax.sort(
+                (dead, _group_hash(kbits, kvalids), iota), num_keys=2)
+            s_kbits = s_kvalids = None
+        perm = out[-1]
 
-    def take(a):
-        return jnp.take(a, perm, axis=0)
+    in_order = [_reduced_in_row_order(op, p.dtype)
+                for p, op in zip(payload, reduce_ops)]
+    with jax.named_scope("gather"):
+        # what follows the sort goes by ONE gather of a stack of rows
+        rows = [] if s_kbits is not None else list(kbits)
+        rows += [p.astype(jnp.int64) for p, c in zip(payload, in_order) if c]
+        # (no row: a GROUP BY without aggregates, ordered by its bits)
+        moved = (jnp.take(jnp.stack(rows), perm, axis=1) if rows
+                 else jnp.zeros((0, R), dtype=jnp.int64))
+        if s_kbits is None:  # ordered by the hash alone: the bits follow
+            s_kbits = list(moved[:nk])
+            s_kvalids = [jnp.take(v, perm, axis=0) for v in kvalids]
+        s_sums = moved[len(rows) - sum(in_order):]
 
-    s_kbits = [take(b) for b in kbits]
-    s_kdatas = [take(d) for d in kdatas]
-    s_kvalids = [take(v) for v in kvalids]
-    s_payload = [take(p) for p in payload]
-    s_live = take(live)
+    with jax.named_scope("runs"):
+        # live rows are the prefix [0, L) (dead sorts last); a run starts
+        # at row 0 or where any exact key component differs from the row
+        # before, and ends where the next begins or the live rows end
+        L = jnp.sum(live.astype(jnp.int32))
+        s_live = iota < L
+        diff = jnp.zeros(R, dtype=jnp.bool_)
+        for b, v in zip(s_kbits, s_kvalids):
+            diff = diff | (b != jnp.roll(b, 1)) | (v != jnp.roll(v, 1))
+        newseg = s_live & ((iota == 0) | diff)
+        runend = s_live & (jnp.roll(newseg, -1) | (iota == L - 1))
+        seg = prefix.cumsum(newseg.astype(jnp.int32)) - 1
+        ngroups = (seg[-1] + 1).astype(jnp.int64)
+        # the row at which each group's run ends, dense by group: the
+        # one scatter, 32 bits wide. Every other row (and a run past the
+        # table's last slot) drops out of bounds, so the targets neither
+        # ascend nor are unique, and the scatter is told neither: on the
+        # chip neither hint bought a millisecond (36 ms at 6.0M rows into
+        # 1.87M slots), and `indices_are_sorted` gave wrong rows
+        end_row = jnp.zeros(S, dtype=jnp.int32).at[
+            jnp.where(runend, seg, S)].set(iota, mode="drop")
+        occupied = jnp.arange(S, dtype=jnp.int32) < seg[-1] + 1
 
-    # live rows are a prefix (dead sorts last); a new segment starts at
-    # row 0 or where any exact key component differs from the previous row
-    idx = jnp.arange(R)
-    diff = jnp.zeros(R, dtype=jnp.bool_)
-    for b, v in zip(s_kbits, s_kvalids):
-        diff = diff | (b != jnp.roll(b, 1)) | (v != jnp.roll(v, 1))
-    newseg = s_live & ((idx == 0) | diff)
-    seg = jnp.clip(prefix.cumsum(newseg.astype(jnp.int64)) - 1, 0, R - 1)
-    ngroups = jnp.sum(newseg.astype(jnp.int64))
+    with jax.named_scope("reduce"):
+        # one running total a payload; at a run's end it holds no dead
+        # row (they all lie after the last run). Every row of a run holds
+        # the same key, so the key is read at the run's end too: again
+        # one gather for all of it
+        totals = jax.vmap(prefix.cumsum)(s_sums) if any(in_order) else s_sums
+        ends = jnp.take(
+            jnp.concatenate([jnp.stack(
+                s_kbits + [v.astype(jnp.int64) for v in s_kvalids]), totals]),
+            end_row, axis=1, mode="clip")
+        tot = ends[2 * nk:]
+        before = jnp.concatenate(
+            [jnp.zeros((tot.shape[0], 1), tot.dtype), tot[:, :-1]], axis=1)
+        sums = iter(jnp.where(occupied, tot - before, 0))
 
-    # representative key values per group, scattered from boundary rows
-    # only — dead rows share the last group's clipped seg id, and letting
-    # them race the scatter would clobber that group's key with zeros
-    tgt = jnp.where(newseg, seg, R)  # non-boundary rows drop out of bounds
-    rep_kdatas = [jnp.zeros(R, dtype=d.dtype).at[tgt].set(d, mode="drop")
-                  for d in s_kdatas]
-    rep_kvalids = [jnp.zeros(R, dtype=jnp.bool_).at[tgt].set(v, mode="drop")
-                   for v in s_kvalids]
+        # the others follow the sort on their own and keep their segment
+        # ops; dead rows share the last run's id and offer the identity
+        seg_all = jnp.maximum(seg, 0)
+        reduced = []
+        for arr, op, summed in zip(payload, reduce_ops, in_order):
+            if summed:
+                reduced.append(next(sums).astype(arr.dtype))
+                continue
+            arr = jnp.take(arr, perm, axis=0)
+            if op == "sum":
+                contrib = jnp.where(s_live, arr, jnp.zeros((), dtype=arr.dtype))
+                reduced.append(jax.ops.segment_sum(contrib, seg_all, num_segments=S))
+            elif op == "min":
+                reduced.append(jax.ops.segment_min(
+                    jnp.where(s_live, arr, jnp.full((), _ident_min(arr.dtype), arr.dtype)),
+                    seg_all, num_segments=S))
+            elif op == "max":
+                reduced.append(jax.ops.segment_max(
+                    jnp.where(s_live, arr, jnp.full((), _ident_max(arr.dtype), arr.dtype)),
+                    seg_all, num_segments=S))
+            else:  # pragma: no cover
+                raise ValueError(op)
 
-    reduced = []
-    for arr, op in zip(s_payload, reduce_ops):
-        if op == "sum":
-            contrib = jnp.where(s_live, arr, jnp.zeros((), dtype=arr.dtype))
-            reduced.append(jax.ops.segment_sum(contrib, seg, num_segments=R))
-        elif op == "min":
-            reduced.append(jax.ops.segment_min(
-                jnp.where(s_live, arr, jnp.full((), _ident_min(arr.dtype), arr.dtype)),
-                seg, num_segments=R))
-        elif op == "max":
-            reduced.append(jax.ops.segment_max(
-                jnp.where(s_live, arr, jnp.full((), _ident_max(arr.dtype), arr.dtype)),
-                seg, num_segments=R))
-        else:  # pragma: no cover
-            raise ValueError(op)
+    with jax.named_scope("keys"):
+        rep_kdatas = [_from_bits64(jnp.where(occupied, b, 0), d.dtype)
+                      for b, d in zip(ends[:nk], kdatas)]
+        rep_kvalids = [occupied & (v != 0) for v in ends[nk:2 * nk]]
     return ngroups, rep_kdatas, rep_kvalids, reduced
 
 
@@ -190,9 +292,29 @@ def _state_layout(aggs: List[AggSpec]) -> List[Tuple[str, str]]:
     return layout
 
 
+def _sum_dtype(a: AggSpec):
+    """The accumulator of a SUM / AVG state: float64 for a FLOAT
+    argument, else int64 (integers, and decimals as scaled integers)."""
+    return jnp.float64 if a.arg.type_.kind == TypeKind.FLOAT else jnp.int64
+
+
+def reduce_paths(aggs: List[AggSpec]) -> List[str]:
+    """Per state array of `_state_layout(aggs)`, how `_sort_reduce`
+    reduces it: "runs" (a running total read at the run ends) or
+    "scatter" (a segment op). What FRAGMENT_REDUCE_PAYLOADS counts."""
+    paths = []
+    for name, op in _state_layout(aggs):
+        j, state = name[1:].split(".")
+        dtype = _sum_dtype(aggs[int(j)]) if state == "sum" else jnp.int64
+        paths.append("runs" if _reduced_in_row_order(op, dtype) else "scatter")
+    return paths
+
+
 def make_partial_kernel(group_exprs, aggs: List[AggSpec],
                         exact: bool = False):
-    """fn(chunk) -> group table dict {"n", "k{i}.d", "k{i}.v", state...}.
+    """fn(chunk, slots=None) -> group table dict {"n", "k{i}.d",
+    "k{i}.v", state...} of `slots` slots (static; default: the chunk's
+    capacity; see _sort_reduce).
 
     `exact` (see _sort_reduce): the table holds every group once, also
     where several keys' mixed hashes collide — for a consumer that emits
@@ -200,15 +322,13 @@ def make_partial_kernel(group_exprs, aggs: List[AggSpec],
     host executor merges its tables by exact key and leaves it off."""
     layout = _state_layout(aggs)
 
-    def partial(chunk: Chunk):
-        R = chunk.capacity
+    def partial(chunk: Chunk, slots: int = None):
         sel = chunk.sel
-        kdatas, kvalids, kbits = [], [], []
+        kdatas, kvalids = [], []
         for g in group_exprs:
             d, v = eval_expr(g, chunk)
             kdatas.append(d)
             kvalids.append(v)
-            kbits.append(_bits64(d, v))
 
         payload, ops = [], []
         for j, a in enumerate(aggs):
@@ -225,8 +345,7 @@ def make_partial_kernel(group_exprs, aggs: List[AggSpec],
                     split_limbs,
                 )
 
-                dt = jnp.float64 if a.arg.type_.kind == TypeKind.FLOAT else jnp.int64
-                contrib = jnp.where(ok, d, 0).astype(dt)
+                contrib = jnp.where(ok, d, 0).astype(_sum_dtype(a))
                 if needs_sum_limbs(a):
                     clo, chi = split_limbs(contrib)
                     payload.append(clo)
@@ -245,8 +364,8 @@ def make_partial_kernel(group_exprs, aggs: List[AggSpec],
                 payload.append(jnp.where(ok, d, _ident_max(dt)).astype(dt))
                 ops.append("max")
 
-        n, rk, rkv, red = _sort_reduce(kbits, kvalids, kdatas, sel, payload,
-                                       ops, exact=exact)
+        n, rk, rkv, red = _sort_reduce(kdatas, kvalids, sel, payload, ops,
+                                       exact=exact, slots=slots)
         table = {"n": n}
         for i in range(len(group_exprs)):
             table[f"k{i}.d"] = rk[i]
@@ -271,10 +390,9 @@ def make_merge_kernel(nkeys: int, aggs: List[AggSpec]):
         live = jnp.concatenate([la, lb])
         kdatas = [cat(f"k{i}.d") for i in range(nkeys)]
         kvalids = [cat(f"k{i}.v") for i in range(nkeys)]
-        kbits = [_bits64(d, v) for d, v in zip(kdatas, kvalids)]
         payload = [cat(name) for name, _ in layout]
         ops = [op for _, op in layout]
-        n, rk, rkv, red = _sort_reduce(kbits, kvalids, kdatas, live, payload, ops)
+        n, rk, rkv, red = _sort_reduce(kdatas, kvalids, live, payload, ops)
         table = {"n": n}
         for i in range(nkeys):
             table[f"k{i}.d"] = rk[i]

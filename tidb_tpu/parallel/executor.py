@@ -57,7 +57,8 @@ __all__ = ["ShardCache", "build_dist_executor", "DistAggExec", "DistJoinAggExec"
 
 
 @contextlib.contextmanager
-def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0):
+def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
+                     reduced: Tuple[int, int] = (0, 0)):
     """One fragment launch: the span ``fragment.<kind>[parts=N]`` on the
     statement's trace and the FRAGMENT_SECONDS collector for /metrics
     (with a trace_id exemplar). Wall time covers the launch plus any
@@ -66,11 +67,14 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0):
     (utils/dispatch.py). One launch is one fragment execution, so the
     dispatch counter lives here too — the count and the histogram can
     never desynchronize — and beside it the `exchanges` the launched
-    program holds (FRAGMENT_EXCHANGE_STEPS; 0 on a mesh of one part)."""
+    program holds (FRAGMENT_EXCHANGE_STEPS; 0 on a mesh of one part) and
+    the payloads its sort-reduces take, `reduced` = (summed in row order,
+    by a segment op) (FRAGMENT_REDUCE_PAYLOADS; a generic aggregate's)."""
     from tidb_tpu.utils import tracing
     from tidb_tpu.utils.metrics import (
         FRAGMENT_DISPATCH,
         FRAGMENT_EXCHANGE_STEPS,
+        FRAGMENT_REDUCE_PAYLOADS,
         FRAGMENT_SECONDS,
     )
 
@@ -79,6 +83,8 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0):
         yield
     FRAGMENT_DISPATCH.inc(kind=kind)
     FRAGMENT_EXCHANGE_STEPS.inc(exchanges, kind=kind)
+    for path, n in zip(("runs", "scatter"), reduced):
+        FRAGMENT_REDUCE_PAYLOADS.inc(n, kind=kind, path=path)
     FRAGMENT_SECONDS.observe(time.perf_counter() - t0, kind=kind)
 
 
@@ -609,7 +615,8 @@ class DistFragmentExec(HashAggExec):
                    probe_mode)
             fn = self._cache.get_fragment(
                 key, lambda: prog.build_fn(growths, probe_mode=probe_mode))
-            with _fragment_launch(kind, n_parts, prog.n_exchange):
+            with _fragment_launch(kind, n_parts, prog.n_exchange,
+                                  prog.n_reduce):
                 out, ovf = fn(*args)
             # host-sync: the per-knob overflow vector (a few int64s)
             # gates the capacity-retry loop — one fetch per dispatch

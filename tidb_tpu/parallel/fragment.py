@@ -31,7 +31,9 @@ On a mesh of ONE part nothing is exchanged (`n_parts` is a static int
 when the fragment is compiled): a join's sides go to the local join as
 they are, the generic aggregate's partial table — reduced exactly — is
 its final table, and neither adds an "exch" knob. FragmentProgram's
-`n_exchange` says how many repartitions a program holds.
+`n_exchange` says how many repartitions a program holds, `n_reduce` how
+many payloads its sort-reduces sum in row order and how many by a
+segment op (agg_device._sort_reduce).
 """
 
 from __future__ import annotations
@@ -51,6 +53,7 @@ from tidb_tpu.executor.agg_device import (
     _sort_reduce,
     _state_layout,
     make_partial_kernel,
+    reduce_paths,
 )
 from tidb_tpu.executor.aggregate import make_segment_kernel
 from tidb_tpu.executor.builder import peel_stages, scan_stages_for
@@ -164,6 +167,9 @@ class FragmentProgram:
     broadcasts: List[_Broadcast]
     n_growth: int                      # number of growth knobs
     n_exchange: int                    # repartitions compiled in (0 on one part)
+    # payloads of the generic aggregate's sort-reduces: ("runs" summed in
+    # row order, "scatter" by a segment op) — FRAGMENT_REDUCE_PAYLOADS
+    n_reduce: Tuple[int, int]
     sig: str
     build_fn: Callable                 # (growths tuple) -> per-shard program
     out_kind: str                      # "segment" | "generic"
@@ -202,6 +208,9 @@ class _Compiler:
         self.stream_unsafe: set = set()
         # repartitions compiled into the program (0 on one part)
         self.n_exchange = 0
+        # how each payload of the program's sort-reduces is reduced
+        # (agg_device.reduce_paths: "runs" | "scatter")
+        self.reduce_paths: List[str] = []
 
     def _add_growth(self, default: float, kind: str) -> int:
         idx = self.n_growth
@@ -286,8 +295,6 @@ class _Compiler:
         DECIMAL sums recombine their two limbs on device (hi*2^32+lo):
         exact while a per-shard per-group partial stays inside int64 —
         the same representability bound as the final DECIMAL result."""
-        from tidb_tpu.executor.agg_device import make_partial_kernel
-
         if not self._partial_agg_ok(agg):
             return None
         child_emit = self.producer(agg.child)
@@ -295,6 +302,7 @@ class _Compiler:
         types = {c.uid: c.type_ for c in agg.schema}
         self.sig.append(
             f"eagg:{agg.group_exprs!r}:{agg.aggs!r}:{agg.group_uids!r}")
+        self.reduce_paths += reduce_paths(agg.aggs)
 
         def emit(env, growths):
             chunk, ovfs = child_emit(env, growths)
@@ -748,6 +756,8 @@ class _Compiler:
         else:
             g_agg = self._add_growth(2.0, "exch")
             self.n_exchange += 1
+        # one sort-reduce on one part, a second after the exchange
+        self.reduce_paths += reduce_paths(agg.aggs) * (1 if one_part else 2)
         # estimate-sized shrink targets (see _compact): the partial sort
         # pays for input capacity and the exchange pays for table slots
         g_in, in_base = self._compact_knob(agg.child.est_rows)
@@ -784,14 +794,13 @@ class _Compiler:
             with jax.named_scope("agg.final"):
                 rkd = [recv[f"k{i}.d"] for i in range(nk)]
                 rkv = [recv[f"k{i}.v"] for i in range(nk)]
-                rbits = [_key_bits(d, v) for d, v in zip(rkd, rkv)]
                 payload = [recv[name] for name, _ in layout]
                 ops = [op for _, op in layout]
                 # exact mode: the emitted tables are duplicate-free, so
                 # the host finalize is a straight per-part conversion —
                 # no merge
-                n, fk, fkv, red = _sort_reduce(rbits, rkv, rkd, recv_sel,
-                                               payload, ops, exact=True)
+                n, fk, fkv, red = _sort_reduce(rkd, rkv, recv_sel, payload,
+                                               ops, exact=True)
                 return n, fk, fkv, _normalize_red_limbs(red, layout, agg.aggs)
 
         def emit(env, growths):
@@ -802,17 +811,16 @@ class _Compiler:
                     chunk, o = _compact_chunk(chunk, capI)
                     ovfs.append((g_in, pmax(o, _AXES)))
             with jax.named_scope("agg.partial"):
-                table = partial(chunk)  # local dedup before the exchange
                 capT = int(np.ceil(growths[g_tab] * tab_base))
-                if capT < table["k0.d"].shape[0]:
-                    # groups are dense in [0, n): slicing the slot arrays
-                    # is free and shrinks everything the exchange must
-                    # carry (on one part: everything the host fetches)
+                # local dedup before the exchange. Groups are dense in
+                # [0, n): the table keeps `capT` slots, which shrinks
+                # what the reduction gathers and everything the exchange
+                # must carry (on one part: everything the host fetches)
+                table = partial(chunk, slots=capT)
+                if capT < chunk.capacity:
                     factor = (table["n"] + capT - 1) // capT
                     ovfs.append(
                         (g_tab, pmax(jnp.maximum(factor - 1, 0), _AXES)))
-                    table = {k: (v if k == "n" else v[:capT])
-                             for k, v in table.items()}
                 if one_part:
                     n = table["n"]
                     fk = [table[f"k{i}.d"] for i in range(nk)]
@@ -907,7 +915,10 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
 
     return FragmentProgram(
         agg=agg, sources=c.sources, broadcasts=c.broadcasts,
-        n_growth=c.n_growth, n_exchange=c.n_exchange, sig="|".join(c.sig),
+        n_growth=c.n_growth, n_exchange=c.n_exchange,
+        n_reduce=(c.reduce_paths.count("runs"),
+                  c.reduce_paths.count("scatter")),
+        sig="|".join(c.sig),
         build_fn=build_fn,
         out_kind=out_kind, domains=domains,
         growth_defaults=tuple(c.growth_defaults),

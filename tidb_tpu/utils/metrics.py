@@ -448,6 +448,15 @@ FRAGMENT_EXCHANGE_STEPS = Counter(
     "its program's count (the mesh join 2, a generic aggregate 1, each "
     "repartitioned join of a general fragment 2; on a mesh of one part 0, "
     "where nothing is exchanged)")
+FRAGMENT_REDUCE_PAYLOADS = Counter(
+    "tidb_tpu_fragment_reduce_payloads_total",
+    "Payload arrays (counts, sums, limbs, extremes) that the sort-reduces "
+    "of the fragment programs launched take, by fragment kind and by how "
+    "the program reduces them: path=runs, an integer sum read off one "
+    "running total at the ends of the sorted runs; path=scatter, a float "
+    "sum, a min or a max by a segment op. A launch adds its program's "
+    "counts (a generic aggregate's states, twice on a mesh of several "
+    "parts: before and after the exchange)")
 FRAGMENT_RETRY_TOTAL = Counter(
     "tidb_tpu_fragment_retry_total",
     "Fragment launches thrown away because a capacity knob overflowed "
