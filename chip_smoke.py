@@ -15,12 +15,14 @@ chips the partial groups repartitioned over all_to_all and reduced
 again, on one chip the partial table is the final one; one program and
 one launch cold,
 its group table sized from the key's distinct count as the bulk load
-sketched it), a transaction (insert, aggregate,
+sketched it), TPC-H Q3 whole (customer, orders and lineitem joined, a
+GROUP BY over the joined rows and a top 10) as ONE general fragment
+launched once (its filters are estimated from what the bulk load
+recorded of orders and lineitem, so no compaction buffer overflows), a
+transaction (insert, aggregate,
 ORDER BY LIMIT) on the fused segment-store tier with read-back on the
-other connection, and a point get. The whole of TPC-H Q3 and Q18 is NOT
-covered: the general fragments their joins compile to (20+ whole-table
-sorts and scans in one program) do not finish their first execution
-inside the smoke's time limit on a v5e (ROADMAP S3;
+other connection, and a point get. The whole of TPC-H Q18 is NOT
+covered: its two general fragments hold 6 sorts on one chip (ROADMAP S3;
 tests/test_chip_compile.py pins it).
 
 One process. The server is booted through ``tidb_tpu.__main__.boot`` with
@@ -76,7 +78,7 @@ class Ref:
 
     def __init__(self, catalog, db="test"):
         self.t = {n: catalog.table(db, n)
-                  for n in ("lineitem", "orders")}
+                  for n in ("lineitem", "orders", "customer")}
         for name, tab in self.t.items():
             check(bool(tab.live_mask(0, tab.n).all()),
                   f"{name}: freshly loaded rows must all be live")
@@ -150,6 +152,28 @@ class Ref:
         big = np.flatnonzero(sumq > 300 * 100)
         return [(int(k), int(sumq[k]) / 1e2) for k in big]
 
+    def q3(self, segment="BUILDING", date="1995-03-15"):
+        day = self.days(date)
+        code = self.t["customer"].dicts["c_mktsegment"].values.index(segment)
+        ck = self.col("customer", "c_custkey")
+        in_segment = np.zeros(int(ck.max()) + 1, dtype=np.bool_)
+        in_segment[ck[self.col("customer", "c_mktsegment") == code]] = True
+        okey, odate, prio = (self.col("orders", c) for c in (
+            "o_orderkey", "o_orderdate", "o_shippriority"))
+        o_ok = (odate < day) & in_segment[self.col("orders", "o_custkey")]
+        li = self._order_index()[self.col("lineitem", "l_orderkey")]
+        m = ((li >= 0) & o_ok[np.maximum(li, 0)]
+             & (self.col("lineitem", "l_shipdate") > day))
+        revenue = np.zeros(len(o_ok), dtype=np.int64)
+        np.add.at(revenue, li[m], self.col("lineitem", "l_extendedprice")[m]
+                  * (100 - self.col("lineitem", "l_discount")[m]))
+        rows = np.unique(li[m])  # the orders with a group
+        top = rows[np.lexsort((okey[rows], odate[rows], -revenue[rows]))[:10]]
+        epoch = datetime.date(1970, 1, 1)
+        return [(int(okey[r]), int(revenue[r]) / 1e4,
+                 str(epoch + datetime.timedelta(days=int(odate[r]))),
+                 int(prio[r])) for r in top]
+
     def top_prices(self, k):
         ext = self.col("lineitem", "l_extendedprice")
         return sorted((int(v) for v in
@@ -171,18 +195,33 @@ Q6_SHAPED = ("select sum(l_extendedprice * l_discount) as revenue "
 Q18_INNER_SQL = ("select l_orderkey, sum(l_quantity) as q from lineitem "
                  "group by l_orderkey having sum(l_quantity) > 300 "
                  "order by l_orderkey")
+# TPC-H Q3 whole (clause 2.4.3's validation parameters), l_orderkey
+# appended to the ORDER BY so that a tie at the cut has one answer: two
+# joins, a generic aggregate over the joined rows and the host's top 10,
+# ONE general fragment
+Q3_SQL = ("select l_orderkey, sum(l_extendedprice * (1 - l_discount)) as revenue, "
+          "o_orderdate, o_shippriority from customer, orders, lineitem "
+          "where c_mktsegment = 'BUILDING' and c_custkey = o_custkey "
+          "and l_orderkey = o_orderkey and o_orderdate < date '1995-03-15' "
+          "and l_shipdate > date '1995-03-15' "
+          "group by l_orderkey, o_orderdate, o_shippriority "
+          "order by revenue desc, o_orderdate, l_orderkey limit 10")
 TOPN_SQL = ("select l_extendedprice, l_orderkey from lineitem "
             "order by l_extendedprice desc limit 10")
+GENERAL = ("q18_inner", "q3")  # the statements the general fragment compiler takes
 
 
-def check_one_launch(cold: dict) -> None:
-    """The group table is sized from the key's distinct count, which the
-    bulk load sketched (Table._seed_key_sketches): the cold statement
-    compiles its general fragment once and launches it once. A second
-    launch is the overflow retry: the estimate fell short of the data."""
+def check_one_launch(name: str, cold: dict) -> None:
+    """Every capacity of a general fragment is sized from the data: the
+    group table from the key's distinct count, which the bulk load
+    sketched (Table._seed_key_sketches), a filtered scan's compaction
+    from the bounds the load recorded (statistics.record_load_stats).
+    The cold statement compiles its fragment once and launches it once.
+    A second launch is the overflow retry: an estimate fell short of the
+    data, and the first launch's compile and device work were thrown away."""
     launches = {k: v for k, v in cold.items() if k.startswith("fragment:")}
     check(launches == {"fragment:general_generic": 1},
-          "Q18's inner aggregate launched its fragment more than once cold "
+          f"{name} launched its fragment more than once cold "
           f"(a capacity knob overflowed): {launches}")
 
 
@@ -393,7 +432,7 @@ def _drive(args, server, boot_s) -> dict:
     t0 = time.perf_counter()
     ref = Ref(server.catalog)
     expected = {"q6": ref.q6(), "q1": ref.q1(), "join": ref.join(),
-                "q18_inner": ref.q18_inner()}
+                "q18_inner": ref.q18_inner(), "q3": ref.q3()}
     base_top = ref.top_prices(10)
     emit(phase="reference", seconds=round(time.perf_counter() - t0, 1),
          q6=expected["q6"], join=expected["join"])
@@ -406,7 +445,7 @@ def _drive(args, server, boot_s) -> dict:
         for c in (a, b):
             c.query("set tidb_device_engine_mode = 'force'")
     stmts = [("q6", Q["q6"][0]), ("q1", Q["q1"][0]), ("join", JOIN_SQL),
-             ("q18_inner", Q18_INNER_SQL)]
+             ("q18_inner", Q18_INNER_SQL), ("q3", Q3_SQL)]
     classic = {}
 
     # 1-3: the analytic statements, mesh tier, connection A cold then warm
@@ -423,11 +462,11 @@ def _drive(args, server, boot_s) -> dict:
              cold_s=round(cold, 3), warm_s=round(warm, 3),
              cold=obs.delta(c0, c1), warm=obs.delta(c1, c2), placement=pd)
         check_placement(want, name, pd, need=("fragment",))
-        if name == "q18_inner":
+        if name in GENERAL:
             check("fragment:general_generic" in obs.delta(c1, c2),
-                  "Q18's inner aggregate did not run as a general fragment "
+                  f"{name} did not run as a general fragment "
                   f"(parallel/fragment.py): {obs.delta(c1, c2)}")
-            check_one_launch(obs.delta(c0, c1))
+            check_one_launch(name, obs.delta(c0, c1))
         if name == "q6":
             emit(phase="hbm", after="connection A first analytic statement",
                  hbm=hbm())
@@ -571,7 +610,7 @@ def _drive_mesh4(args, server, boot_s) -> dict:
         if name == "q18_inner":
             check("fragment:general_generic" in warm_d,
                   f"Q18's inner aggregate ran no general fragment: {warm_d}")
-            check_one_launch(obs.delta(c0, c1))
+            check_one_launch(name, obs.delta(c0, c1))
 
     # placement: a quarter of every column on each device, HBM balanced
     (sess,) = server.sessions.values()

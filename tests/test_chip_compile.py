@@ -573,10 +573,13 @@ def test_general_fragment_fits_a_cold_statement(topo, tpu_target, tiny_tpch, q,
                                                 n_dev):
     """Strict: when S3 brings Q3's / Q18's programs under the budget,
     their cases turn green and they belong in chip_smoke.py's list.
-    Q3 on ONE chip is under it since PR 29 (3 sorts: a mesh of one part
-    exchanges nothing, so the argsort of every repartition is gone);
-    whether its first execution now fits the smoke has not been asked of
-    the chip (ROADMAP S3's next step)."""
+    Q3 on ONE chip is under it since PR 29 (a mesh of one part exchanges
+    nothing, so the argsort of every repartition is gone) and AT it since
+    PR 32: with its filters estimated from the bulk load's record the
+    planner pre-aggregates lineitem under the joins, a fourth sort. The
+    chip was asked (PR 32, PERF.md section 6): a cold Q3 answers in
+    395-418 s, its program compiling in 383-405 (the parent's three
+    sorts: 702 s, twice); it is in the smoke's list."""
     from chip_smoke import Q18_INNER_SQL
     from tidb_tpu.storage.tpch_queries import Q
 
@@ -635,10 +638,12 @@ def test_general_fragment_compiles_at_sf1(topo, tpu_target, sf1_tpch, stmt,
     inner aggregate, one chip and four: accepted, 515 s and 276 s in the
     sandbox with PR 22's capacities; since PR 28 the cold statement's
     only program, its group table 1.25 x the sketched 1.49M order keys),
-    and the whole of Q3 and Q18, which the smoke leaves out:
-    the two together were still compiling after 90 minutes in the
-    sandbox (PR 22) — whether the compiler accepts them is not known
-    yet; ROADMAP S3 starts here. Not tier-1: run it with -m slow."""
+    and the whole of Q3 and Q18: the two together were still compiling
+    after 90 minutes in the sandbox (PR 22). Q3's program as PR 32
+    leaves it (benchmark data, SF1 shapes) compiles here in 626 s, on
+    the chip in 383-405 (PERF.md section 6); whether the compiler
+    accepts Q18's is not known yet: ROADMAP S3. Not tier-1: run it with
+    -m slow."""
     from chip_smoke import Q18_INNER_SQL
     from tidb_tpu.storage.tpch_queries import Q
 

@@ -52,15 +52,24 @@ def test_range_selectivity(tpch):
     t = tpch.catalog.table("test", "lineitem")
     # build the scan IR through the planner for a real predicate
     phys = tpch._plan_select(parse(
-        "select count(*) from lineitem where l_quantity < 1000")[0])
-    # l_quantity is uniform over 100..5000 (scale-2 ints 1..50): < 1000
-    # (i.e. qty < 10) should select ~18%
+        "select count(*) from lineitem where l_quantity < 10")[0])
+    # l_quantity is uniform over 1..50, held as scale-2 ints 100..5000;
+    # the IR carries the INT literal 10 and rescales it when the
+    # comparison is evaluated, so the estimate has to rescale it too
+    # (until PR 32 it read the literal against the scaled values: this
+    # test asked for `< 1000`, which every row passes, and wanted 18%)
     scan = phys
     while not isinstance(scan, PScan):
         scan = scan.children[0]
     uid_to_col = {c.uid: c.name for c in scan.schema}
     sel = scan_selectivity(t, scan.pushed_cond, uid_to_col)
     assert 0.1 < sel < 0.3
+    everything = tpch._plan_select(parse(
+        "select count(*) from lineitem where l_quantity < 1000")[0])
+    while not isinstance(everything, PScan):
+        everything = everything.children[0]
+    assert scan_selectivity(t, everything.pushed_cond, {
+        c.uid: c.name for c in everything.schema}) == 1.0
 
 
 def _join_order(phys):

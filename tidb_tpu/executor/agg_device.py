@@ -39,6 +39,7 @@ NaN payloads are distinct groups, matching np.unique on bits).
 
 from __future__ import annotations
 
+import functools
 import warnings
 from typing import Dict, List, Tuple
 
@@ -168,12 +169,24 @@ def _sort_reduce(kdatas: List[jax.Array], kvalids: List[jax.Array],
             # directly without a dedup pass. kvalids must join the tie-break:
             # _bits64 zeroes NULL bits, so a NULL key and a live 0 share bits
             # and differ only in validity — without it a hash collision could
-            # interleave the two groups
+            # interleave the two groups. The validity bits go as ONE packed
+            # operand (first key's bit highest: the order of the tuple), and
+            # the row number is the last KEY, a total order, so the sort
+            # need not be stable: the same permutation, and a program the
+            # chip's compiler takes a fraction of the time over (PERF.md
+            # section 6, PR 32: a stable sort's time grows with every
+            # operand it carries, 64-bit ones most)
+            if nk > 31:
+                raise ValueError(f"{nk} group keys")
+            vpack = functools.reduce(
+                lambda acc, v: acc * 2 + v.astype(jnp.int32), kvalids,
+                jnp.zeros(R, dtype=jnp.int32))
             keys = ((dead, _group_hash(kbits, kvalids)) + tuple(kbits)
-                    + tuple(v.astype(jnp.int32) for v in kvalids) + (iota,))
-            out = jax.lax.sort(keys, num_keys=len(keys) - 1)
+                    + (vpack, iota))
+            out = jax.lax.sort(keys, num_keys=len(keys), is_stable=False)
             s_kbits = list(out[2:2 + nk])
-            s_kvalids = [v != 0 for v in out[2 + nk:2 + 2 * nk]]
+            s_kvalids = [(out[2 + nk] >> (nk - 1 - i)) & 1 != 0
+                         for i in range(nk)]
         else:
             out = jax.lax.sort(
                 (dead, _group_hash(kbits, kvalids), iota), num_keys=2)

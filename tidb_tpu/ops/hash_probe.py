@@ -92,16 +92,21 @@ def resolve_mode(mode: str = None) -> str:
 
 def probe_for_join(sorted_hashes: jax.Array, probes: jax.Array,
                    mode: str = None):
-    """The fragment join's probe entry point: (lo, hi) ranges over the
-    sorted build hashes via the configured strategy. ``mode`` is the
-    per-statement value threaded from ExecContext through the fragment
-    builder (ISSUE 12 — the trace-time global read raced concurrent
-    sessions); None falls back to the process default for bare
-    fragments."""
+    """The fragment join's probe entry point: (lo, hi, path) — the
+    ranges over the sorted build hashes via the configured strategy, and
+    which of the two paths was traced for them (static): "table", the
+    open-addressing table (which still falls back inside the program if
+    a run finds no slot in MAX_PROBES), or "search", the binary search
+    by gathers — the sorted strategy, or a build past the table's half
+    load within MAX_CAPACITY. ``mode`` is the per-statement value
+    threaded from ExecContext through the fragment builder (ISSUE 12 —
+    the trace-time global read raced concurrent sessions); None falls
+    back to the process default for bare fragments."""
     if resolve_mode(mode) == "sorted":
         lo, hi = xla_probe_ranges(sorted_hashes, probes)
-        return lo.astype(jnp.int64), hi.astype(jnp.int64)
-    return probe_ranges(sorted_hashes, probes)
+        return lo.astype(jnp.int64), hi.astype(jnp.int64), "search"
+    return _table_or_search(sorted_hashes, probes)
+
 
 MAX_PROBES = 32
 # three int32 tables of this capacity ~= 6 MiB: dimension-sized build
@@ -246,6 +251,12 @@ def probe_ranges(sorted_hashes: jax.Array, probes: jax.Array):
     consumes them (hi - lo counts and lo + k positions). Falls back to
     searchsorted inside the SAME jit when the table build overflows its
     displacement bound, so callers never see a behavioral difference."""
+    return _table_or_search(sorted_hashes, probes)[:2]
+
+
+def _table_or_search(sorted_hashes: jax.Array, probes: jax.Array):
+    """`probe_ranges`' (lo, hi) and the path traced for them: "table" or
+    "search" (`probe_for_join`)."""
     from tidb_tpu.ops.join_kernels import _note_trace
 
     _note_trace("hash_probe")  # trace-time only: joins the retrace guard
@@ -253,7 +264,7 @@ def probe_ranges(sorted_hashes: jax.Array, probes: jax.Array):
     if cap is None:
         # load factor would exceed 1/2 within MAX_CAPACITY: stay on
         # searchsorted
-        return xla_probe_ranges(sorted_hashes, probes)
+        return (*xla_probe_ranges(sorted_hashes, probes), "search")
     keys, los, his, ok = _build_table(sorted_hashes, cap)
 
     def fast(_):
@@ -263,4 +274,4 @@ def probe_ranges(sorted_hashes: jax.Array, probes: jax.Array):
         lo, hi = xla_probe_ranges(sorted_hashes, probes)
         return lo.astype(jnp.int64), hi.astype(jnp.int64)
 
-    return jax.lax.cond(ok, fast, slow, None)
+    return (*jax.lax.cond(ok, fast, slow, None), "table")

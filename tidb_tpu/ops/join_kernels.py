@@ -454,24 +454,34 @@ def sort_build_hashes(b_hash, b_live):
     hash and ``cvi[i]`` counts live rows in the sorted prefix [0, i) —
     so (cvi[hi] - cvi[lo]) is an exact live-match count per range."""
     Rb = b_hash.shape[0]
-    inval = (~b_live).astype(jnp.int32)
-    sh, sinv, order = jax.lax.sort(
-        (b_hash, inval, jnp.arange(Rb)), num_keys=2)
+    if Rb >= 1 << 31:
+        raise ValueError(f"a build side of {Rb} slots a shard")
+    # ONE 32-bit operand beside the hash: the row number with the dead
+    # flag as its top bit. As the second key it orders live before dead
+    # and rows by number, a total order, so the sort need not be stable:
+    # the same permutation as a stable sort by (hash, dead) carrying an
+    # int64 row number, which the chip's compiler took five times as
+    # long over (257 s against 50 s for 1.5M rows, 115 against 40 for
+    # 61,440: a described v5e, PERF.md)
+    tag = (jnp.arange(Rb, dtype=jnp.uint32)
+           | ((~b_live).astype(jnp.uint32) << 31))
+    sh, tag = jax.lax.sort((b_hash, tag), num_keys=2, is_stable=False)
     cvi = jnp.concatenate([
         jnp.zeros(1, dtype=jnp.int64),
-        prefix.cumsum((sinv == 0).astype(jnp.int64)),
+        prefix.cumsum((tag >> 31 == 0).astype(jnp.int64)),
     ])
-    return sh, cvi, order
+    return sh, cvi, (tag & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
 
 
 def probe_hash_ranges(sh, cvi, p_hash, p_ok, mode=None):
-    """(lo, cnt) per probe row over a sorted build-hash array, through
-    the configured probe strategy (ops/hash_probe: open-addressing table
-    on TPU, searchsorted elsewhere — identical range semantics).
+    """(lo, cnt, path) per probe row over a sorted build-hash array,
+    through the configured probe strategy (ops/hash_probe: open-addressing
+    table on TPU, searchsorted elsewhere — identical range semantics;
+    `path` says which was traced, "table" or "search").
     ``mode`` threads the per-statement tidb_tpu_join_probe_mode from
     the fragment args (ISSUE 12); None = process default."""
     from tidb_tpu.ops.hash_probe import probe_for_join
 
-    lo, hi = probe_for_join(sh, p_hash, mode=mode)
+    lo, hi, path = probe_for_join(sh, p_hash, mode=mode)
     cnt = jnp.where(p_ok, cvi[hi] - cvi[lo], 0)
-    return lo, cnt
+    return lo, cnt, path

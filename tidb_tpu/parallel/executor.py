@@ -58,7 +58,7 @@ __all__ = ["ShardCache", "build_dist_executor", "DistAggExec", "DistJoinAggExec"
 
 @contextlib.contextmanager
 def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
-                     reduced: Tuple[int, int] = (0, 0)):
+                     reduced: Tuple[int, int] = (0, 0), joins=()):
     """One fragment launch: the span ``fragment.<kind>[parts=N]`` on the
     statement's trace and the FRAGMENT_SECONDS collector for /metrics
     (with a trace_id exemplar). Wall time covers the launch plus any
@@ -69,11 +69,15 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
     never desynchronize — and beside it the `exchanges` the launched
     program holds (FRAGMENT_EXCHANGE_STEPS; 0 on a mesh of one part) and
     the payloads its sort-reduces take, `reduced` = (summed in row order,
-    by a segment op) (FRAGMENT_REDUCE_PAYLOADS; a generic aggregate's)."""
+    by a segment op) (FRAGMENT_REDUCE_PAYLOADS; a generic aggregate's),
+    and the joins a general fragment's program holds, `joins` = the probe
+    path of each (FRAGMENT_JOINS; read after the launch: a program's
+    first call fills the list as it traces)."""
     from tidb_tpu.utils import tracing
     from tidb_tpu.utils.metrics import (
         FRAGMENT_DISPATCH,
         FRAGMENT_EXCHANGE_STEPS,
+        FRAGMENT_JOINS,
         FRAGMENT_REDUCE_PAYLOADS,
         FRAGMENT_SECONDS,
     )
@@ -85,6 +89,8 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
     FRAGMENT_EXCHANGE_STEPS.inc(exchanges, kind=kind)
     for path, n in zip(("runs", "scatter"), reduced):
         FRAGMENT_REDUCE_PAYLOADS.inc(n, kind=kind, path=path)
+    for probe in joins:
+        FRAGMENT_JOINS.inc(kind=kind, probe=probe)
     FRAGMENT_SECONDS.observe(time.perf_counter() - t0, kind=kind)
 
 
@@ -164,6 +170,8 @@ class ShardCache:
                 note_placement("fragment", out)
             return out
 
+        # a general fragment's joins by probe path (fragment.py build_fn)
+        dispatch.join_probes = getattr(fn, "join_probes", ())
         return dispatch
 
     def get_growth(self, gkey) -> float:
@@ -616,7 +624,7 @@ class DistFragmentExec(HashAggExec):
             fn = self._cache.get_fragment(
                 key, lambda: prog.build_fn(growths, probe_mode=probe_mode))
             with _fragment_launch(kind, n_parts, prog.n_exchange,
-                                  prog.n_reduce):
+                                  prog.n_reduce, fn.join_probes):
                 out, ovf = fn(*args)
             # host-sync: the per-knob overflow vector (a few int64s)
             # gates the capacity-retry loop — one fetch per dispatch

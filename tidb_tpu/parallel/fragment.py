@@ -33,7 +33,13 @@ they are, the generic aggregate's partial table — reduced exactly — is
 its final table, and neither adds an "exch" knob. FragmentProgram's
 `n_exchange` says how many repartitions a program holds, `n_reduce` how
 many payloads its sort-reduces sum in row order and how many by a
-segment op (agg_device._sort_reduce).
+segment op (agg_device._sort_reduce), `n_join` how many joins.
+
+A join's device ops carry its number and stage in their `op_name`:
+``join<j>/join.{compact,build,probe,expand,gather}``, j counting the
+joins in the order their ops run (a join's inputs before the join; the
+order of the growth knobs); an eager partial aggregate under a join
+sorts and reduces under ``agg.eager``.
 """
 
 from __future__ import annotations
@@ -170,8 +176,11 @@ class FragmentProgram:
     # payloads of the generic aggregate's sort-reduces: ("runs" summed in
     # row order, "scatter" by a segment op) — FRAGMENT_REDUCE_PAYLOADS
     n_reduce: Tuple[int, int]
+    n_join: int                        # joins compiled in (_join_producer)
     sig: str
-    build_fn: Callable                 # (growths tuple) -> per-shard program
+    # (growths tuple) -> per-shard program; its `join_probes` lists, once
+    # traced, the probe path of each join ("table" | "search"), join0 first
+    build_fn: Callable
     out_kind: str                      # "segment" | "generic"
     domains: List[int] = field(default_factory=list)
     growth_defaults: Tuple[float, ...] = ()
@@ -211,6 +220,8 @@ class _Compiler:
         # how each payload of the program's sort-reduces is reduced
         # (agg_device.reduce_paths: "runs" | "scatter")
         self.reduce_paths: List[str] = []
+        # joins compiled into the program, numbered as their ops run
+        self.n_join = 0
 
     def _add_growth(self, default: float, kind: str) -> int:
         idx = self.n_growth
@@ -223,8 +234,8 @@ class _Compiler:
     # count read from the data (three sigma of the NDV sketch are 9.4%)
     HEADROOM, NDV_HEADROOM = 2.0, 1.25
 
-    def _compact_knob(self, est_rows: float,
-                      headroom: float = HEADROOM) -> Tuple[int, int]:
+    def _compact_knob(self, est_rows: float, headroom: float = HEADROOM,
+                      rounded: bool = True) -> Tuple[int, int]:
         """Estimate-sized compaction target: a "compact" knob plus its
         base capacity (`headroom` times the per-shard cardinality
         estimate, floor 64). The base is part of the fragment signature —
@@ -232,6 +243,15 @@ class _Compiler:
         fragment compiled with the old capacities."""
         base = max(64, int(np.ceil(
             headroom * max(est_rows, 1.0) / self.n_parts)))
+        if rounded:
+            # a capacity is a shape, and a shape is a compile: a guess's
+            # capacity keeps four leading bits (at most an eighth more
+            # slots), so that two loads of one deployment whose counts
+            # differ by a percent share one program. A group table sized
+            # from a distinct count stays to the slot (`rounded=False`):
+            # every slot of it is fetched
+            step = 1 << max(base.bit_length() - 4, 0)
+            base = -(-base // step) * step
         idx = self._add_growth(1.0, "compact")
         self.sig.append(f"cap{idx}:{base}")
         return idx, base
@@ -306,7 +326,8 @@ class _Compiler:
 
         def emit(env, growths):
             chunk, ovfs = child_emit(env, growths)
-            t = partial(chunk)
+            with jax.named_scope("agg.eager"):
+                t = partial(chunk)
             live = jnp.arange(chunk.capacity) < t["n"]
             cols = {}
             for i, uid in enumerate(agg.group_uids):
@@ -426,6 +447,9 @@ class _Compiler:
             self.n_exchange += 2
         g_exch = self._add_growth(2.0, "exch") if exchange else None
         g_expand = self._add_growth(1.0, "expand")
+        # numbered after its inputs, as the knobs are: join0's ops run first
+        j_scope = f"join{self.n_join}"
+        self.n_join += 1
         # estimate-sized compaction targets (overflow-retried): selective
         # filters/joins collapse live counts, and every sort/exchange
         # downstream pays for capacity — so shrink to ~2x the planner's
@@ -453,15 +477,20 @@ class _Compiler:
             pch, p_ovf = probe_emit(env, growths)
             bch, b_ovf = build_emit(env, growths)
             ovfs = list(p_ovf) + list(b_ovf)
+            # the inputs' ops carry their own scopes; this join's, from here
+            with jax.named_scope(j_scope):
+                return join_local(env, growths, pch, bch, ovfs)
 
-            capP = int(np.ceil(growths[g_pcomp] * p_base))
-            if capP < pch.capacity:
-                pch, o = _compact_chunk(pch, capP)
-                ovfs.append((g_pcomp, pmax(o, _AXES)))
-            capB = int(np.ceil(growths[g_bcomp] * b_base))
-            if capB < bch.capacity:
-                bch, o = _compact_chunk(bch, capB)
-                ovfs.append((g_bcomp, pmax(o, _AXES)))
+        def join_local(env, growths, pch, bch, ovfs):
+            with jax.named_scope("join.compact"):
+                capP = int(np.ceil(growths[g_pcomp] * p_base))
+                if capP < pch.capacity:
+                    pch, o = _compact_chunk(pch, capP)
+                    ovfs.append((g_pcomp, pmax(o, _AXES)))
+                capB = int(np.ceil(growths[g_bcomp] * b_base))
+                if capB < bch.capacity:
+                    bch, o = _compact_chunk(bch, capB)
+                    ovfs.append((g_bcomp, pmax(o, _AXES)))
 
             p_outs = [eval_expr(k, pch) for k in probe_keys]
             b_outs = [eval_expr(k, bch) for k in build_keys]
@@ -533,39 +562,45 @@ class _Compiler:
                 tile_positions,
             )
 
-            b_live = bch2.sel & b_kvalid2
-            sh, cvi, order = sort_build_hashes(b_hash2, b_live)
-            p_ok = pch2.sel & p_kvalid2
-            # probe strategy threaded per-statement via build_fn (the
-            # module-global read raced concurrent sessions, ISSUE 12)
-            lo, cnt = probe_hash_ranges(sh, cvi, p_hash2, p_ok,
-                                        mode=env.get("probe_mode"))
+            with jax.named_scope("join.build"):
+                b_live = bch2.sel & b_kvalid2
+                sh, cvi, order = sort_build_hashes(b_hash2, b_live)
+            with jax.named_scope("join.probe"):
+                p_ok = pch2.sel & p_kvalid2
+                # probe strategy threaded per-statement via build_fn (the
+                # module-global read raced concurrent sessions, ISSUE 12)
+                lo, cnt, path = probe_hash_ranges(
+                    sh, cvi, p_hash2, p_ok, mode=env.get("probe_mode"))
+                env["joins"].append(path)
 
-            cum = prefix.cumsum(cnt)
-            total = cum[-1]
-            growth_j = growths[g_expand]
-            capJ = int(np.ceil(growth_j * Rp))
-            # required-factor-minus-one, maxed over shards (0 = fits)
-            factor = (total + capJ - 1) // capJ
-            ovfs.append((g_expand, pmax(jnp.maximum(factor - 1, 0), _AXES)))
+            with jax.named_scope("join.expand"):
+                cum = prefix.cumsum(cnt)
+                total = cum[-1]
+                growth_j = growths[g_expand]
+                capJ = int(np.ceil(growth_j * Rp))
+                # required-factor-minus-one, maxed over shards (0 = fits)
+                factor = (total + capJ - 1) // capJ
+                ovfs.append(
+                    (g_expand, pmax(jnp.maximum(factor - 1, 0), _AXES)))
 
-            valid_out, p_row, b_sorted_pos, k = tile_positions(
-                lo, cnt, cum, 0, capJ, Rp, Rb)
-            b_row = order[b_sorted_pos]
+                valid_out, p_row, b_sorted_pos, k = tile_positions(
+                    lo, cnt, cum, 0, capJ, Rp, Rb)
+                b_row = order[b_sorted_pos]
 
-            sel_out = valid_out
-            if need_verify:  # hash routing can collide; verify exact keys
-                for pb, bb, in zip(p_bits2, b_bits2):
-                    sel_out = sel_out & (pb[p_row] == bb[b_row])
-                sel_out = sel_out & p_kvalid2[p_row] & b_kvalid2[b_row]
+            with jax.named_scope("join.gather"):
+                sel_out = valid_out
+                if need_verify:  # hash routing can collide; verify exact keys
+                    for pb, bb, in zip(p_bits2, b_bits2):
+                        sel_out = sel_out & (pb[p_row] == bb[b_row])
+                    sel_out = sel_out & p_kvalid2[p_row] & b_kvalid2[b_row]
 
-            cols = {}
-            for uid, col in pch2.columns.items():
-                cols[uid] = col.gather(p_row, valid_out)
-            for uid, col in bch2.columns.items():
-                bc = col.gather(b_row, valid_out)
-                cols[uid] = Column(bc.data, bc.valid & sel_out, col.type_)
-            joined = Chunk(cols, sel_out & pch2.sel[p_row])
+                cols = {}
+                for uid, col in pch2.columns.items():
+                    cols[uid] = col.gather(p_row, valid_out)
+                for uid, col in bch2.columns.items():
+                    bc = col.gather(b_row, valid_out)
+                    cols[uid] = Column(bc.data, bc.valid & sel_out, col.type_)
+                joined = Chunk(cols, sel_out & pch2.sel[p_row])
 
             if other_pred is not None:
                 joined = joined.filter(other_pred(joined))
@@ -607,8 +642,9 @@ class _Compiler:
 
             capO = int(np.ceil(growths[g_ocomp] * o_base))
             if capO < result.capacity:
-                result, o = _compact_chunk(result, capO)
-                ovfs.append((g_ocomp, pmax(o, _AXES)))
+                with jax.named_scope("join.compact"):
+                    result, o = _compact_chunk(result, capO)
+                    ovfs.append((g_ocomp, pmax(o, _AXES)))
             return result, ovfs
 
         return emit
@@ -767,7 +803,8 @@ class _Compiler:
         # smaller headroom
         g_tab, tab_base = self._compact_knob(
             agg.est_rows,
-            self.NDV_HEADROOM if agg.est_from_ndv else self.HEADROOM)
+            self.NDV_HEADROOM if agg.est_from_ndv else self.HEADROOM,
+            rounded=not agg.est_from_ndv)
         self.sig.append(
             f"genagg:{agg.group_exprs!r}:{agg.aggs!r}:exch{not one_part}")
 
@@ -878,8 +915,11 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
         # (trace-time STATIC — callers key their fragment cache on it so
         # a knob flip can never serve a program traced for the other
         # strategy); None = the hash_probe process default
+        join_probes: List[str] = []
+
         def frag_general(*args):
-            env = {"scan": [], "bcast": [], "probe_mode": probe_mode}
+            env = {"scan": [], "bcast": [], "probe_mode": probe_mode,
+                   "joins": []}
             i = 0
             for _ in range(n_src):
                 env["scan"].append((args[i], args[i + 1], args[i + 2],
@@ -889,6 +929,7 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
                 env["bcast"].append((args[i], args[i + 1], args[i + 2]))
                 i += 3
             out, reports = emit(env, growths)
+            join_probes[:] = env["joins"]  # trace time: static per program
             # per-knob overflow vector, slot-indexed by knob id so the
             # executor always grows exactly the blown capacity (emission
             # order differs from knob-assignment order)
@@ -906,18 +947,23 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
         # caches build_fn(growths) under (sig, growths, shapes, types)
         # via ShardCache.get_fragment; the closure carries the compiled
         # plan description only — every array arrives as an argument
-        return jax.jit(jax.shard_map(
+        fn = jax.jit(jax.shard_map(
             frag_general, mesh=mesh, in_specs=in_specs, out_specs=(out_spec, P()),
             # pallas_call outputs carry no vma metadata; the fragment's
             # out_specs are the authority here
             check_vma=False,
         ))
+        # what FRAGMENT_JOINS counts at every launch: filled by the first
+        # call's trace, kept with the function in the fragment cache
+        fn.join_probes = join_probes
+        return fn
 
     return FragmentProgram(
         agg=agg, sources=c.sources, broadcasts=c.broadcasts,
         n_growth=c.n_growth, n_exchange=c.n_exchange,
         n_reduce=(c.reduce_paths.count("runs"),
                   c.reduce_paths.count("scatter")),
+        n_join=c.n_join,
         sig="|".join(c.sig),
         build_fn=build_fn,
         out_kind=out_kind, domains=domains,

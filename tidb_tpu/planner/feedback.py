@@ -28,7 +28,9 @@ compared against reality). The store closes that loop:
           digest's default plan carries an eager partial, the
           alternative (no-push, fusible) plan is explored once and the
           warm-measured faster variant wins (no statement needs a
-          ``tidb_opt_agg_push_down=0`` pin);
+          ``tidb_opt_agg_push_down=0`` pin) — except where the default
+          plan ran as one general compiled fragment: trying the other
+          is a second whole-program compile, and the heuristic stands;
       (c) fused-probe tile sizing: observed overflow rates raise the
           statement's ``join_tiles`` so dup-heavy probes expand in
           fewer dispatches.
@@ -110,6 +112,8 @@ class Observation:
         self.join_rows: Dict[frozenset, float] = {}
         self.eager_partial = False
         self.fused_probe = False
+        # the tree ran a general compiled fragment (DistFragmentExec)
+        self.fragment_program = False
         self.latency_s = 0.0
         self.warm = False
         self.tile_chunks = 0
@@ -128,9 +132,9 @@ class _Variant:
 
     __slots__ = ("digest", "plan_digest", "apd", "execs", "warm_execs",
                  "best_warm_s", "best_any_s", "eager_partial",
-                 "fused_probe", "ops", "tile_chunks", "tile_overflows",
-                 "tile_max_need", "topn_overflow", "worst_drift",
-                 "worst_drift_op")
+                 "fused_probe", "fragment_program", "ops", "tile_chunks",
+                 "tile_overflows", "tile_max_need", "topn_overflow",
+                 "worst_drift", "worst_drift_op")
 
     def __init__(self, digest: str, plan_digest: str, apd: bool):
         self.digest = digest
@@ -142,6 +146,7 @@ class _Variant:
         self.best_any_s: Optional[float] = None
         self.eager_partial = False
         self.fused_probe = False
+        self.fragment_program = False
         self.ops: "OrderedDict[str, OpObservation]" = OrderedDict()
         self.tile_chunks = 0
         self.tile_overflows = 0
@@ -374,6 +379,7 @@ class PlanFeedbackStore:
             v.execs += 1
             v.eager_partial = obs.eager_partial
             v.fused_probe = v.fused_probe or obs.fused_probe
+            v.fragment_program = obs.fragment_program
             v.best_any_s = (obs.latency_s if v.best_any_s is None
                             else min(v.best_any_s, obs.latency_s))
             if obs.warm:
@@ -490,6 +496,15 @@ class PlanFeedbackStore:
                 # push-down never fired (or the default variant hasn't
                 # run yet): the decision changes nothing — stay default
                 return None
+            if on.fragment_program:
+                # the statement is ONE compiled program (parallel/
+                # fragment.py) and the alternative a second one, compiled
+                # whole inside somebody's statement and once more per set
+                # of filter literals — minutes each for an accelerator's
+                # compiler (TPC-H Q3: PERF.md) — for a measurement a text
+                # statement, planned anew every time, never has warm: the
+                # cost to try outweighs the choice; the heuristic stands
+                return None
             if off is None:
                 return False  # explore the no-push alternative once
             s_off, s_on = off.score(), on.score()
@@ -590,6 +605,7 @@ class PlanFeedbackStore:
                         "best_any_ms": round((v.best_any_s or 0) * 1e3, 3),
                         "eager_partial": v.eager_partial,
                         "fused_probe": v.fused_probe,
+                        "fragment_program": v.fragment_program,
                         "worst_drift": round(v.worst_drift, 3),
                         "worst_drift_op": v.worst_drift_op,
                         "tile_overflow": [v.tile_overflows, v.tile_chunks],
@@ -720,6 +736,8 @@ def harvest(phys, root, result_rows: int, latency_s: float,
         stack.extend(c for c in e.children if c is not None)
         p = getattr(e, "_feedback_plan", None)
         st = getattr(e, "stats", None)
+        if type(e).__name__ == "DistFragmentExec":
+            obs.fragment_program = True
         if type(e).__name__ == "FusedScanProbeExec" \
                 and getattr(e, "_ran_fused", False):
             obs.fused_probe = True

@@ -743,7 +743,7 @@ def _estimate_groups(plan: "LAggregate") -> Tuple[float, bool]:
 
 
 def _estimate(plan: LogicalPlan) -> float:
-    from tidb_tpu.statistics import scan_selectivity, table_stats
+    from tidb_tpu.statistics import load_stats, scan_selectivity, table_stats
 
     if isinstance(plan, LScan):
         if plan.table is None:
@@ -763,7 +763,9 @@ def _estimate(plan: LogicalPlan) -> float:
                                       plan.pushed_cond, uid_to_name, n)
                 if got is not None:
                     return max(min(got, n), 1.0)
-            if s is not None:
+            if s is not None or load_stats(plan.table) is not None:
+                # ANALYZE's statistics, or what the bulk load recorded
+                # of its columns while nothing has been written since
                 uid_to_col = {c.uid: c.name for c in plan.schema}
                 n *= scan_selectivity(plan.table, plan.pushed_cond, uid_to_col)
             else:

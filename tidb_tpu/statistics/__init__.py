@@ -29,16 +29,20 @@ import numpy as np
 from tidb_tpu.types import TypeKind
 
 __all__ = ["ColumnStats", "TableStats", "analyze_table", "table_stats",
-           "zone_map_stats", "scan_selectivity", "column_ndv",
+           "zone_map_stats", "load_stats", "record_load_stats",
+           "scan_selectivity", "column_ndv",
            "eq_join_selectivity", "NDVSketch", "HIST_BUCKETS", "MCV_SIZE"]
 
 HIST_BUCKETS = 64
 MCV_SIZE = 16
+# a bulk load counts the codes of a dictionary column of at most this
+# many values (one bincount); a wider pool is no "few codes"
+LOAD_CODES = 256
 
 
 @dataclass
 class ColumnStats:
-    ndv: int
+    ndv: Optional[int]  # None: not counted (a bulk load's non-key column)
     null_count: int
     min: Optional[float] = None
     max: Optional[float] = None
@@ -232,6 +236,55 @@ def table_stats(table) -> Optional[TableStats]:
     return None
 
 
+def record_load_stats(table, m: int) -> None:
+    """What a bulk load of `m` rows (every one live, the table empty
+    before) leaves for `scan_selectivity`, since nobody runs ANALYZE
+    between a load and the first statement and a guessed 0.25 a filter
+    sizes the device's compaction buffers: of every numeric or date
+    column its bounds (a two-point histogram, as the zone maps give)
+    and null count, with the distinct count where the load sketched the
+    column (`Table._seed_key_sketches`) and None where it did not; of a
+    dictionary column of few values (LOAD_CODES) the count of every
+    code, as a complete MCV list. Stamped with the table's version and
+    kept apart from ``table.stats`` (the plan cache keys on that
+    object's identity): the next write makes it stale, and stale it is
+    not read (`load_stats`) — a bound that no longer holds must not
+    shrink an estimate."""
+    rec = TableStats(n_rows=m, version=table.version)
+    for c in table.schema.columns:
+        valid = table.valid[c.name][:m]
+        vals = table.data[c.name][:m]
+        if not valid.all():
+            vals = vals[valid]
+        nulls = m - len(vals)
+        dic = table.dicts.get(c.name)
+        if not len(vals):
+            rec.cols[c.name] = ColumnStats(ndv=0, null_count=nulls)
+        elif dic is not None:
+            if len(dic) > LOAD_CODES:
+                continue
+            counts = np.bincount(vals, minlength=len(dic))
+            rec.cols[c.name] = ColumnStats(
+                ndv=int(np.count_nonzero(counts)), null_count=nulls,
+                mcv={dic.values[i]: int(n) for i, n in enumerate(counts) if n})
+        elif vals.dtype.kind in "iufb":
+            lo, hi = float(vals.min()), float(vals.max())
+            sk = table.ndv_sketch.get(c.name)
+            rec.cols[c.name] = ColumnStats(
+                ndv=max(int(round(sk.estimate())), 1) if sk is not None else None,
+                null_count=nulls, min=lo, max=hi, bounds=np.array([lo, hi]))
+    table.load_stats = rec
+
+
+def load_stats(table) -> Optional[TableStats]:
+    """The bulk load's record (`record_load_stats`) while no write has
+    followed the load."""
+    s = getattr(table, "load_stats", None)
+    if s is not None and s.version == table.version:
+        return s
+    return None
+
+
 def zone_map_stats(table) -> Optional[TableStats]:
     """Fallback stats derived from the columnar segment store's zone
     maps (ISSUE 8): per-column min/max as a two-point histogram,
@@ -346,7 +399,37 @@ def _conjuncts(cond):
 _CMP = {"eq", "ne", "lt", "le", "gt", "ge"}
 
 
-def _pred_selectivity(stats: TableStats, pred, uid_to_col: Dict[str, str]) -> float:
+def _in_column_repr(col, lit) -> float:
+    """A literal's value as the column's device representation holds it
+    (the form the bounds and the histogram are in): a DECIMAL is an
+    integer at its scale, and the comparison rescales a literal of
+    another scale only when it is evaluated — `l_quantity < 30` carries
+    the INT 30 against values of 100 to 5000."""
+    def scale(t):
+        return t.scale if t.kind == TypeKind.DECIMAL else 0
+
+    return float(lit.value) * 10.0 ** (scale(col.type_) - scale(lit.type_))
+
+
+def _value_fraction(stats: TableStats, cs: ColumnStats, lit, dic) -> Optional[float]:
+    """The share of rows equal to `lit` (a dictionary code, or a number
+    in the column's representation), where the MCV list holds every
+    value of the column (a bulk load's code counts; an ANALYZE of a
+    column whose every value repeats): exact, whatever the skew. None
+    where values are left out of the list. A string literal is its code
+    in the table's dictionary `dic` (-1: in no row)."""
+    if cs.mcv is None or cs.ndv != len(cs.mcv):
+        return None
+    if dic is not None:
+        code = int(lit)
+        key = dic.values[code] if 0 <= code < len(dic.values) else None
+    else:
+        key = lit
+    return cs.mcv.get(key, 0) / max(stats.n_rows, 1)
+
+
+def _pred_selectivity(stats: TableStats, pred, uid_to_col: Dict[str, str],
+                      dicts: Optional[dict] = None) -> float:
     from tidb_tpu.expression.expr import Call, ColumnRef, InList, Literal
 
     if isinstance(pred, Call) and pred.op in _CMP and len(pred.args) == 2:
@@ -363,7 +446,14 @@ def _pred_selectivity(stats: TableStats, pred, uid_to_col: Dict[str, str]) -> fl
             if cs is None:
                 return {"eq": 0.1, "ne": 0.9}.get(op, 0.33)
             nn = max(stats.n_rows - cs.null_count, 1)
-            v = float(b.value)
+            dic = (dicts or {}).get(col)
+            v = float(b.value) if dic is not None else _in_column_repr(a, b)
+            if op in ("eq", "ne"):
+                f = _value_fraction(stats, cs, v, dic)
+                if f is not None:
+                    return f if op == "eq" else nn / max(stats.n_rows, 1) - f
+                if cs.ndv is None:
+                    return {"eq": 0.1, "ne": 0.9}[op]
             if op == "eq":
                 return min(1.0 / max(cs.ndv, 1), 1.0) * (nn / max(stats.n_rows, 1))
             if op == "ne":
@@ -376,13 +466,13 @@ def _pred_selectivity(stats: TableStats, pred, uid_to_col: Dict[str, str]) -> fl
     if isinstance(pred, InList) and isinstance(pred.arg, ColumnRef):
         col = uid_to_col.get(pred.arg.name)
         cs = stats.cols.get(col) if col else None
-        if cs is not None:
+        if cs is not None and cs.ndv is not None:
             f = min(len(pred.values) / max(cs.ndv, 1), 1.0)
             return 1.0 - f if pred.negated else f
     if isinstance(pred, Call) and pred.op == "or":
         s = 0.0
         for a in pred.args:
-            s = s + _pred_selectivity(stats, a, uid_to_col) * (1 - s)
+            s = s + _pred_selectivity(stats, a, uid_to_col, dicts) * (1 - s)
         return min(s, 1.0)
     if isinstance(pred, Call) and pred.op == "is_null":
         arg = pred.args[0]
@@ -404,9 +494,14 @@ def scan_selectivity(table, cond, uid_to_col: Dict[str, str]) -> float:
         # against real bounds instead of the 0.25-per-conjunct guess
         stats = zone_map_stats(table)
     if stats is None or stats.n_rows == 0:
+        # never analysed, no segment store: what the bulk load recorded
+        # holds until the first write after it
+        stats = load_stats(table)
+    if stats is None or stats.n_rows == 0:
         n = sum(1 for _ in _conjuncts(cond))
         return 0.25 ** min(n, 2)
+    dicts = getattr(table, "dicts", None)
     sel = 1.0
     for pred in _conjuncts(cond):
-        sel *= _pred_selectivity(stats, pred, uid_to_col)
+        sel *= _pred_selectivity(stats, pred, uid_to_col, dicts)
     return min(max(sel, 1.0 / max(stats.n_rows, 1)), 1.0)

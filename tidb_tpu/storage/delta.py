@@ -40,7 +40,7 @@ from tidb_tpu.errors import ExecutionError
 # attribute reads that must NOT trigger compaction (schema-shaped or
 # engine bookkeeping; everything else sees the post-compaction state)
 _PASSTHROUGH = {
-    "schema", "indexes", "ts_source", "stats", "ndv_sketch",
+    "schema", "indexes", "ts_source", "stats", "ndv_sketch", "load_stats",
     "modify_count", "to_device_value", "engine",
     # schema-derived reads: must not force a compaction per statement
     "insertable_names", "generated", "foreign_keys", "checks",

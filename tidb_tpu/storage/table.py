@@ -315,6 +315,9 @@ class Table:
         # ANALYZE and fed by every insert so distinct-count estimates
         # track DML churn between analyzes
         self.ndv_sketch: Dict[str, object] = {}
+        # what a bulk load saw of every column (statistics.record_load_stats):
+        # scan_selectivity's input until ANALYZE runs or a write follows
+        self.load_stats = None
         # FOREIGN KEY constraints: this table's child-side FKs, and
         # back-edges from tables whose FKs reference THIS table
         self.foreign_keys: List[FKInfo] = []
@@ -910,6 +913,9 @@ class Table:
         self.version += 1
         self._uniq_commit()
         self._seed_key_sketches(m)
+        from tidb_tpu.statistics import record_load_stats
+
+        record_load_stats(self, m)
         return m
 
     def _seed_key_sketches(self, m: int) -> None:

@@ -457,6 +457,15 @@ FRAGMENT_REDUCE_PAYLOADS = Counter(
     "sum, a min or a max by a segment op. A launch adds its program's "
     "counts (a generic aggregate's states, twice on a mesh of several "
     "parts: before and after the exchange)")
+FRAGMENT_JOINS = Counter(
+    "tidb_tpu_fragment_joins_total",
+    "Joins compiled into the general fragment programs launched "
+    "(parallel/fragment.py _join_producer), by fragment kind and by the "
+    "probe path each takes, static per program: probe=table, the "
+    "open-addressing table of ops/hash_probe.py; probe=search, the binary "
+    "search by gathers over the sorted build hashes (the sorted strategy, "
+    "or a build side past the table's half load). A launch adds its "
+    "program's joins (TPC-H Q3: 2); a fragment without a join adds nothing")
 FRAGMENT_RETRY_TOTAL = Counter(
     "tidb_tpu_fragment_retry_total",
     "Fragment launches thrown away because a capacity knob overflowed "
