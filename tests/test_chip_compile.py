@@ -236,14 +236,18 @@ def test_for_decode_compiles(one_chip, tpu_target):
 
 # -- whole programs ---------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def tiny_tpch():
+def _tpch(sf: float):
     from tidb_tpu.storage.catalog import Catalog
     from tidb_tpu.storage.tpch import load_tpch
 
     catalog = Catalog()
-    load_tpch(catalog, sf=0.05)  # lineitem spans several 65536-row segments
+    load_tpch(catalog, sf=sf)
     return catalog
+
+
+@pytest.fixture(scope="module")
+def tiny_tpch():
+    return _tpch(0.05)  # lineitem spans several 65536-row segments
 
 
 @contextlib.contextmanager
@@ -435,6 +439,20 @@ def test_fragment_program_is_named_and_its_stages_are_scoped(tiny_tpch, maker,
         assert "all-to-all" in compiled
 
 
+def _ops_by_scope(text: str, scope_re: str) -> dict:
+    """{groups of `scope_re` in an op's op_name: [(opcode, result type)]}
+    over a compiled program's text, fused computations' bodies included."""
+    import re
+
+    ops = {}
+    for line in text.splitlines():
+        scope = re.search(r'op_name="[^"]*?' + scope_re + "/", line)
+        op = re.search(r'= (\(.*?\)|\S+) ([a-z][\w-]*)\(', line)
+        if scope and op:
+            ops.setdefault(scope.groups(), []).append((op.group(2), op.group(1)))
+    return ops
+
+
 @pytest.mark.parametrize("n_dev", [1, 4], ids=["1x1", "1x4"])
 def test_join_fragment_ranks_without_search(topo, tpu_target, tiny_tpch,
                                             n_dev):
@@ -448,8 +466,6 @@ def test_join_fragment_ranks_without_search(topo, tpu_target, tiny_tpch,
     column above the join needs stays under `join.gather`. Shapes: 2,048
     probe and 512 build rows a chip, where the compiler takes a sort in
     seconds."""
-    import re
-
     from tidb_tpu.parallel import make_mesh
     from tidb_tpu.parallel.distsql import make_join_agg_fragment
 
@@ -460,13 +476,8 @@ def test_join_fragment_ranks_without_search(topo, tpu_target, tiny_tpch,
     build, b_shapes = _described_table(build, mesh, n_dev, 512)
     fn = make_join_agg_fragment(probe, build, *args[2:], **kw)
     text = _compile(fn, *p_shapes, *b_shapes).as_text()
-    by_scope = {}  # stage -> opcodes, fused computations' bodies included
-    for line in text.splitlines():
-        scope = re.search(
-            r'op_name="jit\(frag_join_agg\)/[^"]*?(join\.\w+)/', line)
-        op = re.search(r'= (?:\(.*?\)|\S+) ([a-z][\w-]*)\(', line)
-        if scope and op:
-            by_scope.setdefault(scope.group(1), set()).add(op.group(1))
+    by_scope = {stage: {o for o, _ in ops} for (stage,), ops in _ops_by_scope(
+        text, r"jit\(frag_join_agg\)/[^\"]*?(join\.\w+)").items()}
     assert {"join.sort", "join.probe", "join.unsort",
             "join.gather"} <= set(by_scope), sorted(by_scope)
     assert "sort" in by_scope["join.sort"] and "sort" in by_scope["join.unsort"]
@@ -579,7 +590,10 @@ def test_general_fragment_fits_a_cold_statement(topo, tpu_target, tiny_tpch, q,
     planner pre-aggregates lineitem under the joins, a fourth sort. The
     chip was asked (PR 32, PERF.md section 6): a cold Q3 answers in
     395-418 s, its program compiling in 383-405 (the parent's three
-    sorts: 702 s, twice); it is in the smoke's list."""
+    sorts: 702 s, twice); it is in the smoke's list. PR 33's merged rank
+    keeps the count: the one sort of both sides takes the place of the
+    build's own, join for join (cold on the chip: 384 s, 389 to the
+    answer), and the two strict xfails stay as they were (10 and 6)."""
     from chip_smoke import Q18_INNER_SQL
     from tidb_tpu.storage.tpch_queries import Q
 
@@ -593,6 +607,51 @@ def test_general_fragment_fits_a_cold_statement(topo, tpu_target, tiny_tpch, q,
         if q == "q18_inner":
             _one_program_cold(tiny_tpch, prog, growths)
     assert sum(counts) <= SORT_BUDGET, counts
+
+
+@pytest.fixture(scope="module")
+def speck_tpch():
+    return _tpch(0.001)  # 6,000 lineitem rows
+
+
+@pytest.mark.parametrize("n_dev", [1, 4], ids=["1x1", "1x4"])
+def test_q3_fragment_joins_rank_without_search(topo, tpu_target, speck_tpch,
+                                               n_dev):
+    """What PR 33 bought, pinned in the chip's own compiled text as
+    `test_join_fragment_ranks_without_search` pins PR 26's: under the
+    default probe mode each of Q3's two joins ranks its probe slots by
+    ONE sort of both sides (`join<j>/join.build`) and reads the ranges
+    off the merged order (`join<j>/join.probe`): no `while` (a binary
+    search, 2 x 21 rounds of a gather over every probe slot: 5.1 s of a
+    9.4 s statement; or the table probe's 32 rounds), no `conditional`
+    (the table with the whole search kept in its other arm), and what
+    is scattered back to probe-slot order is 32 bits wide (a 64-bit
+    scatter is a tuple of two u32 arrays in this text, and costs ten
+    times the 32-bit one on the chip: PERF.md section 5). Shapes:
+    SF0.001, where the compiler takes the program's sorts in seconds
+    (15 s the whole program; SF0.01: 1,160 s; SF0.05: 683 s)."""
+    import re
+
+    from tidb_tpu.storage.tpch_queries import Q
+
+    (prog, shapes, growths, mode), = _general_fragments(speck_tpch,
+                                                        Q["q3"][0], n_dev)
+    fn, args = _described_fragment(topo, prog, shapes, growths, mode, n_dev)
+    assert prog.n_join == 2
+    text = _compile(fn, *args).as_text()
+    assert fn.join_probes == ["merge", "merge"]
+    ops = _ops_by_scope(text, r"(join\d+)/join\.(\w+)")  # by (join, stage)
+    for j in ("join0", "join1"):
+        build, probe = ops[j, "build"], ops[j, "probe"]
+        assert [o for o, _ in build + probe].count("sort") == 1, (j, build)
+        assert "sort" in [o for o, _ in build]
+        for o, result in build + probe:
+            assert o not in ("while", "conditional", "gather"), (j, o)
+            if o == "scatter":
+                assert re.match(r"(s32|u32|pred)\[", result), (j, result)
+        assert "scatter" in [o for o, _ in probe]
+    if n_dev > 1:
+        assert "all-to-all" in text
 
 
 def _one_program_cold(catalog, prog, growths):
@@ -619,12 +678,7 @@ def _one_program_cold(catalog, prog, growths):
 
 @pytest.fixture(scope="module")
 def sf1_tpch():
-    from tidb_tpu.storage.catalog import Catalog
-    from tidb_tpu.storage.tpch import load_tpch
-
-    catalog = Catalog()
-    load_tpch(catalog, sf=1.0)
-    return catalog
+    return _tpch(1.0)
 
 
 @pytest.mark.slow

@@ -4,7 +4,9 @@ chose — because a bulk load records of every column what
 `scan_selectivity` needs, so that nobody guesses 0.25 a filter — with the
 sqlite oracle's rows on one part and on four; each join's ops under its
 own scope; FRAGMENT_JOINS counting the joins a launch holds by probe
-path; and no second plan variant of a statement that is one program."""
+path; no second plan variant of a statement that is one program; and
+(PR 33) each join ranking its probe slots by ONE merged sort of both
+sides, to the bit what the searched and the table paths give."""
 
 import re
 
@@ -69,6 +71,14 @@ def delta(counter, before: dict) -> dict:
             if n - before.get(k, 0)}
 
 
+def joins_by_probe() -> dict:
+    """FRAGMENT_JOINS summed over the fragment kinds, by probe path."""
+    out = {}
+    for labels, n in FRAGMENT_JOINS.samples():
+        out[labels["probe"]] = out.get(labels["probe"], 0) + n
+    return out
+
+
 def run_spied(s: Session, sql: str) -> tuple:
     """(rows, [(program, arguments, growths in, growths out)]) of every
     `_dispatch_retry` the statement made."""
@@ -126,14 +136,22 @@ def test_q3_on_four_parts_gives_the_same_rows(devices8, tiny_tpch):
 
 
 def test_a_launch_counts_its_joins_by_probe_path(devices8, tiny_tpch):
-    """FRAGMENT_JOINS{kind, probe}: Q3's program holds two joins; on the
-    CPU both probe by binary search (the table is the accelerator's); a
-    fragment without a join adds nothing."""
+    """FRAGMENT_JOINS{kind, probe}: Q3's program holds two joins; under
+    the default both rank by the merged sort, on every platform and at
+    either build size; forced, `off` searches and `xla` probes the table
+    (customer's build is under its half load, join0's result here too);
+    a fragment without a join adds nothing."""
     catalog, _ = tiny_tpch
+    for mode, path in (("off", "search"), ("xla", "table")):
+        s = session(catalog, devices8, 1)
+        s.execute(f"set tidb_tpu_join_probe_mode = '{mode}'")
+        j0 = by_labels(FRAGMENT_JOINS)
+        s.query(Q3.format(**PARAMS[0]))
+        assert delta(FRAGMENT_JOINS, j0) == {(path,): 2}
     s = session(catalog, devices8, 1)
     j0 = by_labels(FRAGMENT_JOINS)
     _rows, seen = run_spied(s, Q3.format(**PARAMS[0]))
-    assert delta(FRAGMENT_JOINS, j0) == {("search",): 2}
+    assert delta(FRAGMENT_JOINS, j0) == {("merge",): 2}
     j0 = by_labels(FRAGMENT_JOINS)
     s.query(Q3.format(**PARAMS[0]))  # the program comes from the cache, its joins with it
     assert sum(delta(FRAGMENT_JOINS, j0).values()) == 2
@@ -189,6 +207,138 @@ def test_the_build_sort_orders_live_before_dead_and_rows_by_number(seed):
     assert order.dtype == jnp.int32 and np.array_equal(np.asarray(order), want)
     assert np.array_equal(np.asarray(sh), h[want])
     assert np.array_equal(np.asarray(cvi), np.concatenate([[0], np.cumsum(live[want])]))
+
+
+I64 = np.iinfo(np.int64)
+
+
+def _rank_case(seed):
+    """(build hashes, live, probe hashes, ok): duplicates on both sides,
+    dead rows on both sides, hash 0 and both ends of int64."""
+    rng = np.random.default_rng(seed)
+    nb, n_p = int(rng.integers(2, 6000)), int(rng.integers(2, 9000))
+    pool = np.concatenate([rng.integers(-40, 40, 60) * (1 << 40),
+                           [0, I64.min, I64.max]])
+    return (rng.choice(pool, nb), rng.random(nb) < 0.7,
+            rng.choice(pool, n_p), rng.random(n_p) < 0.8)
+
+
+def _one_build_slot(seed):
+    bh, bl, ph, pk = _rank_case(seed)
+    return bh[:1], np.array([seed % 2 == 0]), np.where(pk, bh[0], ph), pk
+
+
+def _dead_probe_side(seed):
+    bh, bl, ph, pk = _rank_case(seed)
+    return bh, bl, ph, np.zeros_like(pk)
+
+
+@pytest.mark.parametrize("case,seed", [
+    (_rank_case, 0), (_rank_case, 1), (_rank_case, 2), (_rank_case, 3),
+    (_one_build_slot, 4), (_one_build_slot, 5), (_dead_probe_side, 6)],
+    ids=lambda v: v.__name__.strip("_") if callable(v) else str(v))
+def test_the_merged_rank_is_the_searched_one_to_the_bit(case, seed):
+    """`merged_hash_ranges` against plain numpy AND against the pair it
+    stands in for (`sort_build_hashes` + the binary search): the build
+    rows of the merged order in their sequence ARE `order`; `cnt` is
+    equal at every probe slot; wherever it is not 0 the rows named are
+    the same in the same order (`start` is a place in the merged order
+    where `lo` is one in the build's, and means nothing under cnt 0)."""
+    import jax
+    import jax.numpy as jnp
+
+    from tidb_tpu.ops.join_kernels import (
+        merged_hash_ranges,
+        probe_hash_ranges,
+        sort_build_hashes,
+    )
+
+    bh, bl, ph, pk = case(seed)
+    dev = [jnp.asarray(a) for a in (bh, bl, ph, pk)]
+    start, cnt, slot = (np.asarray(a) for a in jax.jit(merged_hash_ranges)(*dev))
+    assert start.dtype == cnt.dtype == slot.dtype == np.int32
+    sh, cvi, order = sort_build_hashes(dev[0], dev[1])
+    lo, cnt_searched, path = probe_hash_ranges(sh, cvi, dev[2], dev[3], mode="off")
+    assert path == "search"
+    want_order = np.lexsort((np.arange(len(bh)), ~bl, bh))
+    assert np.array_equal(slot[slot < len(bh)], want_order)
+    assert np.array_equal(np.asarray(order), want_order)
+    want_cnt = np.array([np.sum(bl & (bh == h)) if ok else 0 for h, ok in zip(ph, pk)])
+    assert np.array_equal(cnt, want_cnt) and np.array_equal(np.asarray(cnt_searched), want_cnt)
+    if case is _rank_case:
+        assert (want_cnt > 1).any() and (want_cnt == 0).any()
+    lo = np.asarray(lo)
+    for j in np.nonzero(cnt)[0]:
+        rows = slot[start[j]:start[j] + cnt[j]]
+        assert np.array_equal(rows, np.nonzero(bl & (bh == ph[j]))[0])
+        assert np.array_equal(rows, want_order[lo[j]:lo[j] + cnt[j]])
+
+
+def test_the_merged_rank_refuses_a_shard_of_2_30_slots_when_traced():
+    """The 2-bit tag sits above a 30-bit slot number in the sort's second
+    key: one slot more and the fragment is refused before anything runs
+    (shapes only here; one v5e holds no such shard)."""
+    import jax
+
+    from tidb_tpu.ops.join_kernels import merged_hash_ranges
+
+    def shapes(n_build, n_probe):
+        sds = jax.ShapeDtypeStruct
+        return jax.eval_shape(
+            merged_hash_ranges, sds((n_build,), np.int64), sds((n_build,), np.bool_),
+            sds((n_probe,), np.int64), sds((n_probe,), np.bool_))
+
+    start, cnt, slot = shapes(1 << 29, (1 << 29) - 1)
+    assert start.shape == cnt.shape == ((1 << 29) - 1,) and slot.shape == ((1 << 30) - 1,)
+    with pytest.raises(ValueError, match="1073741824 build\\+probe slots"):
+        shapes(1 << 29, 1 << 29)
+
+
+# one statement of each kind of join the general fragment compiles, the
+# build side a scan under a filter
+JOINS = {
+    "inner_q3": Q3.format(**PARAMS[0]),
+    "left": "select o_orderpriority, count(l_orderkey), count(*) from orders "
+            "left join lineitem on o_orderkey = l_orderkey and l_quantity > 45 "
+            "group by o_orderpriority order by o_orderpriority",
+    "semi": "select o_orderpriority, count(*) from orders where exists "
+            "(select 1 from lineitem where l_orderkey = o_orderkey and l_quantity > 49) "
+            "group by o_orderpriority order by o_orderpriority",
+    "anti": "select o_orderpriority, count(*) from orders where not exists "
+            "(select 1 from lineitem where l_orderkey = o_orderkey and l_quantity > 10) "
+            "group by o_orderpriority order by o_orderpriority",
+    "not_in": "select o_orderpriority, count(*) from orders where o_custkey not in "
+              "(select c_custkey from customer where c_acctbal > 0) "
+              "group by o_orderpriority order by o_orderpriority",
+}
+
+
+@pytest.mark.parametrize("n_parts", [1, 8])
+@pytest.mark.parametrize("kind", sorted(JOINS))
+def test_every_kind_of_join_gives_the_same_rows_under_each_probe_mode(
+        devices8, tiny_tpch, kind, n_parts):
+    """The default ranks by the merged sort; `off` (the binary search) and
+    `xla` (the forced table) are the in-program references. All three
+    read the same `lo` and `cnt`, so an inner, a left, a semi, an anti and
+    a NOT IN join give the oracle's rows under each, on one part and
+    after the exchange on eight (the merged rank then takes the received
+    slots, dead ones included)."""
+    catalog, oracle = tiny_tpch
+    sql = JOINS[kind]
+    rows, probes = {}, {}
+    for mode in ("auto", "off", "xla"):
+        s = session(catalog, devices8, n_parts)
+        s.execute(f"set tidb_tpu_join_probe_mode = '{mode}'")
+        j0 = joins_by_probe()
+        rows[mode], seen = run_spied(s, sql)
+        assert seen and all(prog.n_join >= 1 for prog, *_ in seen), kind
+        probes[mode] = {p for p, n in joins_by_probe().items() if n != j0.get(p, 0)}
+    assert probes["auto"] == {"merge"} and probes["off"] == {"search"}
+    assert "merge" not in probes["xla"]
+    assert rows["auto"] == rows["off"] == rows["xla"]
+    ok, msg = rows_equal(rows["auto"], oracle.execute(sql.replace("date '", "'")).fetchall(),
+                         ordered=True)
+    assert ok, msg
 
 
 @pytest.mark.parametrize("a,b,base", [

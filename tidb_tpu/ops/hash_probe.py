@@ -1,22 +1,26 @@
 """Open-addressing hash probe for the device joins.
 
-The host-tier join (executor/join.py) and the general mesh fragment's
-join (parallel/fragment.py) sort their build side and, pre-ISSUE 10,
-probed with two `jnp.searchsorted` calls — O(log Rb) dependent gather
-rounds per probe element, hostile to TPU (each round is an HBM gather
-the next round depends on). The reference's hash join probes an
-O(1)-expected hash table instead (ref: executor/'s HashJoinExec build+probe workers;
+The host-tier join (executor/join.py) sorts its build side and,
+pre-ISSUE 10, probed with two `jnp.searchsorted` calls — O(log Rb)
+dependent gather rounds per probe element, hostile to TPU (each round is
+an HBM gather the next round depends on). The reference's hash join
+probes an O(1)-expected hash table instead (ref: executor/'s HashJoinExec build+probe workers;
 SURVEY.md:294-296 names this kernel as the planned fast path). This
-module supplies that table, consumed two ways: the fragment join
-(parallel/fragment.py) builds + probes it inside one shard_map program
-via `probe_for_join`, and the main single-chip join (ISSUE 10) builds
+module supplies that table: the main single-chip join (ISSUE 10) builds
 it ONCE per join build (ops/join_kernels.build_hash_table) and probes
-it per chunk with the table arrays as kernel args. Strategy selection:
-`tidb_tpu_join_probe_mode` (off/auto/xla) through `resolve_mode` —
-auto picks the table exactly when the computation targets TPU. The
-mesh tier's unique-key join under a segment aggregate
-(parallel/distsql.py `_local_join`) never came through here: it ranks
-both sides by one sort (PR 26), with no table and no mode.
+it per chunk with the table arrays as kernel args. Strategy selection
+there: `tidb_tpu_join_probe_mode` (off/auto/xla) through `resolve_mode`
+— auto picks the table exactly when the computation targets TPU.
+
+The mesh tier does not come through here by default. Its unique-key
+join under a segment aggregate (parallel/distsql.py `_local_join`) ranks
+both sides by one sort (PR 26), with no table and no mode; the general
+fragment's join (parallel/fragment.py) does the same under `auto` since
+PR 33 (ops/join_kernels.merged_hash_ranges: the chip put the table probe
+at 1.6 s for 1.5M probes and the search at 5.1 s for 3.1M, the merged
+rank at 0.08 s for both) and builds + probes this table inside its
+shard_map program via `probe_for_join` only where the statement forces
+`xla` (`off`: the search) — the references its tests compare with.
 
   * BUILD (XLA, inside the same jit): runs of equal values in the sorted
     hash array become (lo, hi) ranges; each run's FIRST row inserts
@@ -53,8 +57,10 @@ __all__ = ["probe_ranges", "xla_probe_ranges", "probe_for_join",
 # "off": always searchsorted; "auto" (default): hash table when the
 # computation targets TPU (trace-time force_platform aware, like
 # segment_sum), searchsorted elsewhere; "xla": hash table everywhere.
-# Which of the two is faster on either platform is not measured on the
-# chip (no cell reaches this module: ROADMAP D3).
+# (What "auto" means in the general fragment's join, which does not come
+# here under it: the comment above tidb_tpu_join_probe_mode in
+# session/sysvars.py.) Which of table and search is faster in the host
+# tier's join is not measured on the chip (no cell reaches it: ROADMAP D3).
 # Sessions thread tidb_tpu_join_probe_mode PER STATEMENT through
 # ExecContext/fragment args (ISSUE 12 — the old per-statement set_mode
 # write raced concurrent sessions); this global is only the default
@@ -92,7 +98,8 @@ def resolve_mode(mode: str = None) -> str:
 
 def probe_for_join(sorted_hashes: jax.Array, probes: jax.Array,
                    mode: str = None):
-    """The fragment join's probe entry point: (lo, hi, path) — the
+    """The fragment join's forced probes (`off`, `xla`; `auto` ranks by
+    the merged sort and never calls this): (lo, hi, path) — the
     ranges over the sorted build hashes via the configured strategy, and
     which of the two paths was traced for them (static): "table", the
     open-addressing table (which still falls back inside the program if

@@ -40,10 +40,18 @@ Python body only runs while jax traces, so ``JOIN_COMPILE_TOTAL`` counts
 real XLA (re)compilations, not dispatches. The retrace-guard test and
 EXPLAIN ANALYZE's per-operator ``recompiles:`` field both read it.
 
-``parallel/fragment.py``'s all_to_all repartition join reuses the same
-primitives (``sort_build_hashes``, ``probe_hash_ranges``,
-``tile_positions``) inside its shard_map trace, so local and distributed
-joins share one definition of the sort/probe/expand arithmetic.
+``parallel/fragment.py``'s general fragment join traces the fragment-tier
+primitives at the end of this file inside its shard_map program and
+expands through the same ``tile_positions`` as the local executor, so
+the two tiers share one definition of the expand arithmetic. How a
+probe slot learns its run of equal build hashes there: by default
+``merged_hash_ranges``, ONE sort of both sides together and two running
+maxima (PR 33: no search, no table; on the chip 80 ms of a Q3 statement
+where the searched and table probes took 6.7 s); under
+tidb_tpu_join_probe_mode ``off`` / ``xla`` the pair it replaced,
+``sort_build_hashes`` + ``probe_hash_ranges`` (the build's own sort,
+then the binary search or the open-addressing table), kept as the
+in-program references its tests compare it with.
 """
 
 from __future__ import annotations
@@ -63,7 +71,8 @@ __all__ = [
     "shape_bucket", "as_int64_key", "hash_combine_device", "pack_keys",
     "build_sort", "build_hash_table", "no_table", "probe_count",
     "probe_ranges_any", "expand_tiles",
-    "sort_build_hashes", "probe_hash_ranges", "tile_positions",
+    "merged_hash_ranges", "sort_build_hashes", "probe_hash_ranges",
+    "tile_positions",
 ]
 
 I64_MAX = np.iinfo(np.int64).max
@@ -448,11 +457,82 @@ def expand_tiles(start, count, real_count, cum, w0,
 
 # -- fragment-tier primitives (traced inside shard_map) ---------------------
 
+_SLOT_BITS = 30  # slot number bits under the 2-bit tag in the sort's uint32 second key
+
+
+def merged_hash_ranges(b_hash, b_live, p_hash, p_ok):
+    """The fragment join's default rank: ONE sort of both sides' hashes
+    together, and every probe slot's run of equal live build hashes read
+    off the merged order by two running maxima. Returns (start, cnt,
+    slot), `start` and `cnt` int32 a probe slot, `slot` int32 a merged
+    place: the slot numbers in merged order, a build row's its row
+    number, probe slot j's Rb + j. Probe slot j matches the build rows
+    ``slot[start[j] : start[j] + cnt[j]]``, the live rows of its hash in
+    the order of their row numbers; `cnt` is 0 for a dead probe slot and
+    where nothing matches, and `start` then means nothing. The build
+    rows of `slot`, in their sequence, are `sort_build_hashes`'
+    permutation (by hash, live before dead, then by row number), so the
+    matches and their order are the searched and the table paths' to the
+    bit.
+
+    No search, no table, no gather: on a v5e a sort of N slots costs less
+    than one round of a binary search over N slots, and a search is 21
+    rounds left and 21 right (PERF.md section 6, PRs 26, 32, 33).
+
+    Validity is a sort key and never an in-band sentinel (`_local_join`'s
+    rule): a 2-bit tag above the slot number, 0 a live build row, 1 a
+    dead one, 2 a live probe slot, 3 a dead one. Within a run of equal
+    hashes the live build rows therefore come first and are contiguous
+    from the run's head; every operand is a key and the order is total,
+    so the sort need not be stable (what that saves the chip's compiler:
+    `sort_build_hashes`). What goes back to probe-slot order is 32 bits
+    wide and written once a slot (a 64-bit scatter costs ten times a
+    32-bit one here, PERF.md section 5).
+
+    Limit: build plus probe slots of one shard stay under 2**30; more
+    raises when the fragment is traced, before anything runs."""
+    Rb, Rp = b_hash.shape[0], p_hash.shape[0]
+    n = Rb + Rp
+    if n >= 1 << _SLOT_BITS:
+        raise ValueError(
+            f"fragment join: {n} build+probe slots on one shard, "
+            f"limit {(1 << _SLOT_BITS) - 1}; use more shards")
+    # scoped as the searched path's two steps are (parallel/fragment.py):
+    # the sort is this join's build, the rest its probe
+    with jax.named_scope("join.build"):
+        tag = jnp.concatenate([jnp.where(b_live, 0, 1),
+                               jnp.where(p_ok, 2, 3)]).astype(jnp.uint32)
+        sh, st = jax.lax.sort(
+            (jnp.concatenate([b_hash, p_hash]),
+             (tag << _SLOT_BITS) | jnp.arange(n, dtype=jnp.uint32)),
+            num_keys=2, is_stable=False)
+    with jax.named_scope("join.probe"):
+        tag = st >> _SLOT_BITS
+        slot = (st & jnp.uint32((1 << _SLOT_BITS) - 1)).astype(jnp.int32)
+        pos = jnp.arange(n, dtype=jnp.int32)
+        head = jnp.concatenate(
+            [jnp.ones(1, dtype=jnp.bool_), sh[1:] != sh[:-1]])
+        # places only grow, so a running maximum carries the nearest one
+        # at or before each row: its run's head, and the last live build
+        # row
+        start = prefix.cummax(jnp.where(head, pos, 0))
+        last_live = prefix.cummax(jnp.where(tag == 0, pos, -1))
+        cnt = jnp.where(tag == 2, jnp.maximum(last_live - start + 1, 0), 0)
+        # back to probe-slot order: the build side's own slots come first
+        # in the numbering and fall outside, dropped
+        at = jnp.where(tag >= 2, slot - Rb, Rp)
+        start, cnt = (jnp.zeros(Rp, dtype=jnp.int32).at[at].set(v, mode="drop")
+                      for v in (start, cnt))
+    return start, cnt, slot
+
+
 def sort_build_hashes(b_hash, b_live):
-    """Sorted-run build for the repartitioned fragment join: (sorted
-    hashes, cvi, order) where dead rows sort after live rows of the same
-    hash and ``cvi[i]`` counts live rows in the sorted prefix [0, i) —
-    so (cvi[hi] - cvi[lo]) is an exact live-match count per range."""
+    """Sorted-run build for the fragment join's searched and table
+    probes (tidb_tpu_join_probe_mode off / xla; the default ranks by
+    `merged_hash_ranges`): (sorted hashes, cvi, order) where dead rows
+    sort after live rows of the same hash and ``cvi[i]`` counts live
+    rows in the sorted prefix [0, i) — so (cvi[hi] - cvi[lo]) is an
+    exact live-match count per range."""
     Rb = b_hash.shape[0]
     if Rb >= 1 << 31:
         raise ValueError(f"a build side of {Rb} slots a shard")

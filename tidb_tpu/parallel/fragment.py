@@ -550,30 +550,48 @@ class _Compiler:
             Rp = pch2.capacity
             Rb = bch2.capacity
 
-            # local sorted-range join on the hash, through the SAME
-            # fused primitives the single-chip executor runs
-            # (ops/join_kernels): sorted build runs with dead rows
-            # sorted after live ones, probe via the configured strategy
-            # (ops/hash_probe's open-addressing VMEM table on TPU,
-            # searchsorted elsewhere), and scatter+prefix-sum expansion
+            # local join on the hash, through the SAME expansion
+            # arithmetic the single-chip executor runs (ops/join_kernels
+            # tile_positions). How a probe slot learns its run of equal
+            # live build hashes is the statement's
+            # tidb_tpu_join_probe_mode: by default ONE sort of both
+            # sides together (merged_hash_ranges: no search, no table,
+            # on every platform and at every build size); "off" and
+            # "xla" keep the build's own sort with a binary search or
+            # the open-addressing table over it, the references the
+            # merged rank is tested against
+            from tidb_tpu.ops import hash_probe
             from tidb_tpu.ops.join_kernels import (
+                merged_hash_ranges,
                 probe_hash_ranges,
                 sort_build_hashes,
                 tile_positions,
             )
 
-            with jax.named_scope("join.build"):
-                b_live = bch2.sel & b_kvalid2
-                sh, cvi, order = sort_build_hashes(b_hash2, b_live)
-            with jax.named_scope("join.probe"):
-                p_ok = pch2.sel & p_kvalid2
-                # probe strategy threaded per-statement via build_fn (the
-                # module-global read raced concurrent sessions, ISSUE 12)
-                lo, cnt, path = probe_hash_ranges(
-                    sh, cvi, p_hash2, p_ok, mode=env.get("probe_mode"))
-                env["joins"].append(path)
+            b_live = bch2.sel & b_kvalid2
+            p_ok = pch2.sel & p_kvalid2
+            # trace-time static, threaded per statement via build_fn (the
+            # module-global read raced concurrent sessions, ISSUE 12)
+            mode = env.get("probe_mode") or hash_probe._mode
+            if mode == "auto":
+                # the one sort is the build's too: `order` is the merged
+                # order itself and `lo` a place in it (the primitive
+                # scopes its sort join.build and the rest join.probe)
+                lo, cnt, order = merged_hash_ranges(
+                    b_hash2, b_live, p_hash2, p_ok)
+                path = "merge"
+            else:
+                with jax.named_scope("join.build"):
+                    sh, cvi, order = sort_build_hashes(b_hash2, b_live)
+                with jax.named_scope("join.probe"):
+                    lo, cnt, path = probe_hash_ranges(
+                        sh, cvi, p_hash2, p_ok, mode=mode)
+            env["joins"].append(path)
 
             with jax.named_scope("join.expand"):
+                # 64 bits from here (the merged rank hands back 32): a
+                # many-to-many join's total may pass 2^31
+                lo, cnt = lo.astype(jnp.int64), cnt.astype(jnp.int64)
                 cum = prefix.cumsum(cnt)
                 total = cum[-1]
                 growth_j = growths[g_expand]
@@ -584,8 +602,10 @@ class _Compiler:
                     (g_expand, pmax(jnp.maximum(factor - 1, 0), _AXES)))
 
                 valid_out, p_row, b_sorted_pos, k = tile_positions(
-                    lo, cnt, cum, 0, capJ, Rp, Rb)
-                b_row = order[b_sorted_pos]
+                    lo, cnt, cum, 0, capJ, Rp, order.shape[0])
+                # (a slot past the output's end may read a probe slot's
+                # number off the merged order: kept inside the build side)
+                b_row = jnp.minimum(order[b_sorted_pos], Rb - 1)
 
             with jax.named_scope("join.gather"):
                 sel_out = valid_out

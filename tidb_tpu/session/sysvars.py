@@ -113,10 +113,15 @@ _reg(
            min_=1, max_=64),
     # join probe strategy (ISSUE 10): how probe chunks resolve (lo, hi)
     # match ranges over the sorted build keys. off = searchsorted always;
-    # auto = open-addressing hash table when the computation targets TPU
-    # (trace-time force_platform aware, like segment_sum), searchsorted
-    # on CPU; xla forces the table everywhere (window-scan probe). Dense
-    # packed-key domains keep the O(1) direct-address index regardless.
+    # xla forces the open-addressing hash table everywhere (window-scan
+    # probe); auto, per tier: the host tier's join takes the table when
+    # the computation targets TPU (trace-time force_platform aware, like
+    # segment_sum) and searchsorted on CPU, dense packed-key domains
+    # keeping the O(1) direct-address index regardless; the general
+    # fragment's join (parallel/fragment.py) takes neither: it ranks
+    # probe slots by ONE merged sort of both sides, on every platform
+    # and at every build size (PR 33), and off / xla are there the
+    # references its tests compare with.
     # Threaded per-statement through ExecContext into BOTH tiers
     # (fragment programs take it as a trace-time static in their cache
     # key) — the hash_probe process global is only the default of bare

@@ -461,10 +461,12 @@ FRAGMENT_JOINS = Counter(
     "tidb_tpu_fragment_joins_total",
     "Joins compiled into the general fragment programs launched "
     "(parallel/fragment.py _join_producer), by fragment kind and by the "
-    "probe path each takes, static per program: probe=table, the "
-    "open-addressing table of ops/hash_probe.py; probe=search, the binary "
-    "search by gathers over the sorted build hashes (the sorted strategy, "
-    "or a build side past the table's half load). A launch adds its "
+    "probe path each takes, static per program: probe=merge, one sort of "
+    "both sides together (ops/join_kernels.py merged_hash_ranges; the "
+    "default tidb_tpu_join_probe_mode); forced, probe=table, the "
+    "open-addressing table of ops/hash_probe.py (xla), and probe=search, "
+    "the binary search by gathers over the sorted build hashes (off, or a "
+    "build side past the table's half load under xla). A launch adds its "
     "program's joins (TPC-H Q3: 2); a fragment without a join adds nothing")
 FRAGMENT_RETRY_TOTAL = Counter(
     "tidb_tpu_fragment_retry_total",
