@@ -21,9 +21,13 @@ launched once (its filters are estimated from what the bulk load
 recorded of orders and lineitem, so no compaction buffer overflows), a
 transaction (insert, aggregate,
 ORDER BY LIMIT) on the fused segment-store tier with read-back on the
-other connection, and a point get. The whole of TPC-H Q18 is NOT
-covered: its two general fragments hold 6 sorts on one chip (ROADMAP S3;
-tests/test_chip_compile.py pins it).
+other connection, and a point get. The whole of TPC-H Q18 is NOT in
+this list: since PR 35 it is ONE general fragment on one chip (the
+IN-subquery's GROUP BY and HAVING compiled into the program) that holds
+6 sorts, over the 4 a statement of this smoke may hold
+(tests/test_chip_compile.py pins it); what it costs on the chip is the
+benchmark's to say (cell tpch_sf1_power.q18; cold:
+``scripts/q3_first_answer.py --statement q18``; ROADMAP S3).
 
 One process. The server is booted through ``tidb_tpu.__main__.boot`` with
 the default configuration (``--mesh auto``, status port on) and TPC-H SF1

@@ -1,16 +1,16 @@
 #!/usr/bin/env python3
-"""What a cold TPC-H Q3 costs on the device this process finds (ROADMAP
-S3's question to the chip): boots as the benchmark's cell
-``tpch_sf1_power.q3`` does, sends Q3 on a fresh connection and prints one
-JSON line a phase —
+"""What a cold TPC-H Q3 (or, ``--statement q18``, Q18) costs on the
+device this process finds (ROADMAP S3's question to the chip): boots as
+the benchmark's cells ``tpch_sf1_power.q3`` / ``.q18`` do, sends the
+statement on a fresh connection and prints one JSON line a phase —
 
-    python3 scripts/q3_first_answer.py [--root TREE] [--sf 1.0] [--seed N]
+    python3 scripts/q3_first_answer.py [--statement q18] [--root TREE] [--sf 1.0] [--seed N]
 
 seconds to the first answer, backend compiles and their seconds,
 launches (FRAGMENT_DISPATCH) and retries by knob (FRAGMENT_RETRY_TOTAL),
 the capacity growths the connection ended on, peak device memory, then
 the warm statement's latency over ``--repeats``; every answer is compared
-with ``benchmarks/statements/q3.py``'s numpy reference. ``--root`` names
+with the statement's numpy reference (``benchmarks/statements/``). ``--root`` names
 the checkout whose program and harness are imported (a ``git archive`` of
 another commit); the statement's text and reference are this checkout's.
 ``--cpu`` asks the CPU for the device engine (a rehearsal: counts, no
@@ -32,6 +32,9 @@ import time
 
 T0 = time.time()
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# each statement's validation values (clauses 2.4.3.4, 2.4.18.4)
+PARAMS = {"q3": [{"segment": "BUILDING", "date": "1995-03-15"}],
+          "q18": [{"quantity": 300}]}
 
 
 def emit(**kw) -> None:
@@ -45,7 +48,8 @@ def program_counters() -> dict:
 
     out = {}
     for name in ("FRAGMENT_DISPATCH", "FRAGMENT_RETRY_TOTAL",
-                 "FRAGMENT_JOINS", "FRAGMENT_EXCHANGE_STEPS",
+                 "FRAGMENT_JOINS", "FRAGMENT_SUBQUERIES",
+                 "FRAGMENT_EXCHANGE_STEPS",
                  "FRAGMENT_REDUCE_PAYLOADS", "FRAGMENT_COMPILE"):
         c = getattr(metrics, name, None)
         if c is None:
@@ -78,7 +82,8 @@ def spy_on_launches() -> list:
     return seen
 
 
-def dump_hlo(out_dir: str, params: dict, prog, fn_args, growths, probe_mode) -> None:
+def dump_hlo(out_dir: str, name: str, params: dict, prog, fn_args, growths,
+             probe_mode) -> None:
     """The program the statement just ran, compiled again (the compile
     cache has it) and written as text."""
     import jax
@@ -91,7 +96,8 @@ def dump_hlo(out_dir: str, params: dict, prog, fn_args, growths, probe_mode) -> 
         text = prog.build_fn(growths, probe_mode=probe_mode).lower(
             *fn_args).compile().as_text()
     os.makedirs(out_dir, exist_ok=True)
-    path = os.path.join(out_dir, f"q3_{params['segment']}_{params['date']}.hlo.txt.gz")
+    path = os.path.join(out_dir, "_".join(
+        [name] + [str(v) for v in params.values()]) + ".hlo.txt.gz")
     with gzip.open(path, "wt") as f:
         f.write(text)
     emit(phase="hlo", path=path, chars=len(text), sorts=text.count(" sort("),
@@ -109,24 +115,27 @@ def main(argv=None) -> int:
     ap.add_argument("--pre", default="[]",
                     help="JSON list of SET statements sent first on every connection")
     ap.add_argument("--dump-hlo", default=None, metavar="DIR")
-    ap.add_argument("--params", default=json.dumps(
-        [{"segment": "BUILDING", "date": "1995-03-15"}]))
+    ap.add_argument("--statement", default="q3", choices=sorted(PARAMS))
+    ap.add_argument("--params", default=None,
+                    help="JSON list of parameter sets (default: the "
+                         "statement's validation values)")
     args = ap.parse_args(argv)
     sys.path.insert(0, os.path.abspath(args.root))
 
     from benchmarks import reference, system, tpch_datagen
 
     spec = importlib.util.spec_from_file_location(
-        "q3_statement", os.path.join(HERE, "benchmarks", "statements", "q3.py"))
-    q3 = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(q3)
+        "statement", os.path.join(HERE, "benchmarks", "statements",
+                                  args.statement + ".py"))
+    stmt = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(stmt)
 
     device, devs = system.device()
     emit(phase="device", root=os.path.abspath(args.root), **device)
     counters = system.Counters()
     tables = tpch_datagen.generate(args.sf, args.seed)
     data = reference.Data(tables)
-    emit(phase="generate", rows={n: data.rows(n) for n in q3.TABLES})
+    emit(phase="generate", rows={n: data.rows(n) for n in stmt.TABLES})
     server = system.start_server(tables, tpch_datagen.PRIMARY_KEYS, {})
     emit(phase="load", mesh=str(dict(server.mesh.shape)))
     launched = spy_on_launches() if args.dump_hlo else []
@@ -135,10 +144,10 @@ def main(argv=None) -> int:
         pre += ("set tidb_device_engine_mode = 'force'",)
     ok = True
     try:
-        for p in json.loads(args.params):
+        for p in json.loads(args.params) if args.params else PARAMS[args.statement]:
             # a fresh connection: its own ShardCache, its own growths
             client = system.connect(server, args.timeout, pre)
-            text, want = q3.sql(p), q3.reference(data, p)
+            text, want = stmt.sql(p), stmt.reference(data, p)
             c0, f0 = counters.read(), program_counters()
             t = time.perf_counter()
             try:
@@ -161,7 +170,7 @@ def main(argv=None) -> int:
                  program=delta(f0, program_counters()), growths=growths,
                  memory=system.memory(devs))
             if launched:
-                dump_hlo(args.dump_hlo, p, *launched[-1])
+                dump_hlo(args.dump_hlo, args.statement, p, *launched[-1])
             c1, f1 = counters.read(), program_counters()
             warm = []
             for _ in range(args.repeats):

@@ -572,14 +572,27 @@ SORT_BUDGET = 4
 
 
 _S3 = pytest.mark.xfail(strict=True, reason="ROADMAP S3: on four chips "
-                        "Q3's general fragment holds 10 sorts; Q18's two "
-                        "hold 6 on one chip (3 and 11 on four); their "
-                        "first execution outlasts the smoke's 1200 s")
+                        "Q3's general fragment holds 10 sorts; its first "
+                        "execution outlasts the smoke's 1200 s")
+# Q18 whole is ONE program on one chip since PR 35 (the IN-subquery's
+# GROUP BY and HAVING compiled in): 6 sorts traced — the eager partial,
+# the subquery's sort-reduce (the compiler shares that sort with the
+# eager partial's: lineitem is one argument), three joins' merged ranks,
+# the root aggregate. Over the budget, yet the chip compiled it in
+# 141.6 s (PR 35, PERF.md section 6; 249.9 s here, for a described v5e)
+# where Q3's 4 sorts take 384 s: the count of sorts is the smoke's
+# guard; the price is in the operands of each (with the root's five keys
+# as tie-breaks, 14 operands, the same program took 1,685.9 s here and
+# gave the chip's client no answer in 600 s: PERF.md section 7 (10)).
+_S3_Q18 = pytest.mark.xfail(strict=True, reason="ROADMAP S3: Q18's one "
+                            "general fragment holds 6 sorts on one chip, "
+                            "over SORT_BUDGET (cold on the chip: one "
+                            "compile of 141.6 s, PR 35)")
 
 
 @pytest.mark.parametrize("q,n_dev", [
     ("q18_inner", 1), ("q3", 1), pytest.param("q3", 4, marks=_S3),
-    pytest.param("q18", 1, marks=_S3)])
+    pytest.param("q18", 1, marks=_S3_Q18)])
 def test_general_fragment_fits_a_cold_statement(topo, tpu_target, tiny_tpch, q,
                                                 n_dev):
     """Strict: when S3 brings Q3's / Q18's programs under the budget,
@@ -593,7 +606,8 @@ def test_general_fragment_fits_a_cold_statement(topo, tpu_target, tiny_tpch, q,
     sorts: 702 s, twice); it is in the smoke's list. PR 33's merged rank
     keeps the count: the one sort of both sides takes the place of the
     build's own, join for join (cold on the chip: 384 s, 389 to the
-    answer), and the two strict xfails stay as they were (10 and 6)."""
+    answer), and the two strict xfails stay (10 and 6: since PR 35 Q18's
+    6 are ONE program's, counted here as `[6]`, no longer `[1, 5]`)."""
     from chip_smoke import Q18_INNER_SQL
     from tidb_tpu.storage.tpch_queries import Q
 
@@ -695,8 +709,9 @@ def test_general_fragment_compiles_at_sf1(topo, tpu_target, sf1_tpch, stmt,
     and the whole of Q3 and Q18: the two together were still compiling
     after 90 minutes in the sandbox (PR 22). Q3's program as PR 32
     leaves it (benchmark data, SF1 shapes) compiles here in 626 s, on
-    the chip in 383-405 (PERF.md section 6); whether the compiler
-    accepts Q18's is not known yet: ROADMAP S3. Not tier-1: run it with
+    the chip in 383-405 (PERF.md section 6); Q18's one program (PR 35)
+    compiles on the chip in 141.6 s and here, from the benchmark's data
+    without running the statement, in 249.9 s. Not tier-1: run it with
     -m slow."""
     from chip_smoke import Q18_INNER_SQL
     from tidb_tpu.storage.tpch_queries import Q

@@ -63,6 +63,14 @@ __all__ = ["make_partial_kernel", "make_merge_kernel", "GroupTableStack",
            "table_to_host_partial"]
 
 
+# A duplicate-free table of up to this many group keys comes from the
+# tie-break sort (`exact=True`: TPC-H Q3's three); of more, from the hash
+# order with its count of split groups (`exact="count"`: Q18's five) —
+# see `_sort_reduce`. Where the table's consumer can merge by exact key
+# (the fragment tier's root on one part: the host finalize).
+TIE_BREAK_KEYS = 3
+
+
 def _bits64(data: jax.Array, valid: jax.Array) -> jax.Array:
     """Group-identity bits: NULLs unify to 0, floats group by bit pattern."""
     if jnp.issubdtype(data.dtype, jnp.floating):
@@ -102,7 +110,7 @@ def _reduced_in_row_order(op: str, dtype) -> bool:
 
 def _sort_reduce(kdatas: List[jax.Array], kvalids: List[jax.Array],
                  live: jax.Array, payload: List[jax.Array],
-                 reduce_ops: List[str], exact: bool = False,
+                 reduce_ops: List[str], exact=False,
                  slots: int = None):
     """Shared core: sort rows by (dead, key identity: `_bits64`), find
     the runs of equal keys, reduce payload arrays into dense per-group
@@ -113,7 +121,15 @@ def _sort_reduce(kdatas: List[jax.Array], kvalids: List[jax.Array],
     hash collision can only SPLIT a group into two partial slots (never
     merge two groups) — consumers dedup by exact key at finalize (host
     _merge_partials), keeping results exact; `exact` adds the key bits
-    as tie-breaks, and no group is split.
+    as tie-breaks, and no group is split; `exact="count"` keeps the hash
+    order and also returns how many runs start INSIDE a block of equal
+    hashes (`splits`): at 0, which is what 64 bits make of millions of
+    groups, every block holds one key and the table is duplicate-free;
+    past 0 the consumer merges by exact key. The tie-break sort carries
+    two 32-bit operands a key through the sorting network, and its
+    compile grows with every one of them (the chip, PRs 32-35: a 3-key
+    aggregate's program 384 s, a 5-key one's past 1,000; counted, that
+    program takes a quarter of it): `TIE_BREAK_KEYS`.
 
     After the sort a group's rows are CONTIGUOUS, and the reduction uses
     that (PR 31). An integer sum is the difference of one running total
@@ -148,8 +164,9 @@ def _sort_reduce(kdatas: List[jax.Array], kvalids: List[jax.Array],
     test). Slots at and past `ngroups` hold zero / False (min / max:
     the identity).
 
-    Returns (ngroups, rep_kdatas, rep_kvalids, reduced_payloads) — all
-    slot arrays with groups dense in [0, ngroups)."""
+    Returns (ngroups, rep_kdatas, rep_kvalids, reduced_payloads, splits)
+    — all slot arrays with groups dense in [0, ngroups); `splits` is None
+    unless `exact == "count"`."""
     R = live.shape[0]
     S = R if slots is None else min(int(slots), R)
     nk = len(kdatas)
@@ -162,7 +179,7 @@ def _sort_reduce(kdatas: List[jax.Array], kvalids: List[jax.Array],
             out = jax.lax.sort(
                 (dead, kbits[0], kvalids[0].astype(jnp.int32), iota), num_keys=3)
             s_kbits, s_kvalids = [out[1]], [out[2] != 0]
-        elif exact:
+        elif exact is True:
             # hash first (cheap comparisons), exact bits as tie-breaks: equal
             # keys are guaranteed contiguous, so the output table can never
             # hold a collision-split duplicate — consumers may emit it
@@ -188,8 +205,11 @@ def _sort_reduce(kdatas: List[jax.Array], kvalids: List[jax.Array],
             s_kvalids = [(out[2 + nk] >> (nk - 1 - i)) & 1 != 0
                          for i in range(nk)]
         else:
+            # by the hash alone; the row number is the last KEY, so the
+            # sort need not be stable (the same permutation; see above)
             out = jax.lax.sort(
-                (dead, _group_hash(kbits, kvalids), iota), num_keys=2)
+                (dead, _group_hash(kbits, kvalids), iota), num_keys=3,
+                is_stable=False)
             s_kbits = s_kvalids = None
         perm = out[-1]
 
@@ -229,6 +249,16 @@ def _sort_reduce(kdatas: List[jax.Array], kvalids: List[jax.Array],
         end_row = jnp.zeros(S, dtype=jnp.int32).at[
             jnp.where(runend, seg, S)].set(iota, mode="drop")
         occupied = jnp.arange(S, dtype=jnp.int32) < seg[-1] + 1
+        splits = None
+        if exact == "count":
+            # one key sorts by its bits and the tie-break sort by all of
+            # them: neither splits. In hash order a run that starts where
+            # the hash does not change is two keys' collision
+            splits = jnp.zeros((), dtype=jnp.int64)
+            if nk > 1:
+                same_hash = out[1] == jnp.roll(out[1], 1)
+                splits = jnp.sum((newseg & (iota != 0) & same_hash)
+                                 .astype(jnp.int64))
 
     with jax.named_scope("reduce"):
         # one running total a payload; at a run's end it holds no dead
@@ -272,7 +302,7 @@ def _sort_reduce(kdatas: List[jax.Array], kvalids: List[jax.Array],
         rep_kdatas = [_from_bits64(jnp.where(occupied, b, 0), d.dtype)
                       for b, d in zip(ends[:nk], kdatas)]
         rep_kvalids = [occupied & (v != 0) for v in ends[nk:2 * nk]]
-    return ngroups, rep_kdatas, rep_kvalids, reduced
+    return ngroups, rep_kdatas, rep_kvalids, reduced, splits
 
 
 def _ident_min(dtype):
@@ -324,15 +354,17 @@ def reduce_paths(aggs: List[AggSpec]) -> List[str]:
 
 
 def make_partial_kernel(group_exprs, aggs: List[AggSpec],
-                        exact: bool = False):
+                        exact=False):
     """fn(chunk, slots=None) -> group table dict {"n", "k{i}.d",
     "k{i}.v", state...} of `slots` slots (static; default: the chunk's
     capacity; see _sort_reduce).
 
     `exact` (see _sort_reduce): the table holds every group once, also
     where several keys' mixed hashes collide — for a consumer that emits
-    the table as it is (the fragment tier on a mesh of one part). The
-    host executor merges its tables by exact key and leaves it off."""
+    the table as it is (the fragment tier on a mesh of one part); with
+    `exact="count"` the table is in hash order and says under "split"
+    how many runs a collision split (0: every group once). The host
+    executor merges its tables by exact key and leaves it off."""
     layout = _state_layout(aggs)
 
     def partial(chunk: Chunk, slots: int = None):
@@ -377,9 +409,11 @@ def make_partial_kernel(group_exprs, aggs: List[AggSpec],
                 payload.append(jnp.where(ok, d, _ident_max(dt)).astype(dt))
                 ops.append("max")
 
-        n, rk, rkv, red = _sort_reduce(kdatas, kvalids, sel, payload, ops,
-                                       exact=exact, slots=slots)
+        n, rk, rkv, red, splits = _sort_reduce(
+            kdatas, kvalids, sel, payload, ops, exact=exact, slots=slots)
         table = {"n": n}
+        if splits is not None:
+            table["split"] = splits
         for i in range(len(group_exprs)):
             table[f"k{i}.d"] = rk[i]
             table[f"k{i}.v"] = rkv[i]
@@ -405,7 +439,7 @@ def make_merge_kernel(nkeys: int, aggs: List[AggSpec]):
         kvalids = [cat(f"k{i}.v") for i in range(nkeys)]
         payload = [cat(name) for name, _ in layout]
         ops = [op for _, op in layout]
-        n, rk, rkv, red = _sort_reduce(kdatas, kvalids, live, payload, ops)
+        n, rk, rkv, red, _ = _sort_reduce(kdatas, kvalids, live, payload, ops)
         table = {"n": n}
         for i in range(nkeys):
             table[f"k{i}.d"] = rk[i]

@@ -58,7 +58,8 @@ __all__ = ["ShardCache", "build_dist_executor", "DistAggExec", "DistJoinAggExec"
 
 @contextlib.contextmanager
 def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
-                     reduced: Tuple[int, int] = (0, 0), joins=()):
+                     reduced: Tuple[int, int] = (0, 0), joins=(),
+                     subqueries: Tuple[int, int] = (0, 0)):
     """One fragment launch: the span ``fragment.<kind>[parts=N]`` on the
     statement's trace and the FRAGMENT_SECONDS collector for /metrics
     (with a trace_id exemplar). Wall time covers the launch plus any
@@ -72,7 +73,10 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
     by a segment op) (FRAGMENT_REDUCE_PAYLOADS; a generic aggregate's),
     and the joins a general fragment's program holds, `joins` = the probe
     path of each (FRAGMENT_JOINS; read after the launch: a program's
-    first call fills the list as it traces)."""
+    first call fills the list as it traces), and the subtrees it takes as
+    build sides that are no scans, `subqueries` = (compiled into the
+    program, answered through the host and broadcast)
+    (FRAGMENT_SUBQUERIES)."""
     from tidb_tpu.utils import tracing
     from tidb_tpu.utils.metrics import (
         FRAGMENT_DISPATCH,
@@ -80,6 +84,7 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
         FRAGMENT_JOINS,
         FRAGMENT_REDUCE_PAYLOADS,
         FRAGMENT_SECONDS,
+        FRAGMENT_SUBQUERIES,
     )
 
     t0 = time.perf_counter()
@@ -91,6 +96,8 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
         FRAGMENT_REDUCE_PAYLOADS.inc(n, kind=kind, path=path)
     for probe in joins:
         FRAGMENT_JOINS.inc(kind=kind, probe=probe)
+    for path, n in zip(("inline", "broadcast"), subqueries):
+        FRAGMENT_SUBQUERIES.inc(n, kind=kind, path=path)
     FRAGMENT_SECONDS.observe(time.perf_counter() - t0, kind=kind)
 
 
@@ -472,8 +479,13 @@ class DistFragmentExec(HashAggExec):
         used to be a hard error telling the user to flip a sysvar)."""
         args, shapes = [], []
         limit = getattr(self.ctx, "broadcast_rows_limit", BROADCAST_LIMIT)
+        from tidb_tpu.utils import tracing
+
         for bc in prog.broadcasts:
-            data, valid, sel, n = self._materialize_broadcast(bc)
+            # the host's statement-within-a-statement: the subtree's own
+            # launches, fetches and device.wait are this span's children
+            with tracing.span("fragment.broadcast"):
+                data, valid, sel, n = self._materialize_broadcast(bc)
             if n > limit:
                 raise _BroadcastTooLarge(n)
             args += [data, valid, sel]
@@ -496,6 +508,17 @@ class DistFragmentExec(HashAggExec):
                 S = len(arr) // n_parts
                 t[name] = arr[p * S:(p + 1) * S]
             yield p, t
+
+    def _host_partial(self, t, nk: int):
+        """One part's fetched group table as a host partial; merged by
+        exact key where the program counted groups that a collision of
+        two keys' hashes split (a table in hash order: `_sort_reduce`)."""
+        from tidb_tpu.executor.agg_device import table_to_host_partial
+
+        part = table_to_host_partial(t, nk, self.aggs)
+        if "split" in t and int(np.sum(t["split"])):
+            part = self._merge_partials([part])
+        return part
 
     def _materialize_broadcast(self, bc):
         """Run a non-scan subtree and return replicated (data, valid, sel)
@@ -539,9 +562,10 @@ class DistFragmentExec(HashAggExec):
 
     def _pick_stream_source(self, prog):
         """Index of the source to stream, or None. A table above the
-        device-cache budget streams in fixed [P, R] batches IF it
-        appears exactly once among the fragment's sources — a self-join
-        of a streamed table would pair only same-batch rows. Running
+        device-cache budget streams in fixed [P, R] batches IF the
+        statement reads it once — a self-join of a streamed table would
+        pair only same-batch rows (the compiler pins a source that two
+        scans share: `stream_unsafe`). Running
         the fragment per batch is otherwise sound: probe rows partition
         across batches (each contributes once), build/broadcast sides
         are identical every batch, and the agg outputs merge (segment:
@@ -555,11 +579,6 @@ class DistFragmentExec(HashAggExec):
             b = table_bytes(src.scan.table)
             if b > self.ctx.device_cache_bytes and b > best_bytes:
                 best, best_bytes = i, b
-        if best is None:
-            return None
-        t = prog.sources[best].scan.table
-        if sum(1 for s in prog.sources if s.scan.table is t) != 1:
-            return None  # self-join of the big table: no streaming
         return best
 
     def _run_fragment(self):
@@ -624,7 +643,8 @@ class DistFragmentExec(HashAggExec):
             fn = self._cache.get_fragment(
                 key, lambda: prog.build_fn(growths, probe_mode=probe_mode))
             with _fragment_launch(kind, n_parts, prog.n_exchange,
-                                  prog.n_reduce, fn.join_probes):
+                                  prog.n_reduce, fn.join_probes,
+                                  (prog.n_subquery, len(prog.broadcasts))):
                 out, ovf = fn(*args)
             # host-sync: the per-knob overflow vector (a few int64s)
             # gates the capacity-retry loop — one fetch per dispatch
@@ -658,7 +678,6 @@ class DistFragmentExec(HashAggExec):
         VERDICT round-2 item 4). Segment states merge on device across
         batches; generic group tables merge per-part on host (parts stay
         disjoint — the exchange routing is identical every batch)."""
-        from tidb_tpu.executor.agg_device import table_to_host_partial
         from tidb_tpu.executor.aggregate import merge_op_for
         from tidb_tpu.parallel.partition import stream_batches
 
@@ -739,8 +758,7 @@ class DistFragmentExec(HashAggExec):
                     n_parts_out = len(np.asarray(host["n"]).reshape(-1))
                     gen_parts = [[] for _ in range(n_parts_out)]
                 for pi, t in self._iter_host_parts(host):
-                    gen_parts[pi].append(
-                        table_to_host_partial(t, nk, self.aggs))
+                    gen_parts[pi].append(self._host_partial(t, nk))
         touch(self._cache.growth, gkey, growths, ShardCache.MAX_FRAGMENTS)
 
         if prog.out_kind == "segment":
@@ -798,8 +816,10 @@ class DistFragmentExec(HashAggExec):
         any cardinality (the 10^7-group host-merge hotspot the round-2
         review flagged). A mesh of one part exchanges nothing: its one
         reduce is the exact one, and its table is `capT` slots, not
-        `n_parts * cap` received ones."""
-        from tidb_tpu.executor.agg_device import table_to_host_partial
+        `n_parts * cap` received ones. Of more than TIE_BREAK_KEYS group
+        keys that table comes in hash order with "split", its count of
+        runs that a collision of two keys' hashes split: where it is not
+        0 the part is merged by exact key here."""
         from tidb_tpu.utils import tracing
 
         # the fetch is this span's device.wait child; its self time is
@@ -808,7 +828,7 @@ class DistFragmentExec(HashAggExec):
             host = dsp.device_get(out)
             nk = len(self.group_exprs)
             cap = self.ctx.chunk_capacity
-            partials = [table_to_host_partial(t, nk, self.aggs)
+            partials = [self._host_partial(t, nk)
                         for _p, t in self._iter_host_parts(host)]
             if not partials:
                 self._out = []  # no groups anywhere

@@ -15,7 +15,14 @@ coprocessor tiers, SURVEY.md §2 parallelism table) to:
   * build sides that aren't scans (subquery results, small dimension
     pipelines) materialize on the host and enter the fragment as
     REPLICATED broadcast inputs — the broadcast-join exchange — which
-    also skips repartitioning the probe side entirely
+    also skips repartitioning the probe side entirely. On a mesh of ONE
+    part an aggregate subquery (filters and projections — the HAVING —
+    over a generic GROUP BY over a scan or a join tree: TPC-H Q18's IN)
+    is no broadcast but a producer INSIDE the program
+    (_subquery_agg_producer): shard-local groups are the groups, so the
+    rows are reduced, filtered and compacted where the join reads them,
+    and nothing of the subquery's answer sizes anything. A table that
+    several scans of the statement read is ONE source of the program
   * both aggregation strategies at the root: segment (dense [G] states,
     psum/pmin/pmax merge) and generic (per-shard sort-based partial
     tables from executor/agg_device.py, hash-repartitioned by group key
@@ -30,7 +37,9 @@ of the reference's spill/split retry.
 On a mesh of ONE part nothing is exchanged (`n_parts` is a static int
 when the fragment is compiled): a join's sides go to the local join as
 they are, the generic aggregate's partial table — reduced exactly — is
-its final table, and neither adds an "exch" knob. FragmentProgram's
+its final table (of more than TIE_BREAK_KEYS group keys: in hash order
+with its count of groups that a hash collision split, which the host
+finalize merges where it is not 0), and neither adds an "exch" knob. FragmentProgram's
 `n_exchange` says how many repartitions a program holds, `n_reduce` how
 many payloads its sort-reduces sum in row order and how many by a
 segment op (agg_device._sort_reduce), `n_join` how many joins.
@@ -39,7 +48,9 @@ A join's device ops carry its number and stage in their `op_name`:
 ``join<j>/join.{compact,build,probe,expand,gather}``, j counting the
 joins in the order their ops run (a join's inputs before the join; the
 order of the growth knobs); an eager partial aggregate under a join
-sorts and reduces under ``agg.eager``.
+sorts and reduces under ``agg.eager``; an aggregate subquery compiled
+into the program under ``subq<k>/{agg.partial,having,compact}``, k
+counting them likewise (`n_subquery`).
 """
 
 from __future__ import annotations
@@ -55,6 +66,7 @@ from jax.sharding import PartitionSpec as P
 from tidb_tpu.chunk.chunk import Chunk
 from tidb_tpu.chunk.column import Column
 from tidb_tpu.executor.agg_device import (
+    TIE_BREAK_KEYS,
     _bits64,
     _sort_reduce,
     _state_layout,
@@ -153,9 +165,9 @@ def _normalize_red_limbs(red, layout, aggs):
 @dataclass
 class _Source:
     """A sharded scan input (4 fragment args: data, valid, sel, refs —
-    refs carries the FoR bases of encoded staged columns, {} raw)."""
+    refs carries the FoR bases of encoded staged columns, {} raw); the
+    first of the scans that read the table (`_scan_producer`)."""
     scan: PScan
-    stages: list
 
 
 @dataclass
@@ -177,6 +189,9 @@ class FragmentProgram:
     # row order, "scatter" by a segment op) — FRAGMENT_REDUCE_PAYLOADS
     n_reduce: Tuple[int, int]
     n_join: int                        # joins compiled in (_join_producer)
+    # aggregate subqueries compiled in (_subquery_agg_producer; the
+    # others are `broadcasts`) — FRAGMENT_SUBQUERIES
+    n_subquery: int
     sig: str
     # (growths tuple) -> per-shard program; its `join_probes` lists, once
     # traced, the probe path of each join ("table" | "search"), join0 first
@@ -222,6 +237,8 @@ class _Compiler:
         self.reduce_paths: List[str] = []
         # joins compiled into the program, numbered as their ops run
         self.n_join = 0
+        # aggregate subqueries compiled into the program, likewise
+        self.n_subquery = 0
 
     def _add_growth(self, default: float, kind: str) -> int:
         idx = self.n_growth
@@ -279,25 +296,33 @@ class _Compiler:
             p = self._try_partial_agg_producer(base)
             if p is not None:
                 return p
+        if self._subquery_agg_ok(plan):
+            return self._subquery_agg_producer(plan)
         # anything else (agg subtree, union, limit...) becomes a broadcast
         return self._broadcast_producer(plan)
+
+    @staticmethod
+    def _emits_as_rows(agg) -> bool:
+        """Is `agg` a GROUP BY whose group table `_group_rows` can hand
+        on as rows: the generic strategy, counts, sums and extremes."""
+        from tidb_tpu.planner.logical import CORE_AGGS
+
+        return (isinstance(agg, PHashAgg) and agg.strategy == "generic"
+                and bool(agg.group_exprs)
+                and not any(a.distinct or a.func not in CORE_AGGS
+                            or a.func == "avg" for a in agg.aggs))
 
     def _partial_agg_ok(self, plan) -> bool:
         """Can `plan` run as a per-shard partial aggregate (a SHARDED
         join input, not a broadcast)?"""
         stages, agg = peel_stages(plan)
-        if stages or not isinstance(agg, PHashAgg):
-            return False
-        from tidb_tpu.planner.logical import CORE_AGGS
-
-        if (agg.strategy != "generic" or not agg.group_exprs
-                or any(a.distinct or a.func not in CORE_AGGS
-                       or a.func == "avg" for a in agg.aggs)):
+        if stages or not self._emits_as_rows(agg):
             return False
         # ONLY eager-agg partials (rule-derived 'eagg.' uids): per-shard
         # emission is sound because THAT rule's upper aggregate re-sums
         # partial rows; a user-written derived-table aggregate has plain
-        # uids and must broadcast (shard-local groups would duplicate)
+        # uids and must broadcast (shard-local groups would duplicate),
+        # except on one part (`_subquery_agg_ok`)
         if not all(a.uid.startswith("eagg.") for a in agg.aggs):
             return False
         _, base = peel_stages(agg.child)
@@ -310,16 +335,11 @@ class _Compiler:
         merge is needed — the rewrite's upper aggregate re-sums partial
         rows, so shard-local groups with duplicate keys are exactly what
         the row-level semantics produced. Returns None for shapes the
-        kernel can't take (falls back to the broadcast producer).
-
-        DECIMAL sums recombine their two limbs on device (hi*2^32+lo):
-        exact while a per-shard per-group partial stays inside int64 —
-        the same representability bound as the final DECIMAL result."""
+        kernel can't take (falls back to the broadcast producer)."""
         if not self._partial_agg_ok(agg):
             return None
         child_emit = self.producer(agg.child)
         partial = make_partial_kernel(agg.group_exprs, agg.aggs)
-        types = {c.uid: c.type_ for c in agg.schema}
         self.sig.append(
             f"eagg:{agg.group_exprs!r}:{agg.aggs!r}:{agg.group_uids!r}")
         self.reduce_paths += reduce_paths(agg.aggs)
@@ -328,28 +348,97 @@ class _Compiler:
             chunk, ovfs = child_emit(env, growths)
             with jax.named_scope("agg.eager"):
                 t = partial(chunk)
-            live = jnp.arange(chunk.capacity) < t["n"]
-            cols = {}
-            for i, uid in enumerate(agg.group_uids):
-                cols[uid] = Column(data=t[f"k{i}.d"],
-                                   valid=t[f"k{i}.v"] & live,
-                                   type_=types[uid])
-            for j, a in enumerate(agg.aggs):
-                cnt = t[f"a{j}.cnt"]
-                if a.func == "count":
-                    data, valid = cnt, live
-                elif a.func == "sum":
-                    data = t[f"a{j}.sum"]
-                    if f"a{j}.sumhi" in t:
-                        data = data + (t[f"a{j}.sumhi"] << 32)
-                    valid = live & (cnt > 0)
-                else:  # min / max
-                    data = t[f"a{j}.{a.func}"]
-                    valid = live & (cnt > 0)
-                cols[a.uid] = Column(
-                    data=data.astype(types[a.uid].np_dtype),
-                    valid=valid, type_=types[a.uid])
-            return Chunk(cols, live), ovfs
+            return self._group_rows(agg, t), ovfs
+
+        return emit
+
+    def _subquery_agg_ok(self, plan) -> bool:
+        """Can `plan` — stages over a user-written GROUP BY — run INSIDE
+        the program, its groups emitted as rows where they are reduced?
+        Only on a mesh of ONE part (a static int when the fragment is
+        compiled): there the shard-local groups ARE the groups, which is
+        what `_partial_agg_ok` must refuse a derived-table aggregate on
+        several (a HAVING could fail each part's share of a group that
+        their sum passes; ROADMAP: exchange by group key, then the
+        same). AVG stays a broadcast: its state is a sum and a count and
+        the division is the host finalize's."""
+        _, agg = peel_stages(plan)
+        if self.n_parts != 1 or not self._emits_as_rows(agg):
+            return False
+        # over what compile_agg takes at the root: a sharded scan or a
+        # join tree
+        _, base = peel_stages(agg.child)
+        return isinstance(base, PHashJoin) or (
+            isinstance(base, PScan) and base.table is not None)
+
+    @staticmethod
+    def _group_rows(agg: PHashAgg, t) -> Chunk:
+        """A partial kernel's group table as rows: the keys and each
+        aggregate's value under the aggregate's uid, slots past `n`
+        dead. DECIMAL sums recombine their two limbs on device
+        (hi*2^32+lo): exact while a group's sum stays inside int64 — the
+        same representability bound as the final DECIMAL result."""
+        types = {c.uid: c.type_ for c in agg.schema}
+        live = jnp.arange(t["k0.d"].shape[0]) < t["n"]
+        cols = {}
+        for i, uid in enumerate(agg.group_uids):
+            cols[uid] = Column(data=t[f"k{i}.d"], valid=t[f"k{i}.v"] & live,
+                               type_=types[uid])
+        for j, a in enumerate(agg.aggs):
+            cnt = t[f"a{j}.cnt"]
+            if a.func == "count":
+                data, valid = cnt, live
+            elif a.func == "sum":
+                data = t[f"a{j}.sum"]
+                if f"a{j}.sumhi" in t:
+                    data = data + (t[f"a{j}.sumhi"] << 32)
+                valid = live & (cnt > 0)
+            else:  # min / max
+                data = t[f"a{j}.{a.func}"]
+                valid = live & (cnt > 0)
+            cols[a.uid] = Column(data=data.astype(types[a.uid].np_dtype),
+                                 valid=valid, type_=types[a.uid])
+        return Chunk(cols, live)
+
+    def _subquery_agg_producer(self, plan) -> Callable:
+        """An aggregate subquery as a producer of its own (one part; see
+        `_subquery_agg_ok`): the child's rows reduced exactly into a
+        group table sized as `compile_agg` sizes the root's
+        (`_table_reducer`), the groups emitted as rows, the
+        stages — the HAVING, the select list — run over them, the
+        survivors compacted to twice the planner's estimate of them.
+        Every capacity comes from estimates and the load's record: a
+        statement's program is the same whatever the subquery answers."""
+        stages, agg = peel_stages(plan)
+        n_before = len(self.sources)
+        child_emit = self.producer(agg.child)
+        # a group's rows span the batches of a streamed source: every
+        # source under the subquery is pinned resident
+        self.stream_unsafe.update(range(n_before, len(self.sources)))
+        reduce_to_table = self._table_reducer(agg, exact=True)
+        pipe = make_pipeline_fn(stages) if stages else (lambda c: c)
+        self.reduce_paths += reduce_paths(agg.aggs)
+        g_out, out_base = self._compact_knob(plan.est_rows)
+        # numbered after its input, as the knobs are: subq0's ops run first
+        scope = f"subq{self.n_subquery}"
+        self.n_subquery += 1
+        self.sig.append(f"subq:{agg.group_exprs!r}:{agg.aggs!r}"
+                        f":{agg.group_uids!r}:{stages!r}")
+
+        def emit(env, growths):
+            chunk, ovfs = child_emit(env, growths)
+            with jax.named_scope(scope):
+                rows = reduce_to_table(
+                    chunk, growths, ovfs,
+                    lambda table: self._group_rows(agg, table))
+                with jax.named_scope("having"):
+                    rows = pipe(rows)
+                capO = int(np.ceil(growths[g_out] * out_base))
+                if capO < rows.capacity:
+                    with jax.named_scope("compact"):
+                        rows, o = _compact_chunk(rows, capO)
+                        ovfs.append((g_out, pmax(o, _AXES)))
+            return rows, ovfs
 
         return emit
 
@@ -358,8 +447,19 @@ class _Compiler:
             # physical rowids are a host-engine concept (shardings
             # re-partition rows); DML selects fall back to the host path
             raise _Unsupported("__rowid__ pseudo-column in a fragment")
-        idx = len(self.sources)
-        self.sources.append(_Source(scan, stages))
+        # a table the statement names twice (Q18: lineitem under the
+        # joins and under the IN-subquery) enters the program ONCE: equal
+        # work over equal arguments is then the compiler's to share (both
+        # sort-reduces of lineitem by order key are one sort), and a
+        # sort is minutes of compile. Such a source is never streamed in
+        # batches: a self-join would pair only same-batch rows
+        idx = next((i for i, src in enumerate(self.sources)
+                    if src.scan.table is scan.table), None)
+        if idx is None:
+            idx = len(self.sources)
+            self.sources.append(_Source(scan))
+        else:
+            self.stream_unsafe.add(idx)
         uid_of = {c.name: c.uid for c in scan.schema}
         type_of = {c.name: c.type_ for c in scan.schema}
         pipe = make_pipeline_fn(stages) if stages else (lambda c: c)
@@ -423,6 +523,9 @@ class _Compiler:
             if self._partial_agg_ok(plan):
                 # eager-agg partial over a sharded scan: each shard emits
                 # its local groups exactly once — sharded, not replicated
+                return False
+            if self._subquery_agg_ok(plan):
+                # one part: the subquery's groups are rows of the program
                 return False
             return True
 
@@ -759,6 +862,43 @@ class _Compiler:
 
     # -- aggregation root --------------------------------------------------
 
+    def _table_reducer(self, agg: PHashAgg, exact) -> Callable:
+        """A generic aggregate's first pass with its two capacity knobs:
+        reduce(chunk, growths, ovfs, then=identity) -> then(group table),
+        `then` running inside the ``agg.partial`` scope. Estimate-sized
+        shrink targets (see _compact): the partial sort pays for input
+        capacity; every slot of the table is exchanged and sorted again
+        (fetched and decoded, or read by a join, on one part) by every
+        statement, so where the groups are bounded by the keys' distinct
+        count the table takes the smaller headroom, to the slot."""
+        partial = make_partial_kernel(agg.group_exprs, agg.aggs, exact=exact)
+        g_in, in_base = self._compact_knob(agg.child.est_rows)
+        g_tab, tab_base = self._compact_knob(
+            agg.est_rows,
+            self.NDV_HEADROOM if agg.est_from_ndv else self.HEADROOM,
+            rounded=not agg.est_from_ndv)
+
+        def reduce(chunk, growths, ovfs, then=lambda table: table):
+            capI = int(np.ceil(growths[g_in] * in_base))
+            if capI < chunk.capacity:
+                with jax.named_scope("agg.compact"):
+                    chunk, o = _compact_chunk(chunk, capI)
+                    ovfs.append((g_in, pmax(o, _AXES)))
+            with jax.named_scope("agg.partial"):
+                capT = int(np.ceil(growths[g_tab] * tab_base))
+                # local dedup before the exchange. Groups are dense in
+                # [0, n): the table keeps `capT` slots, which shrinks
+                # what the reduction gathers and everything the exchange
+                # must carry (on one part: everything the host fetches)
+                table = partial(chunk, slots=capT)
+                if capT < chunk.capacity:
+                    factor = (table["n"] + capT - 1) // capT
+                    ovfs.append(
+                        (g_tab, pmax(jnp.maximum(factor - 1, 0), _AXES)))
+                return then(table)
+
+        return reduce
+
     def compile_agg(self, agg: PHashAgg,
                     topn=None) -> Tuple[Callable, str, List[int]]:
         # the agg child must peel to a real sharded scan or a join tree;
@@ -799,12 +939,16 @@ class _Compiler:
         # knob for either. What the second pass guaranteed is asked of
         # the first: a duplicate-free table (`exact`: several keys order
         # by their mixed hash, and a collision may split a group) with
-        # carry-normalised limbs
+        # carry-normalised limbs. Past TIE_BREAK_KEYS keys the table
+        # comes in hash order with its count of split groups instead
+        # ("count": `_sort_reduce`), and the host finalize merges by
+        # exact key where that count is not 0; a per-shard top-k ranks
+        # whole groups, so it keeps the tie-break sort
         one_part = n_parts == 1
-        partial = make_partial_kernel(agg.group_exprs, agg.aggs,
-                                      exact=one_part)
         layout = _state_layout(agg.aggs)
         nk = len(agg.group_exprs)
+        exact = one_part and (
+            True if nk <= TIE_BREAK_KEYS or topn is not None else "count")
         topn_fn = (self._topn_select(topn[0], nk, layout, topn[1], agg.aggs)
                    if topn is not None else None)
         if one_part:
@@ -814,17 +958,7 @@ class _Compiler:
             self.n_exchange += 1
         # one sort-reduce on one part, a second after the exchange
         self.reduce_paths += reduce_paths(agg.aggs) * (1 if one_part else 2)
-        # estimate-sized shrink targets (see _compact): the partial sort
-        # pays for input capacity and the exchange pays for table slots
-        g_in, in_base = self._compact_knob(agg.child.est_rows)
-        # every slot of the table is exchanged and sorted again (fetched
-        # and decoded, on one part) by every statement: where the groups
-        # are bounded by the keys' distinct count, the table takes the
-        # smaller headroom
-        g_tab, tab_base = self._compact_knob(
-            agg.est_rows,
-            self.NDV_HEADROOM if agg.est_from_ndv else self.HEADROOM,
-            rounded=not agg.est_from_ndv)
+        reduce_to_table = self._table_reducer(agg, exact=exact)
         self.sig.append(
             f"genagg:{agg.group_exprs!r}:{agg.aggs!r}:exch{not one_part}")
 
@@ -856,40 +990,33 @@ class _Compiler:
                 # exact mode: the emitted tables are duplicate-free, so
                 # the host finalize is a straight per-part conversion —
                 # no merge
-                n, fk, fkv, red = _sort_reduce(rkd, rkv, recv_sel, payload,
-                                               ops, exact=True)
+                n, fk, fkv, red, _ = _sort_reduce(rkd, rkv, recv_sel, payload,
+                                                  ops, exact=True)
                 return n, fk, fkv, _normalize_red_limbs(red, layout, agg.aggs)
 
         def emit(env, growths):
             chunk, ovfs = child_emit(env, growths)
-            capI = int(np.ceil(growths[g_in] * in_base))
-            if capI < chunk.capacity:
-                with jax.named_scope("agg.compact"):
-                    chunk, o = _compact_chunk(chunk, capI)
-                    ovfs.append((g_in, pmax(o, _AXES)))
-            with jax.named_scope("agg.partial"):
-                capT = int(np.ceil(growths[g_tab] * tab_base))
-                # local dedup before the exchange. Groups are dense in
-                # [0, n): the table keeps `capT` slots, which shrinks
-                # what the reduction gathers and everything the exchange
-                # must carry (on one part: everything the host fetches)
-                table = partial(chunk, slots=capT)
-                if capT < chunk.capacity:
-                    factor = (table["n"] + capT - 1) // capT
-                    ovfs.append(
-                        (g_tab, pmax(jnp.maximum(factor - 1, 0), _AXES)))
-                if one_part:
-                    n = table["n"]
-                    fk = [table[f"k{i}.d"] for i in range(nk)]
-                    fkv = [table[f"k{i}.v"] for i in range(nk)]
-                    red = _normalize_red_limbs(
-                        [table[name] for name, _ in layout], layout, agg.aggs)
-            if not one_part:
+
+            def final_of_one_part(table):
+                return (table["n"], [table[f"k{i}.d"] for i in range(nk)],
+                        [table[f"k{i}.v"] for i in range(nk)],
+                        _normalize_red_limbs(
+                            [table[name] for name, _ in layout], layout,
+                            agg.aggs), table.get("split"))
+
+            split = None
+            if one_part:
+                n, fk, fkv, red, split = reduce_to_table(
+                    chunk, growths, ovfs, final_of_one_part)
+            else:
+                table = reduce_to_table(chunk, growths, ovfs)
                 n, fk, fkv, red = exchange_and_reduce(table, growths, ovfs)
             if topn_fn is not None:
                 with jax.named_scope("agg.topn"):
                     n, fk, fkv, red = topn_fn(n, fk, fkv, red)
             out = {"n": n[None]}
+            if split is not None:
+                out["split"] = split[None]
             for i in range(nk):
                 out[f"k{i}.d"] = fk[i]
                 out[f"k{i}.v"] = fkv[i]
@@ -983,7 +1110,7 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
         n_growth=c.n_growth, n_exchange=c.n_exchange,
         n_reduce=(c.reduce_paths.count("runs"),
                   c.reduce_paths.count("scatter")),
-        n_join=c.n_join,
+        n_join=c.n_join, n_subquery=c.n_subquery,
         sig="|".join(c.sig),
         build_fn=build_fn,
         out_kind=out_kind, domains=domains,

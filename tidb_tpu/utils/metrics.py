@@ -468,6 +468,18 @@ FRAGMENT_JOINS = Counter(
     "the binary search by gathers over the sorted build hashes (off, or a "
     "build side past the table's half load under xla). A launch adds its "
     "program's joins (TPC-H Q3: 2); a fragment without a join adds nothing")
+FRAGMENT_SUBQUERIES = Counter(
+    "tidb_tpu_fragment_subqueries_total",
+    "Subtrees that are no scans and no joins (an aggregate subquery: "
+    "TPC-H Q18's IN (... GROUP BY ... HAVING ...); a union, a limit) taken "
+    "as join inputs by the general fragment programs launched, by fragment "
+    "kind and by how each reaches the program, static per program: "
+    "path=inline, reduced, filtered and compacted INSIDE the program "
+    "(parallel/fragment.py _subquery_agg_producer: a GROUP BY without "
+    "DISTINCT or AVG on a mesh of one part); path=broadcast, answered as "
+    "a statement of its own through the host and uploaded replicated "
+    "(the span fragment.broadcast). A launch adds its program's counts "
+    "(TPC-H Q18 on one part: inline 1)")
 FRAGMENT_RETRY_TOTAL = Counter(
     "tidb_tpu_fragment_retry_total",
     "Fragment launches thrown away because a capacity knob overflowed "
