@@ -189,6 +189,39 @@ def test_prefix_cumsum_compiles_at_whole_table_length(one_chip, tpu_target,
     assert time.perf_counter() - t0 < 60
 
 
+# -- compaction -------------------------------------------------------------
+
+def test_compaction_compiles_at_whole_table_length(one_chip, tpu_target):
+    """parallel/fragment.py `_compact` over a whole SF1 lineitem shard
+    into the 3,145,728 slots of Q3's dearest compaction, a column of
+    every device type: ONE scatter, of the row numbers (`s32`: a 64-bit
+    one would be a tuple of two u32 arrays), the int64 stack's two u32
+    halves gathered, the float column in a stack of its own (this
+    compiler keeps a float64 as two f32 and refuses its bitcast to
+    int64), and no sort — it lowers a 64-bit scatter of this length
+    through one."""
+    import re
+
+    from tidb_tpu.parallel.fragment import _compact
+
+    sds, cap = _sds(one_chip), 3_145_728
+    arrays = {"k.d": jnp.int64, "f.d": jnp.float64, "s.d": jnp.int32,
+              "b.d": jnp.bool_}
+    arrays.update({name[:2] + "v": jnp.bool_ for name in list(arrays)})
+    text = _compile(
+        jax.jit(lambda a, sel: _compact(a, sel, cap)),
+        {n: sds((LINEITEM_SF1,), t) for n, t in arrays.items()},
+        sds((LINEITEM_SF1,), jnp.bool_)).as_text()
+    results = {opcode: [r.split("{")[0] for r, o in re.findall(
+        r"= (\(.*?\)|\S+) ([a-z][\w-]*)\(", text) if o == opcode]
+        for opcode in ("scatter", "gather", "sort")}
+    assert results["scatter"] == [f"s32[{cap + 1}]"], results
+    # two integer words and one row of five packed flags; the float
+    assert sorted(results["gather"]) == (
+        [f"f32[{cap}]"] * 2 + [f"u32[3,{cap}]"] * 2), results
+    assert not results["sort"], results
+
+
 # -- top-k ------------------------------------------------------------------
 
 @pytest.mark.parametrize("n_keys", [
@@ -628,9 +661,32 @@ def speck_tpch():
     return _tpch(0.001)  # 6,000 lineitem rows
 
 
+@pytest.fixture(scope="module")
+def speck_programs(topo, tpu_target, speck_tpch):
+    """(q, n_dev) -> [(FragmentProgram, its built function, its compiled
+    text)] of the statement's general fragments at SF0.001, where the
+    compiler takes a program's sorts in seconds (Q3: 15 s the whole
+    program; SF0.01: 1,160 s; SF0.05: 683 s); compiled once a module."""
+    from tidb_tpu.storage.tpch_queries import Q
+
+    done = {}
+
+    def get(q, n_dev):
+        if (q, n_dev) not in done:
+            done[q, n_dev] = []
+            for prog, shapes, growths, mode in _general_fragments(
+                    speck_tpch, Q[q][0], n_dev):
+                fn, args = _described_fragment(topo, prog, shapes, growths,
+                                               mode, n_dev)
+                done[q, n_dev].append(
+                    (prog, fn, _compile(fn, *args).as_text()))
+        return done[q, n_dev]
+
+    return get
+
+
 @pytest.mark.parametrize("n_dev", [1, 4], ids=["1x1", "1x4"])
-def test_q3_fragment_joins_rank_without_search(topo, tpu_target, speck_tpch,
-                                               n_dev):
+def test_q3_fragment_joins_rank_without_search(speck_programs, n_dev):
     """What PR 33 bought, pinned in the chip's own compiled text as
     `test_join_fragment_ranks_without_search` pins PR 26's: under the
     default probe mode each of Q3's two joins ranks its probe slots by
@@ -641,18 +697,11 @@ def test_q3_fragment_joins_rank_without_search(topo, tpu_target, speck_tpch,
     (the table with the whole search kept in its other arm), and what
     is scattered back to probe-slot order is 32 bits wide (a 64-bit
     scatter is a tuple of two u32 arrays in this text, and costs ten
-    times the 32-bit one on the chip: PERF.md section 5). Shapes:
-    SF0.001, where the compiler takes the program's sorts in seconds
-    (15 s the whole program; SF0.01: 1,160 s; SF0.05: 683 s)."""
+    times the 32-bit one on the chip: PERF.md section 5)."""
     import re
 
-    from tidb_tpu.storage.tpch_queries import Q
-
-    (prog, shapes, growths, mode), = _general_fragments(speck_tpch,
-                                                        Q["q3"][0], n_dev)
-    fn, args = _described_fragment(topo, prog, shapes, growths, mode, n_dev)
+    (prog, fn, text), = speck_programs("q3", n_dev)
     assert prog.n_join == 2
-    text = _compile(fn, *args).as_text()
     assert fn.join_probes == ["merge", "merge"]
     ops = _ops_by_scope(text, r"(join\d+)/join\.(\w+)")  # by (join, stage)
     for j in ("join0", "join1"):
@@ -666,6 +715,45 @@ def test_q3_fragment_joins_rank_without_search(topo, tpu_target, speck_tpch,
         assert "scatter" in [o for o, _ in probe]
     if n_dev > 1:
         assert "all-to-all" in text
+
+
+@pytest.mark.parametrize("q,n_dev", [("q3", 1), ("q3", 4), ("q18", 1),
+                                     ("q18", 4)])
+def test_fragment_compactions_scatter_row_numbers_only(speck_programs, q,
+                                                       n_dev):
+    """What PR 36 bought, pinned in the chip's own compiled text: under
+    every compaction scope of the general fragment (`join<j>/join.compact`,
+    `subq<k>/compact`, `agg.compact`) a `scatter` writes 32-bit row
+    numbers — ONE `s32|u32|pred[` result, never the tuple of two u32
+    arrays that a 64-bit scatter is in this text and that this compiler
+    lowers through a sort (508 ms a column at 6.0M rows against 36-45
+    for the row numbers: PERF.md section 6) — and the columns follow by
+    ONE gather a compaction, of all of them as an int64 stack: in this
+    text its two u32 halves, `u32[rows, cap]` each (tests/test_compact.py
+    holds the traced program to one). Q18 on one part holds its
+    subquery's compaction (`subq0/compact`); on four the subquery is a
+    program of its own."""
+    import re
+
+    scopes = r"((?:join\d+/join|subq\d+/|agg)\.?compact)"
+    seen = set()
+    for prog, fn, text in speck_programs(q, n_dev):
+        ops = _ops_by_scope(text, scopes)
+        # (none in Q18's subquery as a program of its own, on four parts)
+        assert all(prog.growth_kinds[k] == "compact" for k in fn.compactions)
+        in_scopes = [op for stage in ops.values() for op in stage]
+        for o, result in in_scopes:
+            if o == "scatter":
+                assert re.match(r"(s32|u32|pred)\[", result), result
+        n = len(fn.compactions)
+        assert [o for o, _ in in_scopes].count("scatter") == n, sorted(ops)
+        gathers = [result for o, result in in_scopes if o == "gather"]
+        assert len(gathers) == 2 * n, (gathers, fn.compactions)
+        assert all(re.match(r"u32\[\d+,\d+\]", g) for g in gathers), gathers
+        seen |= {scope for (scope,) in ops}
+    # lineitem's eager partial, join1's probe side: the dearest at SF1
+    assert "join1/join.compact" in seen, seen
+    assert ("subq0/compact" in seen) == (q == "q18" and n_dev == 1), seen
 
 
 def _one_program_cold(catalog, prog, growths):

@@ -307,13 +307,68 @@ def test_a_sort_reduce_sums_in_row_order_and_scatters_one_narrow_array(hlo_ops, 
             for name, opcode, _ in mine if opcode == "sort"] == ["sort"]
 
 
+def lines_by_order(rng, n=3000):
+    """(catalog, k, q, w) of t(k, line, q DECIMAL, w) in primary-key
+    order, 700 keys: lineitem's shape, so the bulk load sketches `k` and
+    a GROUP BY k is one launch cold."""
+    from tidb_tpu.types import decimal_type
+
+    catalog = Catalog()
+    cols = [ColumnInfo("k", BIGINT, not_null=True), ColumnInfo("line", BIGINT, not_null=True),
+            ColumnInfo("q", decimal_type(15, 2)), ColumnInfo("w", BIGINT)]
+    table = catalog.create_table("test", TableSchema("t", cols, primary_key=["k", "line"]))
+    k = np.sort(rng.integers(0, 700, n))
+    q = rng.integers(1, 51, n) * 100  # quantity 1..50, scale 2
+    w = rng.integers(-9, 9, n)
+    table.ingest_encoded({"k": k, "line": np.arange(n, dtype=np.int64), "q": q, "w": w}, {})
+    return catalog, k, q, w
+
+
+def test_the_input_compaction_scatters_row_numbers_and_gathers_once(hlo_ops):
+    """PR 36: `agg.compact` moves the filtered rows by ONE scatter of
+    32-bit row numbers and ONE gather of the chunk's arrays as a stack,
+    where the parent scattered each array by itself (the key and the
+    value in 64 bits)."""
+    _n_parts, ops_by_variant = hlo_ops
+    mine = [(opcode, type_) for name, opcode, type_ in ops_by_variant["filtered_topn"]
+            if "/agg.compact/" in f"/{name}/"]
+    assert [t[:4] for o, t in mine if o == "scatter"] == ["s32["], mine
+    assert [o for o, _ in mine].count("gather") == 1, mine
+
+
+@pytest.mark.parametrize("n_parts", [1, 4])
+def test_a_launch_counts_its_input_compaction(devices8, n_parts):
+    """FRAGMENT_COMPACTIONS{kind}: Q18's inner aggregate reads an
+    unfiltered scan, whose target is over its capacity: +0 a statement;
+    under a filter the input is compacted to its estimate: +1 a launch,
+    from the fragment cache too."""
+    from tidb_tpu.utils.metrics import FRAGMENT_COMPACTIONS
+
+    def compactions():
+        return sum(v for labels, v in FRAGMENT_COMPACTIONS.samples()
+                   if labels.get("kind") == "general_generic")
+
+    catalog, k, q, w = lines_by_order(np.random.default_rng(36))
+    s = session(catalog, devices8, n_parts)
+    for sql, want in (
+            ("select k, sum(q) from t group by k having sum(q) > 300 order by k", 0),
+            ("select k, sum(q) from t where w > 2 group by k order by k", 1)):
+        for _launch in ("traced", "from the fragment cache"):
+            l0, c0 = launches(), compactions()
+            rows = s.query(sql)
+            assert launches() - l0 == 1 and compactions() - c0 == want
+        if want:
+            assert [(int(g), int(round(float(v) * 100))) for g, v in rows] == [
+                (int(g), int(q[(k == g) & (w > 2)].sum()))
+                for g in np.unique(k[w > 2])]
+
+
 @pytest.mark.parametrize("n_parts", [1, 4])
 def test_a_launch_counts_its_payloads_by_how_they_are_reduced(devices8, n_parts):
     """FRAGMENT_REDUCE_PAYLOADS{kind, path}: Q18's inner aggregate (a
     count and the two limbs of a decimal sum) adds 3 to `runs` and 0 to
     `scatter` a launch on one part; a MAX beside it adds its own count
     to `runs` and its extreme to `scatter`. Several parts reduce twice."""
-    from tidb_tpu.types import decimal_type
     from tidb_tpu.utils.metrics import FRAGMENT_REDUCE_PAYLOADS
 
     def by_path():
@@ -323,16 +378,7 @@ def test_a_launch_counts_its_payloads_by_how_they_are_reduced(devices8, n_parts)
                 got[labels["path"]] += v
         return got
 
-    rng = np.random.default_rng(31)
-    n = 3000
-    catalog = Catalog()
-    cols = [ColumnInfo("k", BIGINT, not_null=True), ColumnInfo("line", BIGINT, not_null=True),
-            ColumnInfo("q", decimal_type(15, 2)), ColumnInfo("w", BIGINT)]
-    table = catalog.create_table("test", TableSchema("t", cols, primary_key=["k", "line"]))
-    k = np.sort(rng.integers(0, 700, n))
-    q = rng.integers(1, 51, n) * 100  # quantity 1..50, scale 2
-    w = rng.integers(-9, 9, n)
-    table.ingest_encoded({"k": k, "line": np.arange(n, dtype=np.int64), "q": q, "w": w}, {})
+    catalog, k, q, w = lines_by_order(np.random.default_rng(31))
     s = session(catalog, devices8, n_parts)
     twice = 1 if n_parts == 1 else 2
     for sql, want, rows in (

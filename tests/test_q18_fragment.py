@@ -26,6 +26,7 @@ from tidb_tpu.storage.tpch import TPCH_SCHEMAS
 from tidb_tpu.testutil import mirror_to_sqlite, rows_equal
 from tidb_tpu.utils import tracing
 from tidb_tpu.utils.metrics import (
+    FRAGMENT_COMPACTIONS,
     FRAGMENT_DISPATCH,
     FRAGMENT_RETRY_TOTAL,
     FRAGMENT_SUBQUERIES,
@@ -151,6 +152,31 @@ def test_q18_on_one_part_is_one_program_and_one_launch(devices8, loads, seed, qu
     names = set(trace.self_us_by_name())
     assert "fragment.general_generic[parts=1]" in names
     assert "fragment.broadcast" not in names
+
+
+@pytest.mark.parametrize("quantity", [MANY, NONE], ids=["many", "none"])
+def test_a_q18_launch_counts_its_compactions_the_subquerys_among_them(
+        devices8, loads, quantity):
+    """FRAGMENT_COMPACTIONS{kind} (PR 36): of the program's 17 knobs
+    (join0's expand and three targets 0-3, join1's 4-7, the subquery's
+    input, table and survivors 8-10, join2's 11-14, the root's 15-16)
+    the trace compacts, at this scale, join1's probe side (lineitem's
+    eager partial, 5), **the HAVING's survivors (10)** and join2's output
+    (14) — whatever passes the HAVING, none included: the count is the
+    program's, static — and a launch from the fragment cache adds the
+    same three."""
+    catalog, _data, _oracle = loads[SEEDS[0]]
+    s = session(catalog, devices8, 1)
+    c0 = samples(FRAGMENT_COMPACTIONS)
+    _rows, seen, _trace = run_spied(s, Q18.sql({"quantity": quantity}))
+    (prog, _args, _shapes, _types, growths, grown), = seen
+    assert growths == grown == prog.growth_defaults and prog.n_growth == 17
+    (fn,) = [f for k, f in s._shard_cache.fragments.items() if k[0] == "frag"]
+    assert fn.compactions == [5, 10, 14]
+    assert delta(FRAGMENT_COMPACTIONS, c0) == {("general_generic", ""): 3}
+    c0 = samples(FRAGMENT_COMPACTIONS)
+    s.query(Q18.sql({"quantity": quantity}))
+    assert delta(FRAGMENT_COMPACTIONS, c0) == {("general_generic", ""): 3}
 
 
 def test_two_seeds_of_data_share_one_fragment_cache_key(devices8, loads):

@@ -22,6 +22,7 @@ from tidb_tpu.storage.table import ColumnInfo, TableSchema
 from tidb_tpu.testutil import index_tpch_oracle, mirror_to_sqlite, rows_equal
 from tidb_tpu.types import DATE, INT64, STRING, decimal_type
 from tidb_tpu.utils.metrics import (
+    FRAGMENT_COMPACTIONS,
     FRAGMENT_DISPATCH,
     FRAGMENT_JOINS,
     FRAGMENT_RETRY_TOTAL,
@@ -159,6 +160,38 @@ def test_a_launch_counts_its_joins_by_probe_path(devices8, tiny_tpch):
     _rows, seen = run_spied(s, Q18_INNER)
     assert seen[0][0].n_join == 0
     assert delta(FRAGMENT_DISPATCH, l0) == {(): 1} and delta(FRAGMENT_JOINS, j0) == {}
+
+
+@pytest.mark.parametrize("n_parts,knobs", [(1, [2, 5]), (4, [3, 4, 7, 9])],
+                         ids=["1x1", "1x4"])
+def test_a_launch_counts_the_compactions_its_trace_took(devices8, tiny_tpch,
+                                                        n_parts, knobs):
+    """FRAGMENT_COMPACTIONS{kind} (PR 36): a launch adds the `_compact`s
+    its program's trace took, which is static per built program (a
+    target at or over its chunk's capacity compiles nothing): on one
+    part, at this scale, join0's build side (customer under its filter,
+    knob 2) and join1's probe side (lineitem's eager partial, knob 5), as
+    at SF1; a part of four holds a quarter of the rows against the same
+    floors, and compacts other chunks. The program from the fragment
+    cache brings its count with it; Q18's inner aggregate over an
+    unfiltered scan compacts nothing."""
+    catalog, _ = tiny_tpch
+    s = session(catalog, devices8, n_parts)
+    c0 = by_labels(FRAGMENT_COMPACTIONS)
+    _rows, seen = run_spied(s, Q3.format(**PARAMS[0]))
+    (prog, _args, growths, grown), = seen
+    assert growths == grown == prog.growth_defaults
+    (fn,) = [f for k, f in s._shard_cache.fragments.items() if k[0] == "frag"]
+    assert fn.compactions == knobs
+    assert all(prog.growth_kinds[k] == "compact" for k in knobs)
+    assert delta(FRAGMENT_COMPACTIONS, c0) == {(): len(knobs)}
+    c0 = by_labels(FRAGMENT_COMPACTIONS)
+    s.query(Q3.format(**PARAMS[0]))  # from the fragment cache: not traced again
+    assert delta(FRAGMENT_COMPACTIONS, c0) == {(): len(knobs)}
+    c0, l0 = by_labels(FRAGMENT_COMPACTIONS), by_labels(FRAGMENT_DISPATCH)
+    s.query(Q18_INNER)
+    assert delta(FRAGMENT_DISPATCH, l0) == {(): 1}
+    assert delta(FRAGMENT_COMPACTIONS, c0) == {}
 
 
 def test_the_probe_says_which_path_it_traced_the_table_under_its_half_load_only():

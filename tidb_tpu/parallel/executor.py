@@ -59,7 +59,7 @@ __all__ = ["ShardCache", "build_dist_executor", "DistAggExec", "DistJoinAggExec"
 @contextlib.contextmanager
 def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
                      reduced: Tuple[int, int] = (0, 0), joins=(),
-                     subqueries: Tuple[int, int] = (0, 0)):
+                     subqueries: Tuple[int, int] = (0, 0), compactions=()):
     """One fragment launch: the span ``fragment.<kind>[parts=N]`` on the
     statement's trace and the FRAGMENT_SECONDS collector for /metrics
     (with a trace_id exemplar). Wall time covers the launch plus any
@@ -76,9 +76,12 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
     first call fills the list as it traces), and the subtrees it takes as
     build sides that are no scans, `subqueries` = (compiled into the
     program, answered through the host and broadcast)
-    (FRAGMENT_SUBQUERIES)."""
+    (FRAGMENT_SUBQUERIES), and the compactions its trace took,
+    `compactions` = the knob of each (FRAGMENT_COMPACTIONS; read after
+    the launch, as `joins`)."""
     from tidb_tpu.utils import tracing
     from tidb_tpu.utils.metrics import (
+        FRAGMENT_COMPACTIONS,
         FRAGMENT_DISPATCH,
         FRAGMENT_EXCHANGE_STEPS,
         FRAGMENT_JOINS,
@@ -98,6 +101,7 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
         FRAGMENT_JOINS.inc(kind=kind, probe=probe)
     for path, n in zip(("inline", "broadcast"), subqueries):
         FRAGMENT_SUBQUERIES.inc(n, kind=kind, path=path)
+    FRAGMENT_COMPACTIONS.inc(len(compactions), kind=kind)
     FRAGMENT_SECONDS.observe(time.perf_counter() - t0, kind=kind)
 
 
@@ -177,8 +181,10 @@ class ShardCache:
                 note_placement("fragment", out)
             return out
 
-        # a general fragment's joins by probe path (fragment.py build_fn)
+        # a general fragment's joins by probe path and the compactions
+        # its trace took (fragment.py build_fn)
         dispatch.join_probes = getattr(fn, "join_probes", ())
+        dispatch.compactions = getattr(fn, "compactions", ())
         return dispatch
 
     def get_growth(self, gkey) -> float:
@@ -644,7 +650,8 @@ class DistFragmentExec(HashAggExec):
                 key, lambda: prog.build_fn(growths, probe_mode=probe_mode))
             with _fragment_launch(kind, n_parts, prog.n_exchange,
                                   prog.n_reduce, fn.join_probes,
-                                  (prog.n_subquery, len(prog.broadcasts))):
+                                  (prog.n_subquery, len(prog.broadcasts)),
+                                  fn.compactions):
                 out, ovf = fn(*args)
             # host-sync: the per-knob overflow vector (a few int64s)
             # gates the capacity-retry loop — one fetch per dispatch

@@ -20,9 +20,10 @@ coprocessor tiers, SURVEY.md §2 parallelism table) to:
     over a generic GROUP BY over a scan or a join tree: TPC-H Q18's IN)
     is no broadcast but a producer INSIDE the program
     (_subquery_agg_producer): shard-local groups are the groups, so the
-    rows are reduced, filtered and compacted where the join reads them,
-    and nothing of the subquery's answer sizes anything. A table that
-    several scans of the statement read is ONE source of the program
+    rows are reduced, filtered and compacted (`_compact`: one 32-bit
+    scatter of row numbers, one stacked gather) where the join reads
+    them, and nothing of the subquery's answer sizes anything. A table
+    that several scans of the statement read is ONE source of the program
   * both aggregation strategies at the root: segment (dense [G] states,
     psum/pmin/pmax merge) and generic (per-shard sort-based partial
     tables from executor/agg_device.py, hash-repartitioned by group key
@@ -51,10 +52,21 @@ order of the growth knobs); an eager partial aggregate under a join
 sorts and reduces under ``agg.eager``; an aggregate subquery compiled
 into the program under ``subq<k>/{agg.partial,having,compact}``, k
 counting them likewise (`n_subquery`).
+
+Every ``join.compact``, ``compact`` and ``agg.compact`` scope is one
+`_compact` per chunk it shrinks: a 32-bit prefix sum, ONE 32-bit scatter
+of row numbers and ONE gather of all the chunk's arrays as an int64
+stack — never a scatter per column, which for every 64-bit column is a
+sort to this compiler (Q3's dearest, two int64 columns and their masks
+from 6.0M slots into 3.1M: 1,076-1,116 ms a scatter per array, 79 ms so;
+PERF.md section 6, PR 36). A built program's `compactions` lists the
+ones its trace took (a capacity at or over its chunk's compiles
+nothing): FRAGMENT_COMPACTIONS.
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -98,40 +110,106 @@ BROADCAST_LIMIT = 1 << 21
 _key_bits = _bits64
 
 
+# boolean arrays (every validity mask) a stack row carries as its bits
+_PACK = 63
+
+
 def _compact(arrays: Dict[str, jax.Array], sel: jax.Array, cap: int):
-    """Scatter live rows to the prefix of [cap] buffers (linear — no sort).
+    """Move live rows to the prefix of [cap] buffers (linear — no sort).
 
     Static capacities cascade: every stage inherits the worst case of the
     stage before, while selective joins/filters collapse the LIVE count.
     Sorts and exchanges pay for capacity, so compacting to an
     estimate-sized buffer (with the usual overflow-retry knob) is the
-    static-shape analogue of a dynamic repartition. Returns
+    static-shape analogue of a dynamic repartition.
+
+    The move is a 32-bit scatter of ROW NUMBERS and ONE gather of a
+    stack (PR 36), whatever the chunk carries: a live row's place is the
+    int32 prefix sum of `sel` (dead rows and rows past `cap` go to the
+    drop lane), each slot learns its source row by one scatter of the
+    iota, and every array of the chunk rides as a row of one int64 stack
+    (narrower integers widened and narrowed back; the boolean arrays —
+    every validity mask — packed `_PACK` to a row) that `jnp.take`
+    fetches by those row numbers once. Float columns ride in a float64
+    stack of their own, one gather more where a chunk has any (the
+    chip's compiler has no bitcast between 64-bit floats and integers).
+    The scatter is told nothing of its indices (PR 31: neither hint
+    bought a millisecond and `indices_are_sorted` gave wrong rows).
+
+    What the chip charges (PERF.md section 6, PRs 31 and 36): an array
+    scattered by itself is a 64-bit scatter wherever it is an int64, a
+    float64 or a decimal, which this compiler lowers through a sort —
+    two int64 columns and their masks from 6,001,215 slots into
+    3,145,728 cost 1,076-1,116 ms so (508 a column in Q3's trace), and
+    79 ms this way: the int32 prefix sum 2.2, the scatter of the row
+    numbers 36.2, the gather of the [3, R] stack by 3.1M indices 40.0
+    (in the compiled text its two u32 halves); six int64 columns 4.8 s
+    against 0.17-0.19. A gather is paid by the index more than by what
+    an index fetches (PR 31: three int64 arrays apart 331 ms, one stack
+    65).
+
+    Slots at and past the live count hold zero / False. Returns
     (arrays', sel', required_factor_minus_one)."""
-    pos = prefix.cumsum(sel.astype(jnp.int64)) - 1
-    total = jnp.sum(sel.astype(jnp.int64))
+    (R,) = sel.shape
+    if R >= 1 << 31:
+        raise ValueError(f"a compaction of {R} slots on one shard; use "
+                         "more shards")
+    pos = prefix.cumsum(sel.astype(jnp.int32)) - 1
+    total = pos[-1].astype(jnp.int64) + 1
     tgt = jnp.where(sel & (pos < cap), pos, cap)  # dead rows -> drop lane
-    out = {}
-    for name, a in arrays.items():
-        buf = jnp.zeros((cap + 1,) + a.shape[1:], dtype=a.dtype)
-        out[name] = buf.at[tgt].set(a, mode="drop")[:cap]
+    src = jnp.zeros(cap + 1, dtype=jnp.int32).at[tgt].set(
+        jnp.arange(R, dtype=jnp.int32), mode="drop")[:cap]
     nsel = jnp.arange(cap) < jnp.minimum(total, cap)
+
+    # name -> (its stack, its row there); the flags' bits after the words
+    stacks = {jnp.int64: [], jnp.float64: []}
+    flags, at = [], {}
+    for name, a in arrays.items():
+        if a.dtype == jnp.bool_:
+            flags.append(name)
+            continue
+        wide = (jnp.float64 if jnp.issubdtype(a.dtype, jnp.floating)
+                else jnp.int64)
+        at[name] = wide, len(stacks[wide])
+        stacks[wide].append(a.astype(wide))
+    packed = len(stacks[jnp.int64])
+    for i in range(0, len(flags), _PACK):
+        stacks[jnp.int64].append(functools.reduce(
+            jnp.bitwise_or,
+            [arrays[n].astype(jnp.int64) << b
+             for b, n in enumerate(flags[i:i + _PACK])]))
+    moved = {wide: jnp.where(nsel, jnp.take(jnp.stack(rows), src, axis=1,
+                                            mode="clip"), 0)
+             for wide, rows in stacks.items() if rows}
+    out = {name: moved[wide][row].astype(arrays[name].dtype)
+           for name, (wide, row) in at.items()}
+    for i, name in enumerate(flags):
+        out[name] = (moved[jnp.int64][packed + i // _PACK]
+                     >> (i % _PACK)) & 1 != 0
     factor = (total + cap - 1) // cap
     return out, nsel, jnp.maximum(factor - 1, 0)
 
 
-def _compact_chunk(chunk: Chunk, cap: int):
-    """Compact a Chunk's live rows into a capacity-`cap` Chunk."""
+def _compact_chunk(env, chunk: Chunk, cap: int, knob: int, ovfs) -> Chunk:
+    """`chunk`'s live rows in a Chunk of capacity `cap`, where that is
+    below its own (static: the chunk itself otherwise); the overflow
+    reported under `knob`, the compaction noted in `env` (what
+    FRAGMENT_COMPACTIONS counts)."""
+    if cap >= chunk.capacity:
+        return chunk
     arrays = {}
     for uid, col in chunk.columns.items():
         arrays[uid + ".d"] = col.data
         arrays[uid + ".v"] = col.valid
     out, nsel, ovf = _compact(arrays, chunk.sel, cap)
+    ovfs.append((knob, pmax(ovf, _AXES)))
+    env["compactions"].append(knob)
     cols = {
         uid: Column(data=out[uid + ".d"], valid=out[uid + ".v"],
                     type_=col.type_)
         for uid, col in chunk.columns.items()
     }
-    return Chunk(cols, nsel), ovf
+    return Chunk(cols, nsel)
 
 
 def _mix_hash(bits: List[jax.Array]) -> jax.Array:
@@ -194,7 +272,9 @@ class FragmentProgram:
     n_subquery: int
     sig: str
     # (growths tuple) -> per-shard program; its `join_probes` lists, once
-    # traced, the probe path of each join ("table" | "search"), join0 first
+    # traced, the probe path of each join ("table" | "search"), join0 first,
+    # and its `compactions` the "compact" knob of each `_compact` the trace
+    # took (static per growths: a capacity under its chunk's)
     build_fn: Callable
     out_kind: str                      # "segment" | "generic"
     domains: List[int] = field(default_factory=list)
@@ -429,15 +509,13 @@ class _Compiler:
             chunk, ovfs = child_emit(env, growths)
             with jax.named_scope(scope):
                 rows = reduce_to_table(
-                    chunk, growths, ovfs,
+                    env, chunk, growths, ovfs,
                     lambda table: self._group_rows(agg, table))
                 with jax.named_scope("having"):
                     rows = pipe(rows)
                 capO = int(np.ceil(growths[g_out] * out_base))
-                if capO < rows.capacity:
-                    with jax.named_scope("compact"):
-                        rows, o = _compact_chunk(rows, capO)
-                        ovfs.append((g_out, pmax(o, _AXES)))
+                with jax.named_scope("compact"):
+                    rows = _compact_chunk(env, rows, capO, g_out, ovfs)
             return rows, ovfs
 
         return emit
@@ -587,13 +665,9 @@ class _Compiler:
         def join_local(env, growths, pch, bch, ovfs):
             with jax.named_scope("join.compact"):
                 capP = int(np.ceil(growths[g_pcomp] * p_base))
-                if capP < pch.capacity:
-                    pch, o = _compact_chunk(pch, capP)
-                    ovfs.append((g_pcomp, pmax(o, _AXES)))
+                pch = _compact_chunk(env, pch, capP, g_pcomp, ovfs)
                 capB = int(np.ceil(growths[g_bcomp] * b_base))
-                if capB < bch.capacity:
-                    bch, o = _compact_chunk(bch, capB)
-                    ovfs.append((g_bcomp, pmax(o, _AXES)))
+                bch = _compact_chunk(env, bch, capB, g_bcomp, ovfs)
 
             p_outs = [eval_expr(k, pch) for k in probe_keys]
             b_outs = [eval_expr(k, bch) for k in build_keys]
@@ -764,10 +838,8 @@ class _Compiler:
                     result = Chunk(out_cols, jnp.concatenate([joined.sel, pad_sel]))
 
             capO = int(np.ceil(growths[g_ocomp] * o_base))
-            if capO < result.capacity:
-                with jax.named_scope("join.compact"):
-                    result, o = _compact_chunk(result, capO)
-                    ovfs.append((g_ocomp, pmax(o, _AXES)))
+            with jax.named_scope("join.compact"):
+                result = _compact_chunk(env, result, capO, g_ocomp, ovfs)
             return result, ovfs
 
         return emit
@@ -864,7 +936,7 @@ class _Compiler:
 
     def _table_reducer(self, agg: PHashAgg, exact) -> Callable:
         """A generic aggregate's first pass with its two capacity knobs:
-        reduce(chunk, growths, ovfs, then=identity) -> then(group table),
+        reduce(env, chunk, growths, ovfs, then=identity) -> then(group table),
         `then` running inside the ``agg.partial`` scope. Estimate-sized
         shrink targets (see _compact): the partial sort pays for input
         capacity; every slot of the table is exchanged and sorted again
@@ -878,12 +950,10 @@ class _Compiler:
             self.NDV_HEADROOM if agg.est_from_ndv else self.HEADROOM,
             rounded=not agg.est_from_ndv)
 
-        def reduce(chunk, growths, ovfs, then=lambda table: table):
+        def reduce(env, chunk, growths, ovfs, then=lambda table: table):
             capI = int(np.ceil(growths[g_in] * in_base))
-            if capI < chunk.capacity:
-                with jax.named_scope("agg.compact"):
-                    chunk, o = _compact_chunk(chunk, capI)
-                    ovfs.append((g_in, pmax(o, _AXES)))
+            with jax.named_scope("agg.compact"):
+                chunk = _compact_chunk(env, chunk, capI, g_in, ovfs)
             with jax.named_scope("agg.partial"):
                 capT = int(np.ceil(growths[g_tab] * tab_base))
                 # local dedup before the exchange. Groups are dense in
@@ -1007,9 +1077,9 @@ class _Compiler:
             split = None
             if one_part:
                 n, fk, fkv, red, split = reduce_to_table(
-                    chunk, growths, ovfs, final_of_one_part)
+                    env, chunk, growths, ovfs, final_of_one_part)
             else:
-                table = reduce_to_table(chunk, growths, ovfs)
+                table = reduce_to_table(env, chunk, growths, ovfs)
                 n, fk, fkv, red = exchange_and_reduce(table, growths, ovfs)
             if topn_fn is not None:
                 with jax.named_scope("agg.topn"):
@@ -1063,10 +1133,11 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
         # a knob flip can never serve a program traced for the other
         # strategy); None = the hash_probe process default
         join_probes: List[str] = []
+        compactions: List[int] = []
 
         def frag_general(*args):
             env = {"scan": [], "bcast": [], "probe_mode": probe_mode,
-                   "joins": []}
+                   "joins": [], "compactions": []}
             i = 0
             for _ in range(n_src):
                 env["scan"].append((args[i], args[i + 1], args[i + 2],
@@ -1076,7 +1147,9 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
                 env["bcast"].append((args[i], args[i + 1], args[i + 2]))
                 i += 3
             out, reports = emit(env, growths)
-            join_probes[:] = env["joins"]  # trace time: static per program
+            # trace time: static per program
+            join_probes[:] = env["joins"]
+            compactions[:] = env["compactions"]
             # per-knob overflow vector, slot-indexed by knob id so the
             # executor always grows exactly the blown capacity (emission
             # order differs from knob-assignment order)
@@ -1100,9 +1173,11 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
             # out_specs are the authority here
             check_vma=False,
         ))
-        # what FRAGMENT_JOINS counts at every launch: filled by the first
-        # call's trace, kept with the function in the fragment cache
+        # what FRAGMENT_JOINS and FRAGMENT_COMPACTIONS count at every
+        # launch: filled by the first call's trace, kept with the function
+        # in the fragment cache
         fn.join_probes = join_probes
+        fn.compactions = compactions
         return fn
 
     return FragmentProgram(

@@ -480,6 +480,18 @@ FRAGMENT_SUBQUERIES = Counter(
     "a statement of its own through the host and uploaded replicated "
     "(the span fragment.broadcast). A launch adds its program's counts "
     "(TPC-H Q18 on one part: inline 1)")
+FRAGMENT_COMPACTIONS = Counter(
+    "tidb_tpu_fragment_compactions_total",
+    "Compactions compiled into the general fragment programs launched "
+    "(parallel/fragment.py _compact: a chunk's live rows moved to the "
+    "prefix of an estimate-sized buffer by one 32-bit scatter of row "
+    "numbers and one gather of all its arrays as a stack), by fragment "
+    "kind, static per program: a join side, a join's output, an "
+    "aggregate's input or an inlined subquery's survivors whose capacity "
+    "knob lies under the chunk's own capacity. A launch adds the "
+    "compactions its program's trace took; a fragment whose targets all "
+    "reach their chunks' capacities (TPC-H Q18's inner aggregate over an "
+    "unfiltered scan) adds nothing")
 FRAGMENT_RETRY_TOTAL = Counter(
     "tidb_tpu_fragment_retry_total",
     "Fragment launches thrown away because a capacity knob overflowed "
