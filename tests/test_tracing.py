@@ -662,3 +662,334 @@ class TestRequestTrace:
         assert abs(rd / 1e3 - tr.root().dur_us) < 2_000
         for start, dur, _ in events["tidb.device.wait"]:
             assert r0 <= start and start + dur <= r0 + rd
+
+
+# -- inside one span: phases and counts (ISSUE 37) ---------------------------
+
+
+class _Clock:
+    """``time`` for the tracer, moved by the test alone: what a phase
+    reads of the clock cannot shift what a span reads. In ticks of 1/64
+    s, T microseconds, exact in binary: no reading is rounded."""
+
+    def __init__(self):
+        self.now = 100.0
+
+    def perf_counter(self):
+        return self.now
+
+    def tick(self, n):
+        self.now += n / 64
+
+    time, strftime, localtime = (staticmethod(time.time), staticmethod(time.strftime),
+                                 staticmethod(time.localtime))
+
+
+def _scripted_tree(parts: bool):
+    """One tree on the test's clock, in ticks: root [0, 40) with a child
+    [4, 16) and, in the root's own time, two phases and a count —
+    recorded only with `parts`."""
+    import contextlib
+
+    phase = tracing.phase if parts else (lambda _n: contextlib.nullcontext())
+    add = tracing.add if parts else (lambda _n, _v: None)
+    clock = _Clock()
+    real, tracing.time = tracing.time, clock
+    try:
+        tr = tracing.Trace("t-parts")
+        tracing.push(tr)
+        with tracing.span("root"):
+            clock.tick(4)
+            with tracing.span("child"):
+                with phase("inner"):
+                    clock.tick(12)
+                add("n", 7)
+            with phase("a"):
+                clock.tick(6)
+                add("rows", 5)
+            with phase("b"):
+                clock.tick(2)
+            with phase("a"):
+                clock.tick(1)
+                add("rows", 6)
+            clock.tick(15)
+        tracing.pop()
+    finally:
+        tracing.time = real
+    return tr
+
+
+T = 15625  # a tick of _Clock, microseconds
+
+
+class TestSpanParts:
+    def test_a_phase_or_a_count_is_no_span_and_moves_no_self_time(self):
+        bare, parted = _scripted_tree(False), _scripted_tree(True)
+        assert [(s.span_id, s.parent_id, s.name, s.start_us, s.dur_us)
+                for s in parted.spans] == [
+            (s.span_id, s.parent_id, s.name, s.start_us, s.dur_us)
+            for s in bare.spans] == [(1, None, "root", 0, 40 * T),
+                                     (2, 1, "child", 4 * T, 12 * T)]
+        assert parted.self_us() == bare.self_us() == {1: 28 * T, 2: 12 * T}
+        assert parted.self_us_by_name() == bare.self_us_by_name()
+        assert parted.duration_ms() == bare.duration_ms() == 625.0
+        assert bare.phases_us() == {} and bare.counts() == {}
+        assert all(s.phases is None and s.counts is None for s in bare.spans)
+
+    def test_phases_and_counts_accumulate_on_the_innermost_open_span(self):
+        tr = _scripted_tree(True)
+        root, child = tr.spans
+        assert root.phases == {"a": [7 * T, 2], "b": [2 * T, 1]}
+        assert root.counts == {"rows": 11}
+        assert child.phases == {"inner": [12 * T, 1]} and child.counts == {"n": 7}
+        assert tr.phases_us() == {"root/a": [7 * T, 2], "root/b": [2 * T, 1],
+                                  "child/inner": [12 * T, 1]}
+        assert tr.counts() == {"root/rows": 11, "child/n": 7}
+        for s in tr.spans:  # the parts of a span lie inside it
+            assert sum(us for us, _ in s.phases.values()) <= s.dur_us
+
+    def test_same_named_spans_sum_in_the_read_side(self):
+        tr = tracing.Trace("t-sum")
+        tracing.push(tr)
+        for n in (3, 4):
+            with tracing.span("device.wait"):
+                with tracing.phase("copy"):
+                    pass
+                tracing.add("bytes", n)
+        tracing.pop()
+        assert tr.counts() == {"device.wait/bytes": 7}
+        assert tr.phases_us()["device.wait/copy"][1] == 2
+        assert len(tr.spans) == 2
+
+    def test_to_dict_and_the_rows_of_a_span_show_them(self):
+        d = _scripted_tree(True).to_dict()
+        (root,) = d["tree"]
+        assert root["phases"] == {"a": [7 * T, 2], "b": [2 * T, 1]}
+        assert root["counts"] == {"rows": 11}
+        (child,) = root["children"]
+        assert child["phases"] == {"inner": [12 * T, 1]} and child["counts"] == {"n": 7}
+        bare = _scripted_tree(False).to_dict()["tree"][0]
+        assert bare["phases"] == {} and bare["counts"] == {}
+        assert bare["self_us"] == root["self_us"] == 28 * T
+        json.dumps(d)  # /trace?id= serves it as it is
+        tr = _scripted_tree(True)
+        assert tr.spans[0].parts() == [f"phase:a={7 * T}us/2", f"phase:b={2 * T}us/1",
+                                       "count:rows=11"]
+        assert _scripted_tree(False).spans[0].parts() == []
+
+    def test_they_do_not_cross_processes(self):
+        """``export`` ships a worker's spans without their parts (PERF.md
+        says so): the wire form is what it was."""
+        tr = _scripted_tree(True)
+        assert [sorted(r) for r in tr.export()] == [["a", "d", "i", "n", "p", "s"]] * 2
+        coord = tracing.Trace("t-coord")
+        rpc = coord.begin("dcn.rpc")
+        coord.graft(tr.export(), rpc, proc="w")
+        assert coord.phases_us() == {} and coord.counts() == {}
+
+    def test_the_off_paths_do_nothing_and_raise_nothing(self):
+        assert tracing.current() is None
+        with tracing.phase("no-trace"):
+            tracing.add("n", 1)
+        tr = tracing.Trace("t-off", max_spans=1)
+        tracing.push(tr)
+        try:
+            with tracing.phase("no-open-span"):
+                tracing.add("n", 1)
+            assert tr.spans == [] and tr.phases_us() == {} and tr.counts() == {}
+            with tracing.span("kept"):
+                with tracing.span("over-budget") as dropped:
+                    with tracing.phase("on-the-sentinel"):
+                        tracing.add("n", 1)
+                    assert dropped is tracing._DROPPED
+                tracing.add("n", 2)  # the innermost open span again
+        finally:
+            tracing.pop()
+        assert tracing._DROPPED.phases is None and tracing._DROPPED.counts is None
+        assert tr.dropped == 1 and tr.counts() == {"kept/n": 2}
+        assert tr.phases_us() == {}
+
+    def test_a_phase_left_by_an_exception_is_booked_and_does_not_swallow_it(self):
+        tr = tracing.Trace("t-exc")
+        tracing.push(tr)
+        try:
+            with pytest.raises(ValueError):
+                with tracing.span("s"):
+                    with tracing.phase("p"):
+                        raise ValueError("x")
+        finally:
+            tracing.pop()
+        assert tr.spans[0].phases["p"][1] == 1 and tr.spans[0].dur_us >= 0
+
+
+PLAN_PHASES = ["cache", "bind", "rules", "lower", "privs", "build"]
+
+
+def _plan_phases(tr):
+    return {k.partition("/")[2]: v for k, v in tr.phases_us().items()
+            if k.startswith("session.plan/")}
+
+
+class TestProgramPhases:
+    def test_session_plan_names_its_six_phases_inside_its_duration(self, served):
+        _srv, (c, _) = served
+        tr, _t0, _t1 = _served_trace(lambda: c.query(SERVED_SQL))
+        plan = next(s for s in tr.spans if s.name == "session.plan")
+        assert list(plan.phases) == PLAN_PHASES
+        assert all(calls == 1 for _us, calls in plan.phases.values())
+        assert sum(us for us, _ in plan.phases.values()) <= plan.dur_us
+        # phases are no children: session.plan has none, its self time is
+        # its duration, and the statement's spans are what they were
+        assert _children(tr, plan) == []
+        assert tr.self_us()[plan.span_id] == plan.dur_us
+        stmt = next(s for s in tr.spans if s.name == "stmt.select")
+        assert [s.name for s in _children(tr, stmt)] == [
+            "session.plan", "session.execute"]
+
+    def test_live_rows_books_the_rows_it_read_on_the_callers_span(self, served):
+        srv, (c, _) = served
+        table = srv.catalog.table("test", "rt")
+        calls = []
+        real = type(table).live_rows
+
+        def spy(self):
+            calls.append(self.n)
+            return real.fget(self)
+
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(type(table), "live_rows", property(spy))
+            tr, _t0, _t1 = _served_trace(lambda: c.query(SERVED_SQL))
+        assert calls and set(calls) == {table.n}
+        assert tr.counts() == {"session.plan/rows_counted": sum(calls),
+                               "device.wait/bytes": tr.counts()["device.wait/bytes"]}
+        # outside any trace the count is the off path
+        assert tracing.current() is None
+        assert table.live_rows == 3
+
+    def test_device_wait_is_ready_then_copy_and_its_bytes(self, served):
+        _srv, (c, _) = served
+
+        def d2h():
+            return sum(v for lbl, v in M.XFER_BYTES.samples() if lbl.get("dir") == "d2h")
+
+        b0 = d2h()
+        tr, _t0, _t1 = _served_trace(lambda: c.query(SERVED_SQL))
+        waits = [s for s in tr.spans if s.name == "device.wait"]
+        assert waits
+        for s in waits:
+            assert list(s.phases) == ["ready", "copy"]
+            assert [calls for _us, calls in s.phases.values()] == [1, 1]
+            assert s.phases["ready"][0] + s.phases["copy"][0] <= s.dur_us
+            assert s.counts["bytes"] > 0
+            assert _children(tr, s) == []
+        assert tr.counts()["device.wait/bytes"] == d2h() - b0 > 0
+
+    def test_trace_records_what_a_select_records_and_prints_the_phases(self, served):
+        _srv, (c, _) = served
+        sel, _t0, _t1 = _served_trace(lambda: c.query(SERVED_SQL))
+        out = {}
+        tr, _t0, _t1 = _served_trace(
+            lambda: out.update(rows=c.query("trace " + SERVED_SQL)[1]))
+        stmt = next(s for s in tr.spans if s.name == "stmt.trace")
+        live = [s.name for s in _children(tr, stmt) if not s.name.startswith("executor.")]
+        assert live == ["session.plan", "session.execute"]
+        assert "session.build_executor" not in [s.name for s in tr.spans]
+        assert list(_plan_phases(tr)) == list(_plan_phases(sel)) == PLAN_PHASES
+        assert tr.counts()["session.plan/rows_counted"] \
+            == sel.counts()["session.plan/rows_counted"]
+        names = [str(r[0]) for r in out["rows"]]
+        at = names.index("session.plan")
+        assert names[at + 1:at + 7] == ["  session.plan/" + p for p in PLAN_PHASES]
+        assert any(n.strip() == "device.wait/copy" for n in names)
+        by_name = {n.strip(): r for n, r in zip(names, out["rows"])}
+        plan_ms = float(by_name["session.plan"][2])
+        assert sum(float(by_name["session.plan/" + p][2]) for p in PLAN_PHASES) \
+            <= plan_ms + 0.006  # six roundings to the microsecond
+
+    def test_a_replan_shows_as_two_calls(self, monkeypatch):
+        from tidb_tpu.session import session as session_mod
+
+        s = _quiet(Session())
+        s.execute("create table rp (a bigint, b bigint)")
+        s.execute("insert into rp values (1, 2), (3, 4)")
+        once = iter([True])
+        monkeypatch.setattr(s, "_dist_expected", lambda: True)
+        monkeypatch.setattr(session_mod, "_has_eager_partial", lambda _p: True)
+        monkeypatch.setattr(session_mod, "_dist_engaged",
+                            lambda _r: not next(once, False))
+        assert s.query("select a, sum(b) from rp group by a order by a") == [(1, 2), (3, 4)]
+        got = _plan_phases(tracing.STORE.finished()[-1])
+        assert {p: calls for p, (_us, calls) in got.items()} == {
+            "cache": 2, "bind": 2, "rules": 2, "lower": 2, "privs": 1, "build": 2}
+
+    def test_cluster_trace_shows_a_spans_parts(self):
+        s = Session()
+        s.execute("set tidb_trace_sample_rate = 1")
+        s.execute("set tidb_slow_log_threshold = 300000")
+        s.execute("create table ctp (a bigint)")
+        s.execute("insert into ctp values (1), (2)")
+        s.query("select count(*) from ctp")
+        tid = tracing.STORE.traces()[-1].trace_id
+        (notes,) = [r[0] for r in s.query(
+            "select annotations from information_schema.cluster_trace"
+            f" where trace_id = '{tid}' and name = 'session.plan'")]
+        assert re.search(r"phase:bind=\d+us/1", notes)
+        assert "count:rows_counted=" in notes
+
+    def test_phases_are_on_the_profilers_clock_inside_their_span(self, served, tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+
+        _srv, (c, _) = served
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            _served_trace(lambda: c.query(SERVED_SQL))
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        events = {}
+        for plane in ProfileData.from_file(str(path)).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("tidb."):
+                        events.setdefault(e.name, []).append((e.start_ns, e.duration_ns))
+        (p0, pd), = events["tidb.session.plan"]
+        for phase in PLAN_PHASES:
+            (start, dur), = events["tidb.session.plan/" + phase]
+            assert p0 <= start and start + dur <= p0 + pd
+        assert len(events["tidb.device.wait/ready"]) == len(events["tidb.device.wait"])
+        assert len(events["tidb.device.wait/copy"]) == len(events["tidb.device.wait"])
+
+
+def test_a_table_upload_is_a_span_with_its_bytes(devices8):
+    """``shard_table`` is the span ``stage.upload``; its count ``bytes``
+    is what went to ``device_put`` and what ``XFER_BYTES{h2d}`` gained;
+    the site counts stay as they were: two a column and one."""
+    from tidb_tpu.parallel import make_mesh
+
+    def h2d():
+        return sum(v for lbl, v in M.XFER_BYTES.samples() if lbl.get("dir") == "h2d")
+
+    def staged():
+        return sum(v for lbl, v in M.DISPATCH_TOTAL.samples()
+                   if lbl.get("site") == "stage")
+
+    s = _quiet(Session(mesh=make_mesh(devices=devices8[:4])))
+    s.execute("set tidb_device_engine_mode = 'force'")
+    s.execute("create table up (a bigint, b bigint)")
+    s.execute("insert into up values (1, 2), (3, 4), (5, 6)")
+    b0, n0 = h2d(), staged()
+    assert s.query("select sum(b) from up") == [(12,)]
+    first = tracing.STORE.finished()[-1]
+    (up,) = [sp for sp in first.spans if sp.name == "stage.upload"]
+    st = next(iter(s._shard_cache.resident()))[1]
+    arrays = list(st.data.values()) + list(st.valid.values()) + [st.sel]
+    assert up.counts == {"bytes": sum(a.nbytes for a in arrays)}
+    assert h2d() - b0 >= up.counts["bytes"] > 0
+    assert staged() - n0 == len(arrays) == 5
+    assert up.dur_us >= 0 and up.phases is None
+    assert s.query("select sum(b) from up") == [(12,)]  # resident: no upload
+    assert "stage.upload" not in [sp.name for sp in tracing.STORE.finished()[-1].spans]

@@ -22,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tidb_tpu.parallel.mesh import dcn_axis, shard_axis
 from tidb_tpu.types import SQLType
+from tidb_tpu.utils import tracing
 
 __all__ = ["ShardedTable", "shard_table", "stream_batches", "table_bytes"]
 
@@ -113,66 +114,76 @@ def shard_table(table, mesh: Mesh, columns: Optional[List[str]] = None,
                 encode: bool = False) -> ShardedTable:
     """Partition a host Table (or a row range of it) across the mesh's
     (dcn x shard) grid. ``encode=True`` stages integer-backed columns
-    FoR-encoded in narrow dtypes (see ShardedTable.refs)."""
-    n_parts = mesh.shape[dcn_axis] * mesh.shape[shard_axis]
-    lo, hi = row_range if row_range is not None else (0, table.n)
-    n = hi - lo
-    R = rows_per_part or max((n + n_parts - 1) // n_parts, 1)
-    if R * n_parts < n:
-        raise ValueError(f"rows_per_part {R} too small for {n} rows / {n_parts} parts")
-    names = columns or [c.name for c in table.schema.columns]
-    spec = NamedSharding(mesh, P((dcn_axis, shard_axis), None))
+    FoR-encoded in narrow dtypes (see ShardedTable.refs). The whole of
+    it (encode, pad, ``device_put``) is the span ``stage.upload`` with
+    the count ``bytes`` of what was handed to ``device_put``."""
+    with tracing.span("stage.upload"):
+        n_parts = mesh.shape[dcn_axis] * mesh.shape[shard_axis]
+        lo, hi = row_range if row_range is not None else (0, table.n)
+        n = hi - lo
+        R = rows_per_part or max((n + n_parts - 1) // n_parts, 1)
+        if R * n_parts < n:
+            raise ValueError(f"rows_per_part {R} too small for {n} rows / {n_parts} parts")
+        names = columns or [c.name for c in table.schema.columns]
+        spec = NamedSharding(mesh, P((dcn_axis, shard_axis), None))
 
-    live = np.zeros((n_parts, R), dtype=np.bool_)
-    data: Dict[str, jax.Array] = {}
-    valid: Dict[str, jax.Array] = {}
-    types: Dict[str, SQLType] = {}
-    dicts: Dict[str, object] = {}
-    refs: Dict[str, np.int64] = {}
+        live = np.zeros((n_parts, R), dtype=np.bool_)
+        data: Dict[str, jax.Array] = {}
+        valid: Dict[str, jax.Array] = {}
+        types: Dict[str, SQLType] = {}
+        dicts: Dict[str, object] = {}
+        refs: Dict[str, np.int64] = {}
 
-    host_cols = {}
-    for name in names:
-        info = table.schema.col(name)
-        d, v = table.column_slice(name, lo, hi)
-        if encode:
-            stored, ref = _encode_staged(d, v, info.type_)
-            if stored is not None:
-                d = stored
-                refs[name] = np.int64(ref)
-        buf = np.zeros((n_parts, R), dtype=d.dtype)
-        vbuf = np.zeros((n_parts, R), dtype=np.bool_)
-        host_cols[name] = (buf, vbuf, d, v)
-        types[name] = info.type_
-        dc = table.dicts.get(name)
-        if dc is not None:
-            dicts[name] = dc
-
-    row_live = table.live_mask(lo, hi)
-    for p in range(n_parts):
-        s, e = p * R, min((p + 1) * R, n)
-        if s >= n:
-            break
-        m = e - s
-        live[p, :m] = row_live[s:e]
+        host_cols = {}
         for name in names:
-            buf, vbuf, d, v = host_cols[name]
-            buf[p, :m] = d[s:e]
-            vbuf[p, :m] = v[s:e]
+            info = table.schema.col(name)
+            d, v = table.column_slice(name, lo, hi)
+            if encode:
+                stored, ref = _encode_staged(d, v, info.type_)
+                if stored is not None:
+                    d = stored
+                    refs[name] = np.int64(ref)
+            buf = np.zeros((n_parts, R), dtype=d.dtype)
+            vbuf = np.zeros((n_parts, R), dtype=np.bool_)
+            host_cols[name] = (buf, vbuf, d, v)
+            types[name] = info.type_
+            dc = table.dicts.get(name)
+            if dc is not None:
+                dicts[name] = dc
 
-    from tidb_tpu.utils import dispatch as dsp
-    from tidb_tpu.utils.device import note_placement
+        row_live = table.live_mask(lo, hi)
+        for p in range(n_parts):
+            s, e = p * R, min((p + 1) * R, n)
+            if s >= n:
+                break
+            m = e - s
+            live[p, :m] = row_live[s:e]
+            for name in names:
+                buf, vbuf, d, v = host_cols[name]
+                buf[p, :m] = d[s:e]
+                vbuf[p, :m] = v[s:e]
 
-    for name in names:
-        buf, vbuf, _, _ = host_cols[name]
-        data[name] = jax.device_put(buf, spec)
-        valid[name] = jax.device_put(vbuf, spec)
-        dsp.record(2, site="stage")
-    sel = jax.device_put(live, spec)
-    dsp.record(site="stage")
-    note_placement("shard", (data, valid, sel))
+        from tidb_tpu.utils import dispatch as dsp
+        from tidb_tpu.utils.device import note_placement
 
-    return ShardedTable(
-        mesh=mesh, n_parts=n_parts, rows_per_part=R, total_rows=n,
-        data=data, valid=valid, sel=sel, types=types, dicts=dicts,
-        refs=refs,
-    )
+        nbytes = live.nbytes
+        for name in names:
+            buf, vbuf, _, _ = host_cols[name]
+            data[name] = jax.device_put(buf, spec)
+            valid[name] = jax.device_put(vbuf, spec)
+            dsp.record(2, site="stage")
+            nbytes += buf.nbytes + vbuf.nbytes
+        sel = jax.device_put(live, spec)
+        dsp.record(site="stage")
+        # booked when device_put has returned, not when the bytes are on
+        # the device: no sync here, the statement's first fetch waits for
+        # what the transfers still owe
+        dsp.record_xfer(nbytes, "h2d")
+        tracing.add("bytes", nbytes)
+        note_placement("shard", (data, valid, sel))
+
+        return ShardedTable(
+            mesh=mesh, n_parts=n_parts, rows_per_part=R, total_rows=n,
+            data=data, valid=valid, sel=sel, types=types, dicts=dicts,
+            refs=refs,
+        )

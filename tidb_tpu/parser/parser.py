@@ -161,7 +161,7 @@ class Parser:
             "use": self.parse_use,
             "truncate": self.parse_truncate,
             "analyze": self.parse_analyze,
-            "trace": lambda: (self.next(), TraceStmt(self.parse_statement()))[1],
+            "trace": self.parse_trace,
             "grant": self.parse_grant,
             "revoke": self.parse_revoke,
             "install": self.parse_install,
@@ -1141,13 +1141,23 @@ class Parser:
                 and t.text not in _STMT_KWS):
             return ShowStmt("columns", target=self.expect_ident())
         analyze = bool(self.accept_kw("analyze"))
+        return ExplainStmt(self._parse_target(), analyze)
+
+    def parse_trace(self):
+        self.next()  # trace
+        return TraceStmt(self._parse_target())
+
+    def _parse_target(self):
+        """The statement an EXPLAIN or a TRACE is about, with its own
+        source text: it is planned as that statement is (its digest's
+        plan feedback, bindings, the plan cache)."""
         start = self.peek().pos
         inner = self.parse_statement()
         try:
             inner._source = self.sql[start : self.peek().pos].strip()
         except AttributeError:
             pass
-        return ExplainStmt(inner, analyze)
+        return inner
 
     def parse_set(self) -> SetStmt:
         self.expect_kw("set")

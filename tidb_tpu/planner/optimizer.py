@@ -16,6 +16,7 @@ from tidb_tpu.planner.physical import (
     resolve_topn_pushdown,
 )
 from tidb_tpu.planner.rules import optimize_logical
+from tidb_tpu.utils import tracing
 
 __all__ = ["plan_statement"]
 
@@ -37,13 +38,17 @@ def plan_statement(
     ctx = BuildContext(
         catalog=catalog, db=db, binder=binder, execute_subplan=execute_subplan
     )
-    logical = build_select(stmt, ctx)
-    logical = optimize_logical(logical, hints=getattr(stmt, "hints", ()) or (),
-                               cascades=cascades, n_parts=n_parts,
-                               agg_push_down=agg_push_down)
-    phys = inject_point_get(lower(logical))
-    if n_parts > 1:
-        _annotate_topn(phys)
+    # the three parts of the caller's span (``session.plan``), by name
+    with tracing.phase("bind"):
+        logical = build_select(stmt, ctx)
+    with tracing.phase("rules"):
+        logical = optimize_logical(
+            logical, hints=getattr(stmt, "hints", ()) or (),
+            cascades=cascades, n_parts=n_parts, agg_push_down=agg_push_down)
+    with tracing.phase("lower"):
+        phys = inject_point_get(lower(logical))
+        if n_parts > 1:
+            _annotate_topn(phys)
     return phys
 
 

@@ -1257,6 +1257,43 @@ class Session:
 
     def _acquire_plan_inner(self, stmt, agg_push_down):
         from tidb_tpu.planner import plancache as _pc
+        from tidb_tpu.utils import tracing
+
+        with tracing.phase("cache"):
+            phys, fill = self._probe_plan_cache(stmt, agg_push_down)
+        if phys is not None:
+            return phys
+        stmt = self._materialize_stmt(stmt)
+        if fill is None:
+            return self._plan_select(stmt, agg_push_down=agg_push_down)
+        cache, key, info, digest, sv = fill
+        used = [False]
+
+        def _sub(logical):
+            used[0] = True
+            return self._execute_subplan(logical)
+
+        phys = self._plan_select(stmt, agg_push_down=agg_push_down,
+                                 execute_subplan=_sub)
+        try:
+            new = _pc.build_entry(
+                stmt, phys, info, digest, self.db, sv,
+                plan_sentinel=lambda s2: self._plan_select(
+                    s2, agg_push_down=agg_push_down, execute_subplan=_sub),
+                subplan_used=lambda: used[0])
+            cache.store(key, new, sv)
+        except Exception:  # noqa: BLE001 — the cache must never fail
+            pass          # (or slow-path-block) the statement
+        return phys
+
+    def _probe_plan_cache(self, stmt, agg_push_down):
+        """What comes before planning: is the statement eligible for
+        the plan cache, its analysis, digest and lookup. Returns
+        ``(plan, None)`` on a hit, ``(None, None)`` where the statement
+        is planned and not cached (cache off, or a bypass, noted), and
+        ``(None, (cache, key, info, digest, schema_version))`` on a miss
+        the caller plans and fills."""
+        from tidb_tpu.planner import plancache as _pc
 
         self._last_plan_digest = None  # _run_select hashes the fresh
         # plan unless a cache hit installs the entry's memoized digest
@@ -1266,13 +1303,11 @@ class Session:
             else "tidb_enable_non_prepared_plan_cache"))
         cache = getattr(self.catalog, "plan_cache", None)
         if not enabled or cache is None:
-            return self._plan_select(self._materialize_stmt(stmt),
-                                     agg_push_down=agg_push_down)
+            return None, None
 
         def bypass(reason):
             cache.note_bypass(reason)
-            return self._plan_select(self._materialize_stmt(stmt),
-                                     agg_push_down=agg_push_down)
+            return None, None
 
         if self._lock_read:
             return bypass("locking read")
@@ -1335,27 +1370,9 @@ class Session:
                     from tidb_tpu.planner.optimizer import _annotate_topn
 
                     _annotate_topn(phys)  # re-derive on the patched tree
-                return phys
+                return phys, None
         cache.note_miss()
-        used = [False]
-
-        def _sub(logical):
-            used[0] = True
-            return self._execute_subplan(logical)
-
-        stmt = self._materialize_stmt(stmt)
-        phys = self._plan_select(stmt, agg_push_down=agg_push_down,
-                                 execute_subplan=_sub)
-        try:
-            new = _pc.build_entry(
-                stmt, phys, info, digest, self.db, sv,
-                plan_sentinel=lambda s2: self._plan_select(
-                    s2, agg_push_down=agg_push_down, execute_subplan=_sub),
-                subplan_used=lambda: used[0])
-            cache.store(key, new, sv)
-        except Exception:  # noqa: BLE001 — the cache must never fail
-            pass          # (or slow-path-block) the statement
-        return phys
+        return None, (cache, key, info, digest, sv)
 
     def _plan_cache_key(self, stmt, info, digest, eff_apd):
         """THE plan-cache key — shared by the probe/fill path above and
@@ -1719,15 +1736,22 @@ class Session:
                     f"transaction ({conflict})")
             _time.sleep(0.02)
 
-    def _run_select(self, stmt) -> ResultSet:
+    def _plan_and_build(self, stmt):
+        """(physical plan, executor tree) of a SELECT/UNION: planned,
+        privilege-checked and built under the ONE span ``session.plan``,
+        for an ordinary statement and for TRACE alike. The span's time
+        by phase (``tracing.phase``): ``cache`` (the plan cache's
+        analysis, digest and lookup), ``bind`` / ``rules`` / ``lower``
+        (``plan_statement``), ``privs``, ``build``; a re-plan shows as
+        two calls."""
         from tidb_tpu.utils import tracing
 
-        if self.txn is None and not self.sysvars.get("autocommit"):
-            self._begin()  # consistent-snapshot reads without autocommit
         with tracing.span("session.plan"):
             phys = self._acquire_plan(stmt)
-            self._check_plan_privs(phys)
-            root = self._build_root(phys)
+            with tracing.phase("privs"):
+                self._check_plan_privs(phys)
+            with tracing.phase("build"):
+                root = self._build_root(phys)
             if self._dist_expected() and _has_eager_partial(phys) \
                     and not _dist_engaged(root):
                 # the eager-agg shape kept this plan off the mesh (the
@@ -1736,7 +1760,16 @@ class Session:
                 # rewrite saves, so re-plan without it and keep the
                 # fragments (the no-push variant caches under its own key)
                 phys = self._acquire_plan(stmt, agg_push_down=False)
-                root = self._build_root(phys)
+                with tracing.phase("build"):
+                    root = self._build_root(phys)
+        return phys, root
+
+    def _run_select(self, stmt) -> ResultSet:
+        from tidb_tpu.utils import tracing
+
+        if self.txn is None and not self.sysvars.get("autocommit"):
+            self._begin()  # consistent-snapshot reads without autocommit
+        phys, root = self._plan_and_build(stmt)
         # plan digest: hash of the plan's shape (explain text), paired
         # with the statement digest in statements_summary/slow log so a
         # regressed plan choice is visible as a digest change; a cache
@@ -3367,12 +3400,9 @@ class Session:
             self._begin()  # same consistent-snapshot rule as _run_select
         tracing.keep("trace")  # the trace IS the output: always retain
         tr = tracing.current()
-        with tracing.span("session.plan"):
-            phys = self._plan_select(target)
-            self._check_plan_privs(phys)  # TRACE executes the statement
-        with tracing.span("session.build_executor"):
-            root = self._build_root(phys)
-            instrument(root)
+        # TRACE executes the statement, and as any other is executed
+        phys, root = self._plan_and_build(target)
+        instrument(root)
         with tracing.span("session.execute") as exec_span:
             run_plan(root, self._exec_ctx(plan=phys))
         if tr is not None and exec_span is not None:
@@ -3421,9 +3451,15 @@ class Session:
         rows: list = []
 
         def visit(s, depth):
-            rows.append(("  " * depth + s.name,
-                         round((s.start_us - base_start) / 1e3, 3),
+            start_ms = round((s.start_us - base_start) / 1e3, 3)
+            rows.append(("  " * depth + s.name, start_ms,
                          round(max(s.dur_us, 0) / 1e3, 3)))
+            # what the span's own time is made of (tracing.phase): no
+            # spans, so a row each under it, at the span's start
+            for k, (us, calls) in (s.phases or {}).items():
+                rows.append(("  " * (depth + 1) + f"{s.name}/{k}"
+                             + (f" x{calls}" if calls > 1 else ""),
+                             start_ms, round(us / 1e3, 3)))
             for c in sorted(children.get(s.span_id, ()),
                             key=lambda x: x.start_us):
                 visit(c, depth + 1)

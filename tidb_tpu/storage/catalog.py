@@ -912,7 +912,7 @@ class Catalog:
                     rows.append((
                         t.trace_id, ts, keep, s.span_id, s.parent_id,
                         s.name, s.proc or "local", s.start_us,
-                        max(s.dur_us, 0), ";".join(s.notes)))
+                        max(s.dur_us, 0), ";".join(s.notes + s.parts())))
             return make(
                 [("trace_id", STRING), ("time", STRING), ("keep", STRING),
                  ("span_id", INT64), ("parent_span_id", INT64),

@@ -108,27 +108,45 @@ def xfer_bytes() -> int:
 
 
 def record_fetch(tree):
-    """Record a COMPLETED device→host fetch's bytes (d2h) and return
-    the tree unchanged (the arrays are host-resident by the time this
-    sums nbytes, so the accounting itself never blocks)."""
+    """Record a COMPLETED device→host fetch's bytes (d2h, and the count
+    ``bytes`` of the span it is called under: ``device.wait``) and
+    return the tree unchanged (the arrays are host-resident by the time
+    this sums nbytes, so the accounting itself never blocks)."""
     n = sum(getattr(leaf, "nbytes", 0)
             for leaf in jax.tree_util.tree_leaves(tree))
     record_xfer(n, "d2h")
+    tracing.add("bytes", n)
     return tree
 
 
 def device_get(tree, counted: bool = True):
     """The one place where the host waits for the device:
     ``jax.device_get`` (a whole pytree in one transfer) inside the span
-    ``device.wait``, the fetched bytes booked, and — `counted` — the
+    ``device.wait``, the fetched bytes booked (``XFER_BYTES{d2h}`` and
+    the span's count ``bytes``), and — `counted` — the
     round trip as a ``fetch`` dispatch. Not `counted`: the per-chunk
     fetches of the host tiers and the exchange-overflow scalar, which
     the dispatch budget (O(1) a statement) has never held. The lint
     passes know the fetch by this name, as they know jax's."""
     with launch("fetch") if counted else contextlib.nullcontext():
         with tracing.span("device.wait"):
-            host = jax.device_get(tree)
-        return record_fetch(host)
+            # the span's two parts: until the device has the arrays
+            # ready, then what is left of their copy to the host and
+            # the conversion to numpy. The copies are asked for FIRST,
+            # as jax.device_get itself does, so that each follows its
+            # program on the device with no host round trip between:
+            # asked for after the wait, every fetch that had to wait
+            # paid one more (PERF.md section 6, PR 37: +0.6-1.2% on
+            # q18agg's statement of 25 fetches)
+            leaves = jax.tree_util.tree_leaves(tree)
+            for leaf in leaves:
+                if isinstance(leaf, jax.Array):
+                    leaf.copy_to_host_async()
+            with tracing.phase("ready"):
+                jax.block_until_ready(leaves)
+            with tracing.phase("copy"):
+                host = jax.device_get(tree)
+            return record_fetch(host)
 
 
 def record_spill(nbytes: int) -> None:

@@ -741,8 +741,8 @@ COMPACTION_BYTES = Counter(
 XFER_BYTES = Counter(
     "tidb_tpu_xfer_bytes_total",
     "Host<->device transfer bytes observed at the EXISTING staging/"
-    "fetch choke points (prefetcher stagings, probe-window and agg "
-    "drains), by dir: h2d, d2h — the process-wide mirror of the "
+    "fetch choke points (prefetcher stagings, the mesh tier's table "
+    "uploads, probe-window and agg drains), by dir: h2d, d2h — the process-wide mirror of the "
     "per-statement profile accounting; no new device syncs are paid "
     "to collect it")
 COMPILE_SECONDS = Counter(

@@ -41,6 +41,18 @@ of the ``.xplane.pb`` holds the span tree on the device ops' clock.
 Spans recorded after the fact (``add_complete``: they cross threads or
 were timed by other code) are on the host clock only.
 
+Inside one span (PR 37): a span is a layer boundary, and the readers
+group time by span name, so what a layer's own time is made of is NOT
+told by child spans. ``phase(name)`` times a named part of the
+innermost open span on that span (``Span.phases``: microseconds and
+calls by name) and ``add(name, n)`` keeps a named count on it
+(``Span.counts``). Neither opens a span: the tree, every duration and
+every self time read as without them. A phase holds whatever child
+spans open inside it, and a phase opened inside a phase of the same
+span counts its time twice: the sites keep them apart. A phase is also
+the annotation ``tidb.<span>/<phase>``. Neither crosses processes:
+``export``/``graft`` carry a worker's spans without their parts.
+
 The off path must stay near-free: with no trace installed every hook is
 one thread-local read and a None check. What it costs when on, measured
 on the chip (PR 25, PERF.md section 6): with a profiler session open the
@@ -62,7 +74,7 @@ from jax.profiler import TraceAnnotation
 __all__ = ["Span", "Trace", "TraceStore", "STORE", "current", "push",
            "pop", "span", "begin", "finish", "annotate", "capture",
            "current_span_id", "head_sampled", "make_trace_id", "keep",
-           "current_trace_id"]
+           "current_trace_id", "phase", "add"]
 
 _SEQ = itertools.count(1)
 
@@ -89,7 +101,7 @@ def head_sampled(rate: float) -> bool:
 
 class Span:
     __slots__ = ("span_id", "parent_id", "name", "start_us", "dur_us",
-                 "proc", "notes", "ann")
+                 "proc", "notes", "ann", "phases", "counts")
 
     def __init__(self, span_id: int, parent_id: Optional[int], name: str,
                  start_us: int):
@@ -101,6 +113,15 @@ class Span:
         self.proc = ""    # "" = this process; set on graft to the endpoint
         self.notes: List[str] = []
         self.ann = None   # the open TraceAnnotation (begin .. finish)
+        self.phases: Optional[Dict[str, List[int]]] = None  # name -> [us, calls]
+        self.counts: Optional[Dict[str, int]] = None
+
+    def parts(self) -> List[str]:
+        """The span's phases and counts as notes, for a reader that
+        shows a span as one row (``cluster_trace``'s annotations)."""
+        return ([f"phase:{k}={us}us/{calls}"
+                 for k, (us, calls) in (self.phases or {}).items()]
+                + [f"count:{k}={n}" for k, n in (self.counts or {}).items()])
 
 
 class _NullNotes(list):
@@ -292,6 +313,27 @@ class Trace:
             out[s.name] = out.get(s.name, 0) + by_id.get(s.span_id, 0)
         return out
 
+    def phases_us(self) -> Dict[str, List[int]]:
+        """``"<span name>/<phase>"`` -> [microseconds, calls], summed
+        over the trace's spans (see ``phase``)."""
+        out: Dict[str, List[int]] = {}
+        for s in list(self.spans):
+            for k, (us, calls) in (s.phases or {}).items():
+                tot = out.setdefault(f"{s.name}/{k}", [0, 0])
+                tot[0] += us
+                tot[1] += calls
+        return out
+
+    def counts(self) -> Dict[str, int]:
+        """``"<span name>/<count>"`` -> n, summed over the trace's
+        spans (see ``add``)."""
+        out: Dict[str, int] = {}
+        for s in list(self.spans):
+            for k, n in (s.counts or {}).items():
+                key = f"{s.name}/{k}"
+                out[key] = out.get(key, 0) + n
+        return out
+
     def summary(self) -> Dict:
         root = self.root()
         return {
@@ -317,7 +359,9 @@ class Trace:
                 "span_id": s.span_id, "name": s.name, "proc": s.proc,
                 "start_us": s.start_us, "duration_us": max(s.dur_us, 0),
                 "self_us": self_us.get(s.span_id, 0),
-                "annotations": list(s.notes), "children": [],
+                "annotations": list(s.notes),
+                "phases": {k: list(v) for k, v in (s.phases or {}).items()},
+                "counts": dict(s.counts or {}), "children": [],
             }
         roots = []
         for s in spans:
@@ -448,6 +492,63 @@ class span:
     def __exit__(self, *_exc) -> bool:
         finish(self._span)
         return False
+
+
+def _innermost() -> Optional[Span]:
+    """The thread's innermost open span that records: None without a
+    trace, with no span open, or on the dropped-span sentinel."""
+    stack = getattr(_tls, "stack", None)
+    if not stack:
+        return None
+    spans = stack[-1][1]
+    if not spans or spans[-1] is _DROPPED:
+        return None
+    return spans[-1]
+
+
+class phase:
+    """``with phase(name)``: a named part of the innermost open span's
+    time, accumulated on that span as ``phases[name] = [us, calls]``
+    (module doc). No span, no span id, no node of the tree. A no-op
+    where there is no span to put it on: one TLS read. A class for the
+    reason ``span`` is one."""
+
+    __slots__ = ("_name", "_span", "_ann", "_t0")
+
+    def __init__(self, name: str):
+        self._name = name
+
+    def __enter__(self) -> None:
+        s = self._span = _innermost()
+        if s is not None:
+            self._ann = TraceAnnotation(f"tidb.{s.name}/{self._name}")
+            self._ann.__enter__()
+            self._t0 = time.perf_counter()
+
+    def __exit__(self, *_exc) -> bool:
+        s = self._span
+        if s is not None:
+            us = int((time.perf_counter() - self._t0) * 1e6)
+            self._ann.__exit__(None, None, None)
+            if s.phases is None:
+                s.phases = {}
+            got = s.phases.get(self._name)
+            if got is None:
+                s.phases[self._name] = [us, 1]
+            else:
+                got[0] += us
+                got[1] += 1
+        return False
+
+
+def add(name: str, n: int) -> None:
+    """Add `n` to the count `name` of the innermost open span (module
+    doc); a no-op where there is none."""
+    s = _innermost()
+    if s is not None:
+        if s.counts is None:
+            s.counts = {}
+        s.counts[name] = s.counts.get(name, 0) + n
 
 
 def annotate(note: str) -> None:
