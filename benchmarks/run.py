@@ -543,6 +543,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
                    round((r["t_send"] - marks["start_ns"]) / 1e9, 2),
                    round((r["t_done"] - r["t_send"]) / 1e6, 1)]
                   for r in sorted(records, key=lambda r: r["t_send"] - r["t_done"])[:6]],
+         # every statement's ms, in the order sent: a slow start or a slow
+         # stretch shows here, a slow machine shows in all of them
+         latencies_ms=[round((r["t_done"] - r["t_send"]) / 1e6, 1) for r in records],
          by_item={i: sum(1 for r in records if r["item"] == i)
                   for i in range(len(menu))},
          # [k, ms from BEGIN to the COMMIT's OK, ms of that in the COMMIT]

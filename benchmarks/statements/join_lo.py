@@ -1,8 +1,8 @@
 """lineitem JOIN orders, filtered on the orders side, COUNT and SUM over
 the matches: the largest join of TPC-H (the core of Q3, Q4, Q12, Q18 and
-Q21) with the smallest output, the statement of ``chip_smoke.py`` and of
-``bench.py``'s join microbench. Parameter from the traffic file's menu:
-``{"totalprice": 100000}`` (dollars)."""
+Q21) with the smallest output, and the join statement of ``chip_smoke.py``.
+Parameter from the traffic file's menu: ``{"totalprice": 100000}``
+(dollars)."""
 
 import numpy as np
 

@@ -15,6 +15,8 @@ the column's scale (2), dates are days since 1970-01-01, strings are
 codes into sorted pools. Departures (listed in every configuration's
 ``assumed``): order keys are dense 1..N (dbgen leaves gaps), text
 columns draw from small pools, and it is not dbgen's random stream.
+O_CUSTKEY is the clause's: uniform over the customer keys that are no
+multiple of 3, so a third of the customers have no orders (Q13, Q22).
 
 Every seed gives the same row counts (lineitem is SF x 6,001,215
 exactly, as the spec's table of cardinalities has it), so every seed
@@ -104,6 +106,11 @@ def phone_pool():
     pool = sorted(f"{10 + n}-{a:03d}-{(7 * a) % 1000:03d}-{(13 * a) % 10000:04d}"
                   for n in range(25) for a in range(40))
     return pool
+
+
+def _kth_not_divisible_by_3(k: np.ndarray) -> np.ndarray:
+    """The `k`-th (from 0) positive integer that is no multiple of 3."""
+    return 3 * (k // 2) + k % 2 + 1
 
 
 def generate(sf: float, seed: int) -> dict:
@@ -216,7 +223,8 @@ def _orders_with_lines(rng, okeys: np.ndarray, lines: np.ndarray, sf: float,
         "l_shipinstruct": INSTRUCT, "l_shipmode": SHIPMODES,
         "l_comment": COMMENTS})
     orders = ({
-        "o_orderkey": okeys, "o_custkey": rng.integers(1, nc + 1, no),
+        "o_orderkey": okeys, "o_custkey": _kth_not_divisible_by_3(
+            rng.integers(0, nc - nc // 3, no)),
         # sorted pool F O P: all lines shipped, none, some
         "o_orderstatus": np.where(n_f == lines, 0, np.where(n_f == 0, 1, 2)),
         "o_totalprice": np.add.reduceat(

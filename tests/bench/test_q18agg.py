@@ -61,7 +61,7 @@ def test_the_cell_is_made_of_new_files_and_appended_entries_only():
     assert cell.config["rows"] == spec.Cell("tpch_sf1.scan").config["rows"]
     assert cell.config["guarantees"]["arithmetic"] == \
         spec.Cell("tpch_sf1.scan").config["guarantees"]["arithmetic"]
-    assert cell.traffic["streams"] == 1 and cell.traffic["warm_passes"] == 1
+    assert cell.traffic["streams"] == 1 and cell.traffic["warm_passes"] == 4
     assert [m["params"] for m in cell.traffic["menu"]] == [{"quantity": 300},
                                                           {"quantity": 313}]
     assert [m["name"] for m in cell.end_to_end()] == ["rows_per_s", "stmt_p50_ms",

@@ -122,7 +122,9 @@ def test_what_still_holds_of_q18aggs_own_entries_is_held_by_name():
     assert cell.config["rows"] == scan.config["rows"]
     assert cell.config["guarantees"]["arithmetic"] == \
         scan.config["guarantees"]["arithmetic"]
-    assert cell.traffic["streams"] == 1 and cell.traffic["warm_passes"] == 1
+    # four warm passes: an item's second or third execution is slow, and
+    # with one pass it fell in the window
+    assert cell.traffic["streams"] == 1 and cell.traffic["warm_passes"] == 4
     assert [m["params"] for m in cell.traffic["menu"]] == [{"quantity": 300},
                                                           {"quantity": 313}]
     assert [m["name"] for m in cell.end_to_end()] == ["rows_per_s", "stmt_p50_ms",
