@@ -766,15 +766,25 @@ def test_fragment_joins_expand_by_32bit_marks_and_stacked_gathers(
     scatter-add is in this text, lowered through a sort (230 ms a
     statement over Q3's 3.1M slots: PERF.md section 5) — and under every
     `join<j>/join.gather` the gathers are an int64 stack's two u32 halves a
-    side (`_take_stacked`; a one-row stack, Q18's semi-join's probe side,
-    is `u32[n]`) and a float64 stack's two f32 halves where a float rides:
-    no validity mask (`pred`) and no column by itself
-    (tests/test_join_program_text.py counts the stacks a side)."""
+    side (`_take_stacked`) and a float64 stack's two f32 halves where a
+    float rides: no validity mask (`pred`) and no column by itself
+    (tests/test_join_program_text.py counts the stacks a side). Q18's
+    semi-join (join2) expands nothing: no op of it under
+    either scope, and under its `join.exists` no scatter and no gather."""
     import re
 
+    exists = {"q3": (), "q18": (2,)}[q]
     for prog, fn, text in speck_programs(q, n_dev):
-        ops = _ops_by_scope(text, r"(join\d+)/join\.(expand|gather)")
+        ops = _ops_by_scope(text, r"(join\d+)/join\.(expand|gather|exists)")
+        # (on four parts Q18's subquery is a program of its own, no join)
+        assert prog.n_exists == len([j for j in exists if j < prog.n_join])
         for j in range(prog.n_join):
+            if j in exists:
+                assert not ops.get((f"join{j}", "expand")), (j, sorted(ops))
+                assert not ops.get((f"join{j}", "gather")), (j, sorted(ops))
+                assert not [o for o, _ in ops.get((f"join{j}", "exists"), [])
+                            if o in ("scatter", "gather")], (j, ops)
+                continue
             expand = ops.get((f"join{j}", "expand"), [])
             scatters = [r for o, r in expand if o == "scatter"]
             assert len(scatters) == 1, (j, expand)
@@ -786,6 +796,25 @@ def test_fragment_joins_expand_by_32bit_marks_and_stacked_gathers(
             assert kinds.count("u32") + kinds.count("f32") == len(kinds), (
                 j, gathers)
             assert kinds.count("f32") <= 4, (j, gathers)
+
+
+@pytest.mark.parametrize("n_dev", [1, 4], ids=["1x1", "1x4"])
+def test_q22_compiles_its_scalar_subquery_and_existence_join(speck_programs,
+                                                            n_dev):
+    """In the chip's own compiled text, Q22 whole is ONE program —
+    its AVG's masked sum and count under `scalar0` (an all-reduce across
+    four chips), the bounds' 64-bit division, and the NOT EXISTS decided
+    from the rank's count: under `join0/join.exists` no scatter and no
+    gather, and no op of the join under `join.expand` or `join.gather`."""
+    (prog, fn, text), = speck_programs("q22", n_dev)
+    assert prog.n_scalar == 1 and prog.n_exists == 1 and prog.n_join == 1
+    assert "expand" not in prog.growth_kinds
+    ops = _ops_by_scope(text, r"(join0)/join\.(expand|gather|exists)")
+    assert not ops.get(("join0", "expand")) and not ops.get(("join0", "gather"))
+    assert not [o for o, _ in ops.get(("join0", "exists"), [])
+                if o in ("scatter", "gather")], ops
+    assert "/scalar0/" in text
+    assert ("all-reduce" in text) == (n_dev > 1)
 
 
 def _one_program_cold(catalog, prog, growths):

@@ -1,13 +1,18 @@
 """The general fragment's joins as the compiler receives them (PR 38):
-the lowered text of TPC-H Q3's and Q18's one program at SF0.01, each op
-under the scope its ``op_name`` gives. A join's expansion
+the lowered text of TPC-H Q3's, Q18's and Q22's one program at SF0.01,
+each op under the scope its ``op_name`` gives. A join's expansion
 (`join<j>/join.expand`) marks its row boundaries by ONE scatter-add of
 32 bits — a 64-bit one this compiler lowers through a sort (230 ms a
 statement over Q3's 3.1M slots, PERF.md section 5) — and reads each
 slot's row by ONE gather of a [2, R] stack; its `join<j>/join.gather`
 takes every array a side needs by ONE stack a side (one more where a
 float rides), not a gather per column's data and validity. `_compact`,
-whose stack-and-take the join now shares, lowers as it did in PR 36."""
+whose stack-and-take the join now shares, lowers to one scatter and one
+gather (the last test).
+
+A semi or anti join on one key (Q18's IN, Q22's NOT EXISTS) expands
+nothing: its `join<j>/join.exists` reads the rank's count, with no
+scatter and no gather."""
 
 import re
 
@@ -21,7 +26,9 @@ from tidb_tpu.planner import feedback
 from tidb_tpu.session import Session
 from tidb_tpu.storage.tpch_queries import Q
 
-N_JOINS = {"q3": 2, "q18": 3}
+N_JOINS = {"q3": 2, "q18": 3, "q22": 1}
+# the joins that decide existence from the rank's count and expand nothing
+EXISTS = {"q3": (), "q18": (2,), "q22": (0,)}
 
 
 def lowered_ops(text: str) -> list:
@@ -105,7 +112,7 @@ def in_scope(ops, scope: str) -> list:
 @pytest.mark.parametrize("q", sorted(N_JOINS))
 def test_a_joins_expansion_scatters_32_bits_and_gathers_one_stack(programs,
                                                                   q):
-    for j in range(N_JOINS[q]):
+    for j in set(range(N_JOINS[q])) - set(EXISTS[q]):
         ops = in_scope(programs[q], f"join{j}/join.expand")
         scatters = [operands for o, operands in ops if o == "scatter"]
         # the boundary marks: int32 slots, int32 ones
@@ -123,15 +130,32 @@ def test_a_joins_expansion_scatters_32_bits_and_gathers_one_stack(programs,
 @pytest.mark.parametrize("q", sorted(N_JOINS))
 def test_a_joins_gather_takes_one_stack_a_side(programs, q):
     """Q3's and Q18's columns are integers (decimals as scaled int64,
-    dates, dictionary codes): one int64 stack a side, the semi-join's
-    probe side its selection alone, its build side nothing."""
-    for j in range(N_JOINS[q]):
+    dates, dictionary codes): one int64 stack a side."""
+    for j in set(range(N_JOINS[q])) - set(EXISTS[q]):
         ops = in_scope(programs[q], f"join{j}/join.gather")
         gathers = [operands[0] for o, operands in ops if o == "gather"]
         assert 1 <= len(gathers) <= 2, (j, gathers)
         assert all(len(shape) == 2 and t == "i64" for shape, t in gathers), (
             j, gathers)
         assert not [o for o, _ in ops if o == "scatter"], (j, ops)
+
+
+@pytest.mark.parametrize("q", sorted(N_JOINS))
+def test_an_existence_join_holds_no_scatter_and_no_gather(programs, q):
+    """`join<j>/join.exists` is the comparison of the rank's count with 0
+    and the semantics' masks: no scatter of match flags, no gather; and
+    such a join has no `join.expand` and no `join.gather` at all. Every
+    other join has both."""
+    for j in range(N_JOINS[q]):
+        exists = in_scope(programs[q], f"join{j}/join.exists")
+        moved = (in_scope(programs[q], f"join{j}/join.expand")
+                 + in_scope(programs[q], f"join{j}/join.gather"))
+        if j in EXISTS[q]:
+            assert exists and not moved, (j, moved)
+            assert not [o for o, _ in exists if o in ("scatter", "gather")], (
+                j, exists)
+        else:
+            assert moved and not exists, (j, exists)
 
 
 def test_compact_alone_lowers_to_one_32bit_scatter_and_one_gather():

@@ -139,6 +139,7 @@ def test_q18_on_one_part_is_one_program_and_one_launch(devices8, loads, seed, qu
     (prog, _args, _shapes, _types, growths, grown), = seen
     assert prog.n_subquery == 1 and not prog.broadcasts and prog.n_exchange == 0
     assert prog.n_join == 3 and prog.out_kind == "generic"
+    assert prog.n_exists == 1  # the semi-join: its rank's count, no expansion
     # lineitem, which the statement names twice, is ONE argument of the
     # program (the compiler then shares the two sort-reduces' sort), and
     # such a source is never streamed in batches
@@ -157,22 +158,23 @@ def test_q18_on_one_part_is_one_program_and_one_launch(devices8, loads, seed, qu
 @pytest.mark.parametrize("quantity", [MANY, NONE], ids=["many", "none"])
 def test_a_q18_launch_counts_its_compactions_the_subquerys_among_them(
         devices8, loads, quantity):
-    """FRAGMENT_COMPACTIONS{kind} (PR 36): of the program's 17 knobs
+    """FRAGMENT_COMPACTIONS{kind}: of the program's 16 knobs
     (join0's expand and three targets 0-3, join1's 4-7, the subquery's
-    input, table and survivors 8-10, join2's 11-14, the root's 15-16)
-    the trace compacts, at this scale, join1's probe side (lineitem's
-    eager partial, 5), **the HAVING's survivors (10)** and join2's output
-    (14) — whatever passes the HAVING, none included: the count is the
-    program's, static — and a launch from the fragment cache adds the
-    same three."""
+    input, table and survivors 8-10, join2's three targets 11-13 — the
+    semi-join decides existence from its rank's count, no expand knob —
+    the root's 14-15) the trace compacts, at this scale,
+    join1's probe side (lineitem's eager partial, 5), **the HAVING's
+    survivors (10)** and join2's output (13) — whatever passes the
+    HAVING, none included: the count is the program's, static — and a
+    launch from the fragment cache adds the same three."""
     catalog, _data, _oracle = loads[SEEDS[0]]
     s = session(catalog, devices8, 1)
     c0 = samples(FRAGMENT_COMPACTIONS)
     _rows, seen, _trace = run_spied(s, Q18.sql({"quantity": quantity}))
     (prog, _args, _shapes, _types, growths, grown), = seen
-    assert growths == grown == prog.growth_defaults and prog.n_growth == 17
+    assert growths == grown == prog.growth_defaults and prog.n_growth == 16
     (fn,) = [f for k, f in s._shard_cache.fragments.items() if k[0] == "frag"]
-    assert fn.compactions == [5, 10, 14]
+    assert fn.compactions == [5, 10, 13]
     assert delta(FRAGMENT_COMPACTIONS, c0) == {("general_generic", ""): 3}
     c0 = samples(FRAGMENT_COMPACTIONS)
     s.query(Q18.sql({"quantity": quantity}))

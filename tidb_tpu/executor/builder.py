@@ -145,7 +145,9 @@ def _build_hash_join(plan: PHashJoin) -> HashJoinExec:
         plan.schema,
         build_executor(probe_plan),
         build_executor(build_plan),
-        plan.kind,
+        # a scalar subquery's one row joins every probe row: a keyless
+        # inner join (the mesh tier compiles it into its program)
+        "inner" if plan.kind == "scalar" else plan.kind,
         probe_keys,
         build_keys,
         other_cond=plan.other_cond,

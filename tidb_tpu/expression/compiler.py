@@ -325,6 +325,26 @@ def _mod(e: Call, chunk) -> Pair:
     return r, va & vb & ~zero
 
 
+def _avg_bound(up: bool):
+    """``avg_floor(sum, count)`` / ``avg_ceil(sum, count)``: the AVG of
+    an exact column as an integer at the sum's own scale, rounded down /
+    up, NULL where the count is 0. An exact value x at that scale lies
+    over the AVG iff x > avg_floor, under it iff x < avg_ceil (the
+    comparison ``x * count cmp sum`` without the product, which could
+    leave int64): planner/logical.py _scalar_subquery_join."""
+
+    def fn(e: Call, chunk) -> Pair:
+        (s, vs), (c, vc) = (eval_expr(a, chunk) for a in e.args)
+        s, c = s.astype(jnp.int64), c.astype(jnp.int64)
+        ok = vs & vc & (c > 0)
+        c = jnp.where(ok, c, 1)
+        q = jax.lax.div(s, c)  # toward zero
+        off = (s - q * c != 0) & ((s > 0) if up else (s < 0))
+        return q + off if up else q - off, ok
+
+    return fn
+
+
 def _neg(e: Call, chunk) -> Pair:
     d, v = eval_expr(e.args[0], chunk)
     return -d, v
@@ -687,6 +707,8 @@ FUNCS = {
     "div": _div,
     "intdiv": _intdiv,
     "mod": _mod,
+    "avg_floor": _avg_bound(False),
+    "avg_ceil": _avg_bound(True),
     "neg": _neg,
     "eq": _cmp(lambda a, b: a == b),
     "ne": _cmp(lambda a, b: a != b),

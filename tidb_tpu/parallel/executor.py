@@ -59,7 +59,8 @@ __all__ = ["ShardCache", "build_dist_executor", "DistAggExec", "DistJoinAggExec"
 @contextlib.contextmanager
 def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
                      reduced: Tuple[int, int] = (0, 0), joins=(),
-                     subqueries: Tuple[int, int] = (0, 0), compactions=()):
+                     subqueries: Tuple[int, ...] = (0, 0, 0), compactions=(),
+                     exists: int = 0):
     """One fragment launch: the span ``fragment.<kind>[parts=N]`` on the
     statement's trace and the FRAGMENT_SECONDS collector for /metrics
     (with a trace_id exemplar). Wall time covers the launch plus any
@@ -75,15 +76,18 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
     path of each (FRAGMENT_JOINS; read after the launch: a program's
     first call fills the list as it traces), and the subtrees it takes as
     build sides that are no scans, `subqueries` = (compiled into the
-    program, answered through the host and broadcast)
-    (FRAGMENT_SUBQUERIES), and the compactions its trace took,
-    `compactions` = the knob of each (FRAGMENT_COMPACTIONS; read after
-    the launch, as `joins`)."""
+    program, answered through the host and broadcast, scalar subqueries
+    compiled into the program) (FRAGMENT_SUBQUERIES), and the compactions
+    its trace took, `compactions` = the knob of each
+    (FRAGMENT_COMPACTIONS; read after the launch, as `joins`), and the
+    semi and anti joins it decides from the rank's count alone, `exists`
+    (FRAGMENT_EXISTENCE_JOINS)."""
     from tidb_tpu.utils import tracing
     from tidb_tpu.utils.metrics import (
         FRAGMENT_COMPACTIONS,
         FRAGMENT_DISPATCH,
         FRAGMENT_EXCHANGE_STEPS,
+        FRAGMENT_EXISTENCE_JOINS,
         FRAGMENT_JOINS,
         FRAGMENT_REDUCE_PAYLOADS,
         FRAGMENT_SECONDS,
@@ -99,8 +103,9 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
         FRAGMENT_REDUCE_PAYLOADS.inc(n, kind=kind, path=path)
     for probe in joins:
         FRAGMENT_JOINS.inc(kind=kind, probe=probe)
-    for path, n in zip(("inline", "broadcast"), subqueries):
+    for path, n in zip(("inline", "broadcast", "scalar"), subqueries):
         FRAGMENT_SUBQUERIES.inc(n, kind=kind, path=path)
+    FRAGMENT_EXISTENCE_JOINS.inc(exists, kind=kind)
     FRAGMENT_COMPACTIONS.inc(len(compactions), kind=kind)
     FRAGMENT_SECONDS.observe(time.perf_counter() - t0, kind=kind)
 
@@ -650,8 +655,9 @@ class DistFragmentExec(HashAggExec):
                 key, lambda: prog.build_fn(growths, probe_mode=probe_mode))
             with _fragment_launch(kind, n_parts, prog.n_exchange,
                                   prog.n_reduce, fn.join_probes,
-                                  (prog.n_subquery, len(prog.broadcasts)),
-                                  fn.compactions):
+                                  (prog.n_subquery, len(prog.broadcasts),
+                                   prog.n_scalar),
+                                  fn.compactions, prog.n_exists):
                 out, ovf = fn(*args)
             # host-sync: the per-knob overflow vector (a few int64s)
             # gates the capacity-retry loop — one fetch per dispatch

@@ -68,7 +68,12 @@ row boundaries by ONE 32-bit scatter-add and reads each output slot's
 first slot and build start by one gather of a [2, R] stack
 (`ops/join_kernels.py tile_positions`); ``join.gather`` takes every
 array a side needs by ONE stacked gather (`_take_stacked`, `_compact`'s
-own), not a gather per column's data and validity.
+own), not a gather per column's data and validity. A semi or anti join
+on one key with no other condition moves no row at all: its
+``join.exists`` reads whether each probe slot matched off the rank's
+count (`n_exists`). An uncorrelated scalar aggregate subquery is one row
+the program computes under ``scalar<k>`` (`_scalar_producer`,
+`n_scalar`), not a literal the planner made by running it first.
 """
 
 from __future__ import annotations
@@ -97,7 +102,7 @@ from tidb_tpu.executor.builder import peel_stages, scan_stages_for
 from tidb_tpu.executor.scan import make_pipeline_fn
 from tidb_tpu.expression.compiler import compile_predicate, eval_expr
 from tidb_tpu.ops import prefix
-from tidb_tpu.parallel.distsql import merge_state, pmax, repartition_by_key
+from tidb_tpu.parallel.distsql import merge_state, pmax, pmin, repartition_by_key
 from tidb_tpu.parallel.mesh import dcn_axis, shard_axis
 from tidb_tpu.planner.physical import PHashAgg, PHashJoin, PScan
 from tidb_tpu.types import TypeKind
@@ -109,6 +114,9 @@ _SPEC = P(_AXES, None)
 
 # rows above this don't broadcast — the subtree is too big to replicate
 BROADCAST_LIMIT = 1 << 21
+
+# what a scalar subquery compiled into the program may compute
+_SCALAR_AGGS = ("sum", "count", "min", "max")
 
 
 # group/join key identity bits: same rule as the local sort-reduce
@@ -300,8 +308,13 @@ class FragmentProgram:
     n_reduce: Tuple[int, int]
     n_join: int                        # joins compiled in (_join_producer)
     # aggregate subqueries compiled in (_subquery_agg_producer; the
-    # others are `broadcasts`) — FRAGMENT_SUBQUERIES
+    # others are `broadcasts`) and scalar subqueries (_scalar_producer)
+    # — FRAGMENT_SUBQUERIES
     n_subquery: int
+    n_scalar: int
+    # semi and anti joins decided by the rank's count (_join_producer)
+    # — FRAGMENT_EXISTENCE_JOINS
+    n_exists: int
     sig: str
     # (growths tuple) -> per-shard program; its `join_probes` lists, once
     # traced, the probe path of each join ("table" | "search"), join0 first,
@@ -349,8 +362,11 @@ class _Compiler:
         self.reduce_paths: List[str] = []
         # joins compiled into the program, numbered as their ops run
         self.n_join = 0
-        # aggregate subqueries compiled into the program, likewise
+        # aggregate subqueries compiled into the program, likewise; the
+        # scalar ones; the joins that only test existence
         self.n_subquery = 0
+        self.n_scalar = 0
+        self.n_exists = 0
 
     def _add_growth(self, default: float, kind: str) -> int:
         idx = self.n_growth
@@ -608,9 +624,95 @@ class _Compiler:
 
         return emit
 
+    # -- a scalar subquery ------------------------------------------------
+
+    def _scalar_producer(self, join: PHashJoin) -> Callable:
+        """A join with the one row of an uncorrelated scalar aggregate
+        subquery (kind "scalar": planner/logical.py
+        _scalar_subquery_join) as a producer of the program: the
+        subquery's input reduced to one row where it is read — a sum,
+        a count, a minimum or a maximum each a masked reduction of the
+        part's slots, combined across parts by psum / pmin / pmax, no
+        exchange — its stages (an AVG's exact bounds) run over that row,
+        and every column of it broadcast to the probe side's slots.
+        Nothing of the answer is a literal of the program: one program
+        for every state of the data."""
+        stages, agg = peel_stages(join.children[1])
+        if not (isinstance(agg, PHashAgg) and not agg.group_exprs
+                and all(not a.distinct and a.func in _SCALAR_AGGS
+                        and (a.arg is None or a.arg.type_.kind in (
+                            TypeKind.INT, TypeKind.DECIMAL))
+                        for a in agg.aggs)):
+            raise _Unsupported("scalar subquery other than exact aggregates")
+        _, base = peel_stages(agg.child)
+        if not (isinstance(base, PHashJoin)
+                or (isinstance(base, PScan) and base.table is not None)):
+            raise _Unsupported("scalar subquery over non-scan/join subtree")
+        probe_emit = self.producer(join.children[0])
+        n_before = len(self.sources)
+        child_emit = self.producer(agg.child)
+        # the one row reduces every row of its input: nothing under it
+        # is streamed in batches
+        self.stream_unsafe.update(range(n_before, len(self.sources)))
+        pipe = make_pipeline_fn(stages) if stages else (lambda c: c)
+        types = {c.uid: c.type_ for c in agg.schema}
+        out_uids = [c.uid for c in join.children[1].schema]
+        scope = f"scalar{self.n_scalar}"
+        self.n_scalar += 1
+        several = self.n_parts > 1
+        self.sig.append(f"scalar:{agg.aggs!r}:{stages!r}")
+
+        def one_row(chunk: Chunk) -> Chunk:
+            cols = {}
+            for a in agg.aggs:
+                if a.arg is None:
+                    data, valid = chunk.sel, chunk.sel
+                else:
+                    data, valid = eval_expr(a.arg, chunk)
+                    valid = valid & chunk.sel
+                n = jnp.sum(valid, dtype=jnp.int64)
+                if several:
+                    n = jax.lax.psum(n, _AXES)
+                data = data.astype(jnp.int64)
+                if a.func == "count":
+                    v = n
+                elif a.func == "sum":
+                    v = jnp.sum(jnp.where(valid, data, 0))
+                    v = jax.lax.psum(v, _AXES) if several else v
+                else:
+                    big = jnp.iinfo(jnp.int64)
+                    fill = big.max if a.func == "min" else big.min
+                    reduce_ = jnp.min if a.func == "min" else jnp.max
+                    v = reduce_(jnp.where(valid, data, fill))
+                    if several:
+                        v = (pmin if a.func == "min" else pmax)(v, _AXES)
+                cols[a.uid] = Column(
+                    data=v.astype(types[a.uid].np_dtype)[None],
+                    valid=(n >= 0 if a.func == "count" else n > 0)[None],
+                    type_=types[a.uid])
+            return Chunk(cols, jnp.ones(1, dtype=jnp.bool_))
+
+        def emit(env, growths):
+            pch, ovfs = probe_emit(env, growths)
+            ch, s_ovfs = child_emit(env, growths)
+            with jax.named_scope(scope):
+                row = pipe(one_row(ch))
+                cols = dict(pch.columns)
+                for uid in out_uids:
+                    col = row.columns[uid]
+                    cols[uid] = Column(
+                        jnp.broadcast_to(col.data[0], (pch.capacity,)),
+                        jnp.broadcast_to(col.valid[0], (pch.capacity,)),
+                        col.type_)
+            return Chunk(cols, pch.sel), list(ovfs) + list(s_ovfs)
+
+        return emit
+
     # -- joins -------------------------------------------------------------
 
     def _join_producer(self, join: PHashJoin) -> Callable:
+        if join.kind == "scalar":
+            return self._scalar_producer(join)
         if not join.eq_left:
             raise _Unsupported("keyless (cross) join")
         if join.kind not in ("inner", "left", "semi", "anti"):
@@ -654,12 +756,24 @@ class _Compiler:
             self.stream_unsafe.update(
                 range(n_before_build, len(self.sources)))
 
+        kind = join.kind
+        exists_sem = join.exists_sem
+        other_cond = join.other_cond
+        other_pred = compile_predicate(other_cond) if other_cond is not None else None
+        nk = len(probe_keys)
+        need_verify = nk > 1
+        # a semi or anti join on ONE key with nothing more to test needs
+        # to know of each probe slot only whether a live build row has its
+        # key: the rank's count says it exactly (one key's hash is its
+        # value), so nothing is expanded, gathered or scattered back
+        exists = kind in ("semi", "anti") and nk == 1 and other_pred is None
         # one part owns every key: nothing to exchange, no knob for it
         exchange = not build_is_bcast and self.n_parts > 1
         if exchange:
             self.n_exchange += 2
         g_exch = self._add_growth(2.0, "exch") if exchange else None
-        g_expand = self._add_growth(1.0, "expand")
+        g_expand = None if exists else self._add_growth(1.0, "expand")
+        self.n_exists += exists
         # numbered after its inputs, as the knobs are: join0's ops run first
         j_scope = f"join{self.n_join}"
         self.n_join += 1
@@ -671,13 +785,7 @@ class _Compiler:
         g_bcomp, b_base = self._compact_knob(build_plan.est_rows)
         g_ocomp, o_base = self._compact_knob(join.est_rows)
 
-        kind = join.kind
-        exists_sem = join.exists_sem
-        other_cond = join.other_cond
-        other_pred = compile_predicate(other_cond) if other_cond is not None else None
         n_parts = self.n_parts
-        nk = len(probe_keys)
-        need_verify = nk > 1
         self.sig.append(
             f"join:{kind}:{exists_sem}:{probe_keys!r}:{build_keys!r}:{other_cond!r}"
             f":exch{exchange}"
@@ -695,6 +803,12 @@ class _Compiler:
                 return join_local(env, growths, pch, bch, ovfs)
 
         def join_local(env, growths, pch, bch, ovfs):
+            def compact_out(result):
+                capO = int(np.ceil(growths[g_ocomp] * o_base))
+                with jax.named_scope("join.compact"):
+                    result = _compact_chunk(env, result, capO, g_ocomp, ovfs)
+                return result, ovfs
+
             with jax.named_scope("join.compact"):
                 capP = int(np.ceil(growths[g_pcomp] * p_base))
                 pch = _compact_chunk(env, pch, capP, g_pcomp, ovfs)
@@ -787,6 +901,19 @@ class _Compiler:
                         sh, cvi, p_hash2, p_ok, mode=mode)
             env["joins"].append(path)
 
+            if exists:
+                with jax.named_scope("join.exists"):
+                    # (the rank's starts and order go unread: no op of
+                    # theirs is left in the program)
+                    m = cnt > 0
+                    if kind == "semi":
+                        keep = p_ok & m
+                    elif exists_sem:
+                        keep = pch2.sel & ~(p_kvalid2 & m)
+                    else:
+                        keep = pch2.sel & p_kvalid2 & ~m & (b_null == 0)
+                return compact_out(pch2.with_sel(keep))
+
             with jax.named_scope("join.expand"):
                 # 64 bits from here (the merged rank hands back 32): a
                 # many-to-many join's total may pass 2^31
@@ -844,7 +971,8 @@ class _Compiler:
             if kind == "inner":
                 result = joined
             else:
-                # per-probe-row match flags (post-cond): scatter-or by p_row
+                # per-probe-row match flags (post-cond; several keys
+                # verified): scatter-or by p_row
                 m = jnp.zeros(Rp, dtype=jnp.int32).at[p_row].add(
                     joined.sel.astype(jnp.int32)) > 0
                 if kind == "semi":
@@ -875,11 +1003,7 @@ class _Compiler:
                             col.type_,
                         )
                     result = Chunk(out_cols, jnp.concatenate([joined.sel, pad_sel]))
-
-            capO = int(np.ceil(growths[g_ocomp] * o_base))
-            with jax.named_scope("join.compact"):
-                result = _compact_chunk(env, result, capO, g_ocomp, ovfs)
-            return result, ovfs
+            return compact_out(result)
 
         return emit
 
@@ -1224,7 +1348,8 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
         n_growth=c.n_growth, n_exchange=c.n_exchange,
         n_reduce=(c.reduce_paths.count("runs"),
                   c.reduce_paths.count("scatter")),
-        n_join=c.n_join, n_subquery=c.n_subquery,
+        n_join=c.n_join, n_subquery=c.n_subquery, n_scalar=c.n_scalar,
+        n_exists=c.n_exists,
         sig="|".join(c.sig),
         build_fn=build_fn,
         out_kind=out_kind, domains=domains,

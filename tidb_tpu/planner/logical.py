@@ -222,6 +222,11 @@ class BuildContext:
     # body ids whose every reference is duplicate-insensitive (all inside
     # IN/EXISTS semi-join zones): materialization may dedup + rewrite
     cte_duponly: set = field(default_factory=set)
+    # an uncorrelated scalar aggregate subquery compared in WHERE becomes
+    # a join with its one row (_scalar_subquery_join) instead of a literal
+    # made by running it now: set where the mesh tier compiles such a
+    # join into the statement's own program
+    scalar_joins: bool = False
 
 
 def _conjuncts(e) -> List:
@@ -970,7 +975,8 @@ def _build_select_core(stmt: A.SelectStmt, ctx: BuildContext, outer) -> LogicalP
                 else:
                     plain.append(A.EBool(_exists_value(conj, ctx, scope)))
                 continue
-            hit = _try_scalar_corr(conj, plan, scope, ctx)
+            hit = (_try_scalar_corr(conj, plan, scope, ctx)
+                   or _scalar_subquery_join(conj, plan, scope, ctx))
             if hit is not None:
                 conj, plan, scope = hit
             conj = _fold_subqueries(conj, ctx, scope)
@@ -1528,6 +1534,98 @@ def _try_scalar_corr(conj, plan, scope: Scope, ctx: BuildContext):
     new_conj = A.EBinary(conj.op, other_side, vref) if sub_on_right else A.EBinary(conj.op, vref, other_side)
     new_scope = Scope(list(scope.cols) + [value_col], scope.parent)
     return new_conj, join, new_scope
+
+
+_FLIP = {"<": ">", "<=": ">=", ">": "<", ">=": "<=", "=": "=", "<>": "<>",
+         "!=": "!="}
+_EXACT = (TypeKind.INT, TypeKind.DECIMAL)
+
+
+def _scalar_subquery_join(conj, plan, scope: Scope, ctx: BuildContext):
+    """`x cmp (SELECT agg(y) FROM ... [WHERE ...])`, uncorrelated, with
+    `agg` one of SUM, COUNT, MIN, MAX, AVG over an exact `x` and `y`
+    (INT or DECIMAL), as a join of `plan` with the subquery's one row
+    (LJoin kind "scalar", the row's columns hidden from `*`): nothing is
+    run now and nothing of the data becomes a literal, so the mesh tier
+    compiles the subquery into the statement's own program
+    (parallel/fragment.py _scalar_producer). Only where the session asks
+    (`ctx.scalar_joins`); every other shape folds (_fold_subqueries).
+
+    An AVG is kept exact: the subquery computes SUM(y) and COUNT(y) and
+    the row carries `avg_floor` and `avg_ceil` of them (the AVG rounded
+    down and up at y's scale), so ``x > AVG`` is ``x > avg_floor``,
+    ``x >= AVG`` is ``x >= avg_ceil``, ``x = AVG`` is ``x = avg_floor
+    AND x = avg_ceil``, and so on, all in int64; unknown where the count
+    is 0. Where x's scale passes y's (a comparison that would
+    round the bound) it folds. Returns (conj', plan', scope') or None."""
+    if not (ctx.scalar_joins and isinstance(conj, A.EBinary)
+            and conj.op in _CMP_OPS):
+        return None
+    if isinstance(conj.right, A.ESubquery) and not _has_subquery(conj.left):
+        sub, x, op = conj.right.select, conj.left, conj.op
+    elif isinstance(conj.left, A.ESubquery) and not _has_subquery(conj.right):
+        sub, x, op = conj.left.select, conj.right, _FLIP[conj.op]
+    else:
+        return None
+    if not (isinstance(sub, A.SelectStmt) and sub.from_ is not None
+            and len(sub.items) == 1 and not sub.group_by
+            and sub.having is None and sub.limit is None
+            and sub.offset is None and not sub.order_by
+            and not sub.distinct and not sub.ctes
+            and (sub.where is None or not _has_subquery(sub.where))):
+        return None
+    call = sub.items[0].expr
+    if not (isinstance(call, A.EFunc) and not call.distinct
+            and call.name.lower() in CORE_AGGS and len(call.args) == 1
+            and not _has_subquery(call.args[0])):
+        return None
+    binder = ctx.binder
+    try:
+        xt = binder.bind_expr(x, scope).type_
+        avg = call.name.lower() == "avg"
+        if avg:
+            items = [A.SelectItem(A.EFunc(name=f, args=list(call.args)))
+                     for f in ("sum", "count")]
+            sub = dataclasses.replace(sub, items=items)
+        # no outer scope: a correlated name raises, and then it folds
+        sub_plan = build_select(sub, ctx, None)
+    except (PlanError, SchemaError, UnsupportedError):
+        return None
+    node = sub_plan
+    while isinstance(node, (LProjection, LSelection)):
+        node = node.child
+    if not (isinstance(node, LAggregate) and not node.group_exprs
+            and xt.kind in _EXACT
+            and all(a.arg is None or a.arg.type_.kind in _EXACT
+                    for a in node.aggs)):
+        return None
+    if avg:
+        s_col, c_col = sub_plan.schema
+        yt = s_col.type_
+        if xt.scale > yt.scale:
+            return None
+        cols = [PlanCol(uid=binder.new_uid(f"avg_{b}"), name=f"avg_{b}",
+                        type_=yt) for b in ("floor", "ceil")]
+        sub_plan = LProjection(
+            schema=cols, children=[sub_plan],
+            exprs=[Call(type_=yt, op=f"avg_{b}",
+                        args=(s_col.ref(), c_col.ref()))
+                   for b in ("floor", "ceil")])
+        lo, hi = (A.EName(c.uid) for c in cols)
+        bound = {">": lo, "<=": lo, ">=": hi, "<": hi}
+        if op in bound:
+            new_conj = A.EBinary(op, x, bound[op])
+        else:
+            # equal to both bounds, or unequal to either: a NULL x stays
+            # unknown
+            new_conj = A.EBinary("and" if op == "=" else "or",
+                                 A.EBinary(op, x, lo), A.EBinary(op, x, hi))
+    else:
+        new_conj = A.EBinary(op, x, A.EName(sub_plan.schema[0].uid))
+    cols = [dataclasses.replace(c, hidden=True) for c in sub_plan.schema]
+    join = LJoin(schema=list(plan.schema) + cols, children=[plan, sub_plan],
+                 kind="scalar")
+    return new_conj, join, Scope(list(scope.cols) + cols, scope.parent)
 
 
 # ---------------------------------------------------------------------------

@@ -30,14 +30,17 @@ def plan_statement(
     n_parts: int = 1,
     session_info: Optional[dict] = None,
     agg_push_down: bool = True,
+    scalar_joins: bool = False,
 ) -> PhysicalPlan:
-    """SELECT/UNION AST -> optimized physical plan."""
+    """SELECT/UNION AST -> optimized physical plan. `scalar_joins`: an
+    uncorrelated scalar aggregate subquery in WHERE becomes a join with
+    its one row instead of a literal (logical._scalar_subquery_join)."""
     assert isinstance(stmt, (A.SelectStmt, A.UnionStmt)), type(stmt)
     binder = Binder()
     binder.session_info = dict(session_info or {}, db=db)
     ctx = BuildContext(
-        catalog=catalog, db=db, binder=binder, execute_subplan=execute_subplan
-    )
+        catalog=catalog, db=db, binder=binder, execute_subplan=execute_subplan,
+        scalar_joins=scalar_joins)
     # the three parts of the caller's span (``session.plan``), by name
     with tracing.phase("bind"):
         logical = build_select(stmt, ctx)

@@ -161,6 +161,30 @@ def _has_eager_partial(phys) -> bool:
     return False
 
 
+def _scalar_joins(phys) -> int:
+    """Scalar subqueries the physical plan holds as joins with their
+    one row (planner/logical.py _scalar_subquery_join)."""
+    from tidb_tpu.planner.physical import PHashJoin
+
+    n, stack = 0, [phys]
+    while stack:
+        p = stack.pop()
+        n += isinstance(p, PHashJoin) and p.kind == "scalar"
+        stack.extend(p.children)
+    return n
+
+
+def _compiled_scalar_joins(root) -> int:
+    """Of them, those the executor tree's fragment programs compile in
+    (parallel/fragment.py _scalar_producer)."""
+    n, stack = 0, [root]
+    while stack:
+        e = stack.pop()
+        n += getattr(getattr(e, "_prog", None), "n_scalar", 0)
+        stack.extend(e.children)
+    return n
+
+
 def _dist_engaged(root) -> bool:
     """Did the dist builder actually place mesh executors (vs a silent
     full host fallback)?"""
@@ -1134,7 +1158,13 @@ class Session:
         return bool(self.sysvars.get("tidb_opt_agg_push_down"))
 
     def _execute_subplan(self, logical) -> List[tuple]:
-        """Planner callback: run a bound logical subplan to completion."""
+        """Planner callback: run a bound logical subplan to completion.
+        Each call is a statement within the statement's planning: the
+        count ``subqueries_run`` of the innermost open span says how
+        many."""
+        from tidb_tpu.utils import tracing
+
+        tracing.add("subqueries_run", 1)
         logical = optimize_logical(
             logical,
             cascades=bool(self.sysvars.get("tidb_enable_cascades_planner")),
@@ -1167,7 +1197,8 @@ class Session:
             return 1
         return int(np.prod(list(self.mesh.shape.values())))
 
-    def _plan_select(self, stmt, agg_push_down=None, execute_subplan=None):
+    def _plan_select(self, stmt, agg_push_down=None, execute_subplan=None,
+                     scalar_joins=False):
         import time as _time
 
         from tidb_tpu.utils import metrics as M
@@ -1198,11 +1229,12 @@ class Session:
                 agg_push_down=(self._agg_push_down()
                                if agg_push_down is None
                                else agg_push_down),
+                scalar_joins=scalar_joins,
             )
         M.PLAN_SECONDS.observe(_time.perf_counter() - t0)
         return phys
 
-    def _acquire_plan(self, stmt, agg_push_down=None):
+    def _acquire_plan(self, stmt, agg_push_down=None, scalar_joins=False):
         """Physical plan for a SELECT/UNION, through the digest-keyed
         plan cache when the statement is eligible (ref: planner/core
         plan_cache*). Sets @@last_plan_from_cache and accumulates the
@@ -1234,7 +1266,7 @@ class Session:
             self._fb_last_apd = (self._agg_push_down()
                                  if agg_push_down is None
                                  else bool(agg_push_down))
-            return self._acquire_plan_inner(stmt, agg_push_down)
+            return self._acquire_plan_inner(stmt, agg_push_down, scalar_joins)
         finally:
             self._stmt_plan_s += _time.perf_counter() - t0
 
@@ -1255,17 +1287,19 @@ class Session:
         self._ps_materialized = (stmt, out)
         return out
 
-    def _acquire_plan_inner(self, stmt, agg_push_down):
+    def _acquire_plan_inner(self, stmt, agg_push_down, scalar_joins):
         from tidb_tpu.planner import plancache as _pc
         from tidb_tpu.utils import tracing
 
         with tracing.phase("cache"):
-            phys, fill = self._probe_plan_cache(stmt, agg_push_down)
+            phys, fill = self._probe_plan_cache(stmt, agg_push_down,
+                                                scalar_joins)
         if phys is not None:
             return phys
         stmt = self._materialize_stmt(stmt)
         if fill is None:
-            return self._plan_select(stmt, agg_push_down=agg_push_down)
+            return self._plan_select(stmt, agg_push_down=agg_push_down,
+                                     scalar_joins=scalar_joins)
         cache, key, info, digest, sv = fill
         used = [False]
 
@@ -1274,19 +1308,21 @@ class Session:
             return self._execute_subplan(logical)
 
         phys = self._plan_select(stmt, agg_push_down=agg_push_down,
-                                 execute_subplan=_sub)
+                                 execute_subplan=_sub,
+                                 scalar_joins=scalar_joins)
         try:
             new = _pc.build_entry(
                 stmt, phys, info, digest, self.db, sv,
                 plan_sentinel=lambda s2: self._plan_select(
-                    s2, agg_push_down=agg_push_down, execute_subplan=_sub),
+                    s2, agg_push_down=agg_push_down, execute_subplan=_sub,
+                    scalar_joins=scalar_joins),
                 subplan_used=lambda: used[0])
             cache.store(key, new, sv)
         except Exception:  # noqa: BLE001 — the cache must never fail
             pass          # (or slow-path-block) the statement
         return phys
 
-    def _probe_plan_cache(self, stmt, agg_push_down):
+    def _probe_plan_cache(self, stmt, agg_push_down, scalar_joins=False):
         """What comes before planning: is the statement eligible for
         the plan cache, its analysis, digest and lookup. Returns
         ``(plan, None)`` on a hit, ``(None, None)`` where the statement
@@ -1346,6 +1382,10 @@ class Session:
         eff_apd = (self._agg_push_down() if agg_push_down is None
                    else agg_push_down)
         key = self._plan_cache_key(stmt, info, digest, eff_apd)
+        if scalar_joins:
+            # a plan that holds its scalar subqueries as joins is the mesh
+            # tier's own (batch_probe never coalesces mesh sessions)
+            key += ("scalar_joins",)
         sv = self.catalog.schema_version
         cap = int(self.sysvars.get("tidb_prepared_plan_cache_size"))
         entry = cache.lookup(key, sv, cap)
@@ -1746,20 +1786,30 @@ class Session:
         two calls."""
         from tidb_tpu.utils import tracing
 
+        dist = self._dist_expected()
+        apd = None
         with tracing.span("session.plan"):
-            phys = self._acquire_plan(stmt)
+            phys = self._acquire_plan(stmt, scalar_joins=dist)
             with tracing.phase("privs"):
                 self._check_plan_privs(phys)
             with tracing.phase("build"):
                 root = self._build_root(phys)
-            if self._dist_expected() and _has_eager_partial(phys) \
-                    and not _dist_engaged(root):
+            if dist and _has_eager_partial(phys) and not _dist_engaged(root):
                 # the eager-agg shape kept this plan off the mesh (the
                 # fragment tier takes scan-rooted generic partials, not
                 # every shape) — losing fragmentation costs more than the
                 # rewrite saves, so re-plan without it and keep the
                 # fragments (the no-push variant caches under its own key)
-                phys = self._acquire_plan(stmt, agg_push_down=False)
+                apd = False
+                phys = self._acquire_plan(stmt, agg_push_down=apd,
+                                          scalar_joins=dist)
+                with tracing.phase("build"):
+                    root = self._build_root(phys)
+            if dist and _scalar_joins(phys) > _compiled_scalar_joins(root):
+                # a scalar subquery held as a join that no fragment
+                # program compiled in: plan again with every one folded
+                # to its literal, as every other engine runs them
+                phys = self._acquire_plan(stmt, agg_push_down=apd)
                 with tracing.phase("build"):
                     root = self._build_root(phys)
         return phys, root

@@ -478,8 +478,19 @@ FRAGMENT_SUBQUERIES = Counter(
     "(parallel/fragment.py _subquery_agg_producer: a GROUP BY without "
     "DISTINCT or AVG on a mesh of one part); path=broadcast, answered as "
     "a statement of its own through the host and uploaded replicated "
-    "(the span fragment.broadcast). A launch adds its program's counts "
-    "(TPC-H Q18 on one part: inline 1)")
+    "(the span fragment.broadcast); path=scalar, an uncorrelated scalar "
+    "aggregate subquery reduced to its one row INSIDE the program "
+    "(_scalar_producer, psum / pmin / pmax across parts), which the "
+    "planner did not run first. A launch adds its program's counts "
+    "(TPC-H Q18 on one part: inline 1; Q22: scalar 1)")
+FRAGMENT_EXISTENCE_JOINS = Counter(
+    "tidb_tpu_fragment_existence_joins_total",
+    "Semi and anti joins of the general fragment programs launched that "
+    "decide each probe row from the rank's count of matching build rows "
+    "alone (parallel/fragment.py _join_producer: one key, no other "
+    "condition), with no expansion, gather or scatter of match flags, by "
+    "fragment kind, static per program. A launch adds its program's count "
+    "(TPC-H Q18: 1, its IN; Q22: 1, its NOT EXISTS)")
 FRAGMENT_COMPACTIONS = Counter(
     "tidb_tpu_fragment_compactions_total",
     "Compactions compiled into the general fragment programs launched "
