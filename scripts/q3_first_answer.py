@@ -49,7 +49,7 @@ def program_counters() -> dict:
     out = {}
     for name in ("FRAGMENT_DISPATCH", "FRAGMENT_RETRY_TOTAL",
                  "FRAGMENT_JOINS", "FRAGMENT_SUBQUERIES",
-                 "FRAGMENT_COMPACTIONS",
+                 "FRAGMENT_COMPACTIONS", "FRAGMENT_EAGER_PARTIALS",
                  "FRAGMENT_EXCHANGE_STEPS",
                  "FRAGMENT_REDUCE_PAYLOADS", "FRAGMENT_COMPILE"):
         c = getattr(metrics, name, None)

@@ -158,27 +158,62 @@ def test_q18_on_one_part_is_one_program_and_one_launch(devices8, loads, seed, qu
 @pytest.mark.parametrize("quantity", [MANY, NONE], ids=["many", "none"])
 def test_a_q18_launch_counts_its_compactions_the_subquerys_among_them(
         devices8, loads, quantity):
-    """FRAGMENT_COMPACTIONS{kind}: of the program's 16 knobs
-    (join0's expand and three targets 0-3, join1's 4-7, the subquery's
-    input, table and survivors 8-10, join2's three targets 11-13 — the
-    semi-join decides existence from its rank's count, no expand knob —
-    the root's 14-15) the trace compacts, at this scale,
-    join1's probe side (lineitem's eager partial, 5), **the HAVING's
-    survivors (10)** and join2's output (13) — whatever passes the
-    HAVING, none included: the count is the program's, static — and a
-    launch from the fragment cache adds the same three."""
+    """FRAGMENT_COMPACTIONS{kind}: of the program's 17 knobs
+    (lineitem's eager partial's table 0, join0's expand and three
+    targets 1-4, join1's 5-8, the subquery's input, table and survivors
+    9-11, join2's three targets 12-14 — the semi-join decides existence
+    from its rank's count, no expand knob — the root's 15-16) the trace
+    compacts, at this scale, **the HAVING's survivors (11)** and join2's
+    output (14) — whatever passes the HAVING, none included: the count
+    is the program's, static — and a launch from the fragment cache adds
+    the same two. The eager partial's table holds the key's estimated
+    groups, under twice their estimate: the join over it compacts
+    nothing of it."""
     catalog, _data, _oracle = loads[SEEDS[0]]
     s = session(catalog, devices8, 1)
     c0 = samples(FRAGMENT_COMPACTIONS)
     _rows, seen, _trace = run_spied(s, Q18.sql({"quantity": quantity}))
     (prog, _args, _shapes, _types, growths, grown), = seen
-    assert growths == grown == prog.growth_defaults and prog.n_growth == 16
+    assert growths == grown == prog.growth_defaults and prog.n_growth == 17
     (fn,) = [f for k, f in s._shard_cache.fragments.items() if k[0] == "frag"]
-    assert fn.compactions == [5, 10, 13]
-    assert delta(FRAGMENT_COMPACTIONS, c0) == {("general_generic", ""): 3}
+    assert fn.compactions == [11, 14]
+    assert delta(FRAGMENT_COMPACTIONS, c0) == {("general_generic", ""): 2}
     c0 = samples(FRAGMENT_COMPACTIONS)
     s.query(Q18.sql({"quantity": quantity}))
-    assert delta(FRAGMENT_COMPACTIONS, c0) == {("general_generic", ""): 3}
+    assert delta(FRAGMENT_COMPACTIONS, c0) == {("general_generic", ""): 2}
+
+
+@pytest.mark.parametrize("n_parts", [1, 8])
+def test_q18s_eager_table_takes_the_estimate_and_no_knob_grows(devices8, loads,
+                                                              n_parts):
+    """FRAGMENT_EAGER_PARTIALS{kind, sized}: the outer program's one eager
+    partial (lineitem by order key, under join1) is sized from the key's
+    sketched distinct count — a part's share of it, a quarter over —
+    not one slot a lineitem row; the statement launches with every knob
+    at its default cold and warm, on one part and on eight (where the
+    subquery is a program of its own, with no eager partial), and the
+    rows are the reference's."""
+    from tidb_tpu.parallel.fragment import _Compiler
+    from tidb_tpu.statistics import column_ndv
+    from tidb_tpu.utils.metrics import FRAGMENT_EAGER_PARTIALS
+
+    catalog, data, oracle = loads[SEEDS[1]]
+    s = session(catalog, devices8, n_parts)
+    ndv = column_ndv(catalog.table("test", "lineitem"), "l_orderkey")
+    slots = int(np.ceil(_Compiler.NDV_HEADROOM * ndv / n_parts))
+    for _ in range(2):  # cold, then from the fragment cache
+        e0 = {tuple(sorted(k.items())): n for k, n in FRAGMENT_EAGER_PARTIALS.samples()}
+        r0 = samples(FRAGMENT_RETRY_TOTAL)
+        rows, seen, _trace = run_spied(s, Q18.sql({"quantity": FEW}))
+        check_rows(rows, data, oracle, FEW)
+        assert delta(FRAGMENT_RETRY_TOTAL, r0) == {}
+        assert all(growths == grown for *_, growths, grown in seen)
+        after = {tuple(sorted(k.items())): n for k, n in FRAGMENT_EAGER_PARTIALS.samples()}
+        assert {k: n - e0.get(k, 0) for k, n in after.items() if n - e0.get(k, 0)} == {
+            (("kind", "general_generic"), ("sized", "estimate")): 1}
+    (outer,) = [prog for prog, *_ in seen if prog.n_join == 3]
+    assert f"cap0:{slots}|eagg:" in outer.sig
+    assert slots < catalog.table("test", "lineitem").n / n_parts / 3
 
 
 def test_two_seeds_of_data_share_one_fragment_cache_key(devices8, loads):

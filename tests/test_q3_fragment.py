@@ -162,19 +162,22 @@ def test_a_launch_counts_its_joins_by_probe_path(devices8, tiny_tpch):
     assert delta(FRAGMENT_DISPATCH, l0) == {(): 1} and delta(FRAGMENT_JOINS, j0) == {}
 
 
-@pytest.mark.parametrize("n_parts,knobs", [(1, [2, 5]), (4, [3, 4, 7, 9])],
+@pytest.mark.parametrize("n_parts,knobs", [(1, [3]), (4, [4, 5, 10])],
                          ids=["1x1", "1x4"])
 def test_a_launch_counts_the_compactions_its_trace_took(devices8, tiny_tpch,
                                                         n_parts, knobs):
     """FRAGMENT_COMPACTIONS{kind} (PR 36): a launch adds the `_compact`s
     its program's trace took, which is static per built program (a
-    target at or over its chunk's capacity compiles nothing): on one
-    part, at this scale, join0's build side (customer under its filter,
-    knob 2) and join1's probe side (lineitem's eager partial, knob 5), as
-    at SF1; a part of four holds a quarter of the rows against the same
-    floors, and compacts other chunks. The program from the fragment
-    cache brings its count with it; Q18's inner aggregate over an
-    unfiltered scan compacts nothing."""
+    target at or over its chunk's capacity compiles nothing). The
+    program's first knob is lineitem's eager partial's table (join1's
+    probe side, whose producer is compiled first); join0's follow. On one
+    part, at this scale as at SF1, join0's build side (customer under its
+    filter, knob 3) is compacted and join1's probe side is not: the eager
+    table holds the key's estimated groups, under twice their estimate; a
+    part of four holds a quarter of the rows against the same floors,
+    and compacts join0's build side and output (4, 5) and join1's output
+    (10). The program from the fragment cache brings its count with it;
+    Q18's inner aggregate over an unfiltered scan compacts nothing."""
     catalog, _ = tiny_tpch
     s = session(catalog, devices8, n_parts)
     c0 = by_labels(FRAGMENT_COMPACTIONS)
@@ -398,6 +401,135 @@ def test_a_guessed_capacity_keeps_four_leading_bits(a, b, base):
     assert four._compact_knob(29761)[1] == 15360  # a part's share, then the bits
 
 
+@pytest.mark.parametrize("n_parts,slots", [(1, 1865612), (8, 233202)])
+def test_an_eager_table_at_sf1s_sketch_takes_its_estimate(monkeypatch, n_parts,
+                                                          slots):
+    """The eager partial's group table is sized by the rule of the root's
+    (`_table_sizer`): lineitem's 1,492,489 sketched order keys a quarter
+    over, to the slot, a part's share of them — not one slot for each of
+    the part's 6,001,215 / n rows, which a join then compacted to twice
+    the estimate (3,145,728 on one part). Under its chunk's capacity the
+    table reports its groups' factor under its knob."""
+    from types import SimpleNamespace
+
+    from tidb_tpu.parallel import fragment
+    from tidb_tpu.parallel.fragment import _Compiler
+
+    # the overflow's max across the parts, outside a program
+    monkeypatch.setattr(fragment, "pmax", lambda x, _axes: x)
+    c = _Compiler(n_parts)
+    to_table = c._table_sizer(SimpleNamespace(est_rows=1492488.89,
+                                              est_from_ndv=True))
+    assert c.sig == [f"cap0:{slots}"] and c.growth_kinds == ["compact"]
+    asked = []
+
+    def partial(chunk, slots):
+        asked.append(slots)
+        return {"n": np.int64(1_500_000 // n_parts)}
+
+    ovfs = []
+    rows = SimpleNamespace(capacity=-(-6_001_215 // n_parts))
+    _table, sized = to_table(partial, rows, (1.0,), ovfs)
+    assert asked == [slots] and sized and [k for k, _ in ovfs] == [0]
+    # a table the estimate would not shrink keeps one slot a row
+    _table, sized = to_table(partial, SimpleNamespace(capacity=slots), (1.0,), ovfs)
+    assert not sized and len(ovfs) == 1
+
+
+def eager_slots(monkeypatch) -> list:
+    """The slots of every eager partial's group table traced from here
+    on, as `_group_rows` receives them."""
+    from tidb_tpu.parallel.fragment import _Compiler
+
+    real, seen = _Compiler._group_rows, []
+
+    def spy(agg, t):
+        seen.append(t["k0.d"].shape[0])
+        return real(agg, t)
+
+    monkeypatch.setattr(_Compiler, "_group_rows", staticmethod(spy))
+    return seen
+
+
+@pytest.mark.parametrize("n_parts", [1, 8])
+def test_the_eager_table_is_born_at_the_keys_estimated_groups(
+        devices8, tiny_tpch, monkeypatch, n_parts):
+    """FRAGMENT_EAGER_PARTIALS{kind, sized}: Q3's program holds one eager
+    partial, and its table takes the estimate — a quarter over the
+    sketched order keys, a part's share — not one slot a lineitem row. No knob grows on the cold statement or the warm one,
+    on one part (one compaction a launch: customer's) or on eight, whose
+    parts each hold a block of lineitem's rows; the rows are sqlite's."""
+    from tidb_tpu.parallel.fragment import _Compiler
+    from tidb_tpu.statistics import column_ndv
+    from tidb_tpu.utils.metrics import FRAGMENT_EAGER_PARTIALS
+
+    catalog, oracle = tiny_tpch
+    sql = Q3.format(**PARAMS[1])
+    want = oracle.execute(sql.replace("date '", "'")).fetchall()
+    s = session(catalog, devices8, n_parts)
+    slots = eager_slots(monkeypatch)
+    ndv = column_ndv(catalog.table("test", "lineitem"), "l_orderkey")
+    for _ in range(2):  # cold, then from the fragment cache
+        e0, r0, c0 = (by_labels(c) for c in (
+            FRAGMENT_EAGER_PARTIALS, FRAGMENT_RETRY_TOTAL, FRAGMENT_COMPACTIONS))
+        rows, seen = run_spied(s, sql)
+        (prog, _args, growths, grown), = seen
+        assert growths == grown == prog.growth_defaults
+        assert delta(FRAGMENT_EAGER_PARTIALS, e0) == {("estimate",): 1}
+        assert delta(FRAGMENT_RETRY_TOTAL, r0) == {}
+        if n_parts == 1:
+            assert delta(FRAGMENT_COMPACTIONS, c0) == {(): 1}
+        ok, msg = rows_equal(rows, want, ordered=True)
+        assert ok, msg
+    assert slots == [int(np.ceil(_Compiler.NDV_HEADROOM * ndv / n_parts))]
+    lineitem = catalog.table("test", "lineitem").n
+    assert slots[0] < lineitem / n_parts / 3
+
+
+def test_an_eager_table_estimated_short_grows_once_and_answers_right(
+        devices8, tiny_tpch, monkeypatch):
+    """A sketch that reads a third of lineitem's order keys sizes the
+    eager table a third too small: its groups overflow it, the launch
+    reports the factor under the table's knob, and the statement is
+    launched once more with that knob grown — no group dropped. The
+    grown knob is kept: the next statement launches once."""
+    import tidb_tpu.statistics as stats
+
+    catalog, oracle = tiny_tpch
+    real = stats.column_ndv
+
+    def short(table, col_name):
+        ndv = real(table, col_name)
+        return ndv / 3 if col_name == "l_orderkey" and ndv else ndv
+
+    monkeypatch.setattr(stats, "column_ndv", short)
+    sql = Q3.format(**PARAMS[0])
+    want = oracle.execute(sql.replace("date '", "'")).fetchall()
+    s = session(catalog, devices8, 1)
+    slots = eager_slots(monkeypatch)
+    r0, l0 = by_labels(FRAGMENT_RETRY_TOTAL), by_labels(FRAGMENT_DISPATCH)
+    rows, seen = run_spied(s, sql)
+    (prog, _args, growths, grown), = seen
+    ok, msg = rows_equal(rows, want, ordered=True)
+    assert ok, msg
+    assert delta(FRAGMENT_DISPATCH, l0) == {(): 2}
+    assert delta(FRAGMENT_RETRY_TOTAL, r0) == {("compact",): 1}
+    # the eager table's knob: its base is signed just before the partial
+    k = int(re.search(r"(?:^|\|)cap(\d+):\d+\|eagg:", prog.sig).group(1))
+    assert prog.growth_kinds[k] == "compact"
+    assert growths[k] == 1.0 and grown[k] > 1.0
+    assert len(slots) == 2 and slots[1] == int(np.ceil(grown[k] * slots[0]))
+    (groups,), = oracle.execute(
+        "select count(distinct l_orderkey) from lineitem where l_shipdate > "
+        f"'{PARAMS[0]['date']}'").fetchall()
+    assert slots[0] < groups <= slots[1]
+    r0, l0 = by_labels(FRAGMENT_RETRY_TOTAL), by_labels(FRAGMENT_DISPATCH)
+    rows, seen = run_spied(s, sql)
+    assert seen[0][2] == grown and delta(FRAGMENT_DISPATCH, l0) == {(): 1}
+    assert delta(FRAGMENT_RETRY_TOTAL, r0) == {}
+    assert rows_equal(rows, want, ordered=True)[0]
+
+
 @pytest.fixture(scope="module")
 def q3_scopes(devices8, tiny_tpch):
     """The scope (``op_name`` less the program's name) of every op in the
@@ -413,10 +545,15 @@ def q3_scopes(devices8, tiny_tpch):
 @pytest.mark.parametrize("stage", STAGES)
 def test_each_stage_of_each_join_has_its_scope(q3_scopes, join, stage):
     """join0 is the join whose ops run first (orders with customer),
-    join1 the one over its result (lineitem). Neither side of join1's
-    input is compacted when twice its estimate reaches its capacity, but
-    its output is; join0 compacts customer."""
+    join1 the one over its result (lineitem). join0 compacts customer;
+    join1 compacts nothing on one part, at this scale as at SF1: its
+    probe side is lineitem's eager table, which holds the key's estimated
+    groups, under twice their estimate, and its build side and output
+    reach their capacities."""
     mine = [n for n in q3_scopes if f"/{join}/{stage}/" in f"/{n}/"]
+    if (join, stage) == ("join1", "join.compact"):
+        assert not mine, mine
+        return
     assert mine, sorted(n for n in q3_scopes if join in n)[:20]
     # a join's scope never nests in the other's
     other = "join1" if join == "join0" else "join0"

@@ -60,7 +60,7 @@ __all__ = ["ShardCache", "build_dist_executor", "DistAggExec", "DistJoinAggExec"
 def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
                      reduced: Tuple[int, int] = (0, 0), joins=(),
                      subqueries: Tuple[int, ...] = (0, 0, 0), compactions=(),
-                     exists: int = 0):
+                     exists: int = 0, eager_partials=()):
     """One fragment launch: the span ``fragment.<kind>[parts=N]`` on the
     statement's trace and the FRAGMENT_SECONDS collector for /metrics
     (with a trace_id exemplar). Wall time covers the launch plus any
@@ -81,11 +81,14 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
     its trace took, `compactions` = the knob of each
     (FRAGMENT_COMPACTIONS; read after the launch, as `joins`), and the
     semi and anti joins it decides from the rank's count alone, `exists`
-    (FRAGMENT_EXISTENCE_JOINS)."""
+    (FRAGMENT_EXISTENCE_JOINS), and how its trace sized each eager
+    partial's group table, `eager_partials` = "estimate" or "input" of
+    each (FRAGMENT_EAGER_PARTIALS; read after the launch, as `joins`)."""
     from tidb_tpu.utils import tracing
     from tidb_tpu.utils.metrics import (
         FRAGMENT_COMPACTIONS,
         FRAGMENT_DISPATCH,
+        FRAGMENT_EAGER_PARTIALS,
         FRAGMENT_EXCHANGE_STEPS,
         FRAGMENT_EXISTENCE_JOINS,
         FRAGMENT_JOINS,
@@ -107,6 +110,8 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
         FRAGMENT_SUBQUERIES.inc(n, kind=kind, path=path)
     FRAGMENT_EXISTENCE_JOINS.inc(exists, kind=kind)
     FRAGMENT_COMPACTIONS.inc(len(compactions), kind=kind)
+    for sized in eager_partials:
+        FRAGMENT_EAGER_PARTIALS.inc(kind=kind, sized=sized)
     FRAGMENT_SECONDS.observe(time.perf_counter() - t0, kind=kind)
 
 
@@ -186,10 +191,12 @@ class ShardCache:
                 note_placement("fragment", out)
             return out
 
-        # a general fragment's joins by probe path and the compactions
-        # its trace took (fragment.py build_fn)
+        # a general fragment's joins by probe path, the compactions its
+        # trace took and how it sized its eager partials (fragment.py
+        # build_fn)
         dispatch.join_probes = getattr(fn, "join_probes", ())
         dispatch.compactions = getattr(fn, "compactions", ())
+        dispatch.eager_partials = getattr(fn, "eager_partials", ())
         return dispatch
 
     def get_growth(self, gkey) -> float:
@@ -657,7 +664,8 @@ class DistFragmentExec(HashAggExec):
                                   prog.n_reduce, fn.join_probes,
                                   (prog.n_subquery, len(prog.broadcasts),
                                    prog.n_scalar),
-                                  fn.compactions, prog.n_exists):
+                                  fn.compactions, prog.n_exists,
+                                  fn.eager_partials):
                 out, ovf = fn(*args)
             # host-sync: the per-knob overflow vector (a few int64s)
             # gates the capacity-retry loop — one fetch per dispatch

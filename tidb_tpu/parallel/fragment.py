@@ -49,7 +49,9 @@ A join's device ops carry its number and stage in their `op_name`:
 ``join<j>/join.{compact,build,probe,expand,gather}``, j counting the
 joins in the order their ops run (a join's inputs before the join; the
 order of the growth knobs); an eager partial aggregate under a join
-sorts and reduces under ``agg.eager``; an aggregate subquery compiled
+sorts and reduces under ``agg.eager``, into a group table of the
+estimated groups' slots as the root's (`_table_sizer`), so that the join
+over it reads no more slots than that; an aggregate subquery compiled
 into the program under ``subq<k>/{agg.partial,having,compact}``, k
 counting them likewise (`n_subquery`).
 
@@ -57,11 +59,11 @@ Every ``join.compact``, ``compact`` and ``agg.compact`` scope is one
 `_compact` per chunk it shrinks: a 32-bit prefix sum, ONE 32-bit scatter
 of row numbers and ONE gather of all the chunk's arrays as an int64
 stack — never a scatter per column, which for every 64-bit column is a
-sort to this compiler (Q3's dearest, two int64 columns and their masks
-from 6.0M slots into 3.1M: 1,076-1,116 ms a scatter per array, 79 ms so;
-PERF.md section 6, PR 36). A built program's `compactions` lists the
-ones its trace took (a capacity at or over its chunk's compiles
-nothing): FRAGMENT_COMPACTIONS.
+sort to this compiler (two int64 columns and their masks from 6.0M slots
+into 3.1M, once Q3's eager table compacted for its join: 1,076-1,116 ms
+a scatter per array, 79 ms so; PERF.md section 6, PR 36). A built
+program's `compactions` lists the ones its trace took (a capacity at or
+over its chunk's compiles nothing): FRAGMENT_COMPACTIONS.
 
 A join moves its rows the same way (PR 38): ``join.expand`` marks the
 row boundaries by ONE 32-bit scatter-add and reads each output slot's
@@ -319,7 +321,9 @@ class FragmentProgram:
     # (growths tuple) -> per-shard program; its `join_probes` lists, once
     # traced, the probe path of each join ("table" | "search"), join0 first,
     # and its `compactions` the "compact" knob of each `_compact` the trace
-    # took (static per growths: a capacity under its chunk's)
+    # took (static per growths: a capacity under its chunk's), and its
+    # `eager_partials` how each eager partial's group table was sized
+    # ("estimate" | "input": `_table_sizer`)
     build_fn: Callable
     out_kind: str                      # "segment" | "generic"
     domains: List[int] = field(default_factory=list)
@@ -462,12 +466,15 @@ class _Compiler:
         table and emits the groups as ordinary rows. No cross-shard
         merge is needed — the rewrite's upper aggregate re-sums partial
         rows, so shard-local groups with duplicate keys are exactly what
-        the row-level semantics produced. Returns None for shapes the
-        kernel can't take (falls back to the broadcast producer)."""
+        the row-level semantics produced. The table has the estimated
+        groups' slots, as the root's has (`_table_sizer`): the join reads
+        no more. Returns None for shapes the kernel can't take (falls
+        back to the broadcast producer)."""
         if not self._partial_agg_ok(agg):
             return None
         child_emit = self.producer(agg.child)
         partial = make_partial_kernel(agg.group_exprs, agg.aggs)
+        to_table = self._table_sizer(agg)
         self.sig.append(
             f"eagg:{agg.group_exprs!r}:{agg.aggs!r}:{agg.group_uids!r}")
         self.reduce_paths += reduce_paths(agg.aggs)
@@ -475,7 +482,8 @@ class _Compiler:
         def emit(env, growths):
             chunk, ovfs = child_emit(env, growths)
             with jax.named_scope("agg.eager"):
-                t = partial(chunk)
+                t, sized = to_table(partial, chunk, growths, ovfs)
+            env["eager"].append("estimate" if sized else "input")
             return self._group_rows(agg, t), ovfs
 
         return emit
@@ -1097,6 +1105,37 @@ class _Compiler:
 
     # -- aggregation root --------------------------------------------------
 
+    def _table_sizer(self, agg: PHashAgg) -> Callable:
+        """The slots of `agg`'s group table, one rule for every partial
+        table of the program (the root's and a subquery's through
+        `_table_reducer`; an eager partial under a join): a "compact"
+        knob whose base is the estimated groups with their headroom — the
+        smaller one, to the slot, where the groups are bounded by the
+        keys' distinct count read from the data. Groups are dense in
+        [0, n): a table of fewer slots than its chunk has rows shrinks
+        what the reduction gathers and everything that reads the table
+        (an exchange, the host's fetch, a join). Such a table reports the
+        factor its groups ask for under the knob, so an estimate that
+        falls short relaunches with the knob grown and never drops a
+        group. to_table(partial, chunk, growths, ovfs) -> (table, whether
+        it took the estimate: False where that reaches the chunk's
+        capacity, which the table then keeps, one slot a row)."""
+        g_tab, tab_base = self._compact_knob(
+            agg.est_rows,
+            self.NDV_HEADROOM if agg.est_from_ndv else self.HEADROOM,
+            rounded=not agg.est_from_ndv)
+
+        def to_table(partial, chunk, growths, ovfs):
+            capT = int(np.ceil(growths[g_tab] * tab_base))
+            table = partial(chunk, slots=capT)
+            sized = capT < chunk.capacity
+            if sized:
+                factor = (table["n"] + capT - 1) // capT
+                ovfs.append((g_tab, pmax(jnp.maximum(factor - 1, 0), _AXES)))
+            return table, sized
+
+        return to_table
+
     def _table_reducer(self, agg: PHashAgg, exact) -> Callable:
         """A generic aggregate's first pass with its two capacity knobs:
         reduce(env, chunk, growths, ovfs, then=identity) -> then(group table),
@@ -1104,30 +1143,18 @@ class _Compiler:
         shrink targets (see _compact): the partial sort pays for input
         capacity; every slot of the table is exchanged and sorted again
         (fetched and decoded, or read by a join, on one part) by every
-        statement, so where the groups are bounded by the keys' distinct
-        count the table takes the smaller headroom, to the slot."""
+        statement: `_table_sizer`."""
         partial = make_partial_kernel(agg.group_exprs, agg.aggs, exact=exact)
         g_in, in_base = self._compact_knob(agg.child.est_rows)
-        g_tab, tab_base = self._compact_knob(
-            agg.est_rows,
-            self.NDV_HEADROOM if agg.est_from_ndv else self.HEADROOM,
-            rounded=not agg.est_from_ndv)
+        to_table = self._table_sizer(agg)
 
         def reduce(env, chunk, growths, ovfs, then=lambda table: table):
             capI = int(np.ceil(growths[g_in] * in_base))
             with jax.named_scope("agg.compact"):
                 chunk = _compact_chunk(env, chunk, capI, g_in, ovfs)
             with jax.named_scope("agg.partial"):
-                capT = int(np.ceil(growths[g_tab] * tab_base))
-                # local dedup before the exchange. Groups are dense in
-                # [0, n): the table keeps `capT` slots, which shrinks
-                # what the reduction gathers and everything the exchange
-                # must carry (on one part: everything the host fetches)
-                table = partial(chunk, slots=capT)
-                if capT < chunk.capacity:
-                    factor = (table["n"] + capT - 1) // capT
-                    ovfs.append(
-                        (g_tab, pmax(jnp.maximum(factor - 1, 0), _AXES)))
+                # local dedup before the exchange
+                table, _ = to_table(partial, chunk, growths, ovfs)
                 return then(table)
 
         return reduce
@@ -1297,10 +1324,11 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
         # strategy); None = the hash_probe process default
         join_probes: List[str] = []
         compactions: List[int] = []
+        eager: List[str] = []
 
         def frag_general(*args):
             env = {"scan": [], "bcast": [], "probe_mode": probe_mode,
-                   "joins": [], "compactions": []}
+                   "joins": [], "compactions": [], "eager": []}
             i = 0
             for _ in range(n_src):
                 env["scan"].append((args[i], args[i + 1], args[i + 2],
@@ -1313,6 +1341,7 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
             # trace time: static per program
             join_probes[:] = env["joins"]
             compactions[:] = env["compactions"]
+            eager[:] = env["eager"]
             # per-knob overflow vector, slot-indexed by knob id so the
             # executor always grows exactly the blown capacity (emission
             # order differs from knob-assignment order)
@@ -1336,11 +1365,12 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
             # out_specs are the authority here
             check_vma=False,
         ))
-        # what FRAGMENT_JOINS and FRAGMENT_COMPACTIONS count at every
-        # launch: filled by the first call's trace, kept with the function
-        # in the fragment cache
+        # what FRAGMENT_JOINS, FRAGMENT_COMPACTIONS and
+        # FRAGMENT_EAGER_PARTIALS count at every launch: filled by the
+        # first call's trace, kept with the function in the fragment cache
         fn.join_probes = join_probes
         fn.compactions = compactions
+        fn.eager_partials = eager
         return fn
 
     return FragmentProgram(

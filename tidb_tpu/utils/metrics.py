@@ -503,6 +503,16 @@ FRAGMENT_COMPACTIONS = Counter(
     "compactions its program's trace took; a fragment whose targets all "
     "reach their chunks' capacities (TPC-H Q18's inner aggregate over an "
     "unfiltered scan) adds nothing")
+FRAGMENT_EAGER_PARTIALS = Counter(
+    "tidb_tpu_fragment_eager_partials_total",
+    "Eager partial aggregates (a join input grouped per shard before the "
+    "join: parallel/fragment.py _try_partial_agg_producer) compiled into "
+    "the general fragment programs launched, by fragment kind and by how "
+    "the group table was sized, static per program: sized=estimate, the "
+    "estimated groups with their headroom, fewer slots than the input has "
+    "rows (TPC-H Q3 and Q18 at SF1: 1,865,612 slots over 6,001,215 rows); "
+    "sized=input, that estimate at or over the input's capacity, one slot "
+    "a row. A launch adds its program's count (Q3: 1; Q18: 1)")
 FRAGMENT_RETRY_TOTAL = Counter(
     "tidb_tpu_fragment_retry_total",
     "Fragment launches thrown away because a capacity knob overflowed "
