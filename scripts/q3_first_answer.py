@@ -1,10 +1,10 @@
 #!/usr/bin/env python3
-"""What a cold TPC-H Q3 (or, ``--statement q18``, Q18) costs on the
+"""What a cold TPC-H Q3 (or, ``--statement q18`` / ``q9``, Q18 / Q9) costs on the
 device this process finds (ROADMAP S3's question to the chip): boots as
 the benchmark's cells ``tpch_sf1_power.q3`` / ``.q18`` do, sends the
 statement on a fresh connection and prints one JSON line a phase —
 
-    python3 scripts/q3_first_answer.py [--statement q18] [--root TREE] [--sf 1.0] [--seed N]
+    python3 scripts/q3_first_answer.py [--statement q18|q9] [--root TREE] [--sf 1.0] [--seed N]
 
 seconds to the first answer, backend compiles and their seconds,
 launches (FRAGMENT_DISPATCH) and retries by knob (FRAGMENT_RETRY_TOTAL),
@@ -32,9 +32,10 @@ import time
 
 T0 = time.time()
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# each statement's validation values (clauses 2.4.3.4, 2.4.18.4)
+# each statement's validation values (clauses 2.4.3.4, 2.4.18.4); Q9's
+# COLOR is the one word of the generator's names that traffic/q9.json takes
 PARAMS = {"q3": [{"segment": "BUILDING", "date": "1995-03-15"}],
-          "q18": [{"quantity": 300}]}
+          "q18": [{"quantity": 300}], "q9": [{"color": "blue"}]}
 
 
 def emit(**kw) -> None:
@@ -49,7 +50,8 @@ def program_counters() -> dict:
     out = {}
     for name in ("FRAGMENT_DISPATCH", "FRAGMENT_RETRY_TOTAL",
                  "FRAGMENT_JOINS", "FRAGMENT_SUBQUERIES",
-                 "FRAGMENT_COMPACTIONS", "FRAGMENT_EAGER_PARTIALS",
+                 "FRAGMENT_COMPACTIONS", "FRAGMENT_COMPACT_SLOTS",
+                 "FRAGMENT_EAGER_PARTIALS", "FRAGMENT_MULTIKEY_JOINS",
                  "FRAGMENT_EXCHANGE_STEPS",
                  "FRAGMENT_REDUCE_PAYLOADS", "FRAGMENT_COMPILE"):
         c = getattr(metrics, name, None)

@@ -234,9 +234,10 @@ def _chunk(rng) -> Chunk:
 @pytest.mark.parametrize("cap", [64, 4096, R, R + 1])
 def test_a_chunk_is_compacted_only_under_its_capacity(cap):
     """`_compact_chunk`: columns keep their types; at or over the chunk's
-    capacity nothing is compiled, reported or counted."""
+    capacity nothing is compiled, reported or counted; under it the
+    compaction's slots are noted beside its knob."""
     chunk = _chunk(np.random.default_rng(cap))
-    env, ovfs = {"compactions": []}, []
+    env, ovfs = {"compactions": [], "compact_slots": []}, []
     mesh = make_mesh(devices=jax.devices()[:1])
     axes = tuple(mesh.axis_names)
 
@@ -249,8 +250,10 @@ def test_a_chunk_is_compacted_only_under_its_capacity(cap):
         run, mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False))(chunk)
     if cap >= R:
         assert env["compactions"] == [] and ovfs == [] and out.capacity == R
+        assert env["compact_slots"] == []
         return
     assert env["compactions"] == [5] and [k for k, _ in ovfs] == [5]
+    assert env["compact_slots"] == [cap] == [out.capacity]
     arrays = {}
     for uid, col in chunk.columns.items():
         arrays[uid + ".d"] = np.asarray(col.data)

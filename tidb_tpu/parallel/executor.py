@@ -60,7 +60,8 @@ __all__ = ["ShardCache", "build_dist_executor", "DistAggExec", "DistJoinAggExec"
 def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
                      reduced: Tuple[int, int] = (0, 0), joins=(),
                      subqueries: Tuple[int, ...] = (0, 0, 0), compactions=(),
-                     exists: int = 0, eager_partials=()):
+                     exists: int = 0, eager_partials=(), multikey: int = 0,
+                     compact_slots=None):
     """One fragment launch: the span ``fragment.<kind>[parts=N]`` on the
     statement's trace and the FRAGMENT_SECONDS collector for /metrics
     (with a trace_id exemplar). Wall time covers the launch plus any
@@ -83,15 +84,23 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
     semi and anti joins it decides from the rank's count alone, `exists`
     (FRAGMENT_EXISTENCE_JOINS), and how its trace sized each eager
     partial's group table, `eager_partials` = "estimate" or "input" of
-    each (FRAGMENT_EAGER_PARTIALS; read after the launch, as `joins`)."""
+    each (FRAGMENT_EAGER_PARTIALS; read after the launch, as `joins`), and
+    its joins on several keys, `multikey` (FRAGMENT_MULTIKEY_JOINS, and
+    the count ``multikey_joins`` on the span), and a general fragment's
+    compactions' slots, `compact_slots` = the capacity of each
+    (FRAGMENT_COMPACT_SLOTS, and the count ``compact_slots`` on the span:
+    static capacities, nothing fetched; read after the launch, as
+    `joins`)."""
     from tidb_tpu.utils import tracing
     from tidb_tpu.utils.metrics import (
+        FRAGMENT_COMPACT_SLOTS,
         FRAGMENT_COMPACTIONS,
         FRAGMENT_DISPATCH,
         FRAGMENT_EAGER_PARTIALS,
         FRAGMENT_EXCHANGE_STEPS,
         FRAGMENT_EXISTENCE_JOINS,
         FRAGMENT_JOINS,
+        FRAGMENT_MULTIKEY_JOINS,
         FRAGMENT_REDUCE_PAYLOADS,
         FRAGMENT_SECONDS,
         FRAGMENT_SUBQUERIES,
@@ -100,6 +109,10 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
     t0 = time.perf_counter()
     with tracing.span(f"fragment.{kind}[parts={n_parts}]"):
         yield
+        if multikey:
+            tracing.add("multikey_joins", multikey)
+        if compact_slots is not None:
+            tracing.add("compact_slots", sum(compact_slots))
     FRAGMENT_DISPATCH.inc(kind=kind)
     FRAGMENT_EXCHANGE_STEPS.inc(exchanges, kind=kind)
     for path, n in zip(("runs", "scatter"), reduced):
@@ -109,7 +122,9 @@ def _fragment_launch(kind: str, n_parts: int, exchanges: int = 0,
     for path, n in zip(("inline", "broadcast", "scalar"), subqueries):
         FRAGMENT_SUBQUERIES.inc(n, kind=kind, path=path)
     FRAGMENT_EXISTENCE_JOINS.inc(exists, kind=kind)
+    FRAGMENT_MULTIKEY_JOINS.inc(multikey, kind=kind)
     FRAGMENT_COMPACTIONS.inc(len(compactions), kind=kind)
+    FRAGMENT_COMPACT_SLOTS.inc(sum(compact_slots or ()), kind=kind)
     for sized in eager_partials:
         FRAGMENT_EAGER_PARTIALS.inc(kind=kind, sized=sized)
     FRAGMENT_SECONDS.observe(time.perf_counter() - t0, kind=kind)
@@ -196,6 +211,7 @@ class ShardCache:
         # build_fn)
         dispatch.join_probes = getattr(fn, "join_probes", ())
         dispatch.compactions = getattr(fn, "compactions", ())
+        dispatch.compact_slots = getattr(fn, "compact_slots", ())
         dispatch.eager_partials = getattr(fn, "eager_partials", ())
         return dispatch
 
@@ -448,7 +464,8 @@ class DistFragmentExec(HashAggExec):
     # to the exact reported factor (never speculative), and a compacted
     # probe side legitimately inflates the factor — the ceiling only
     # guards against compiling absurd buffers for pathological skew.
-    MAX_GROWTH = {"exch": 64.0, "expand": 65536.0, "compact": float("inf")}
+    MAX_GROWTH = {"exch": 64.0, "expand": 65536.0, "compact": float("inf"),
+                  "pack": 2.0}
 
     def __init__(self, plan: PHashAgg, prog, cache: ShardCache):
         super().__init__(plan.schema, None, plan.group_exprs, plan.group_uids,
@@ -665,7 +682,8 @@ class DistFragmentExec(HashAggExec):
                                   (prog.n_subquery, len(prog.broadcasts),
                                    prog.n_scalar),
                                   fn.compactions, prog.n_exists,
-                                  fn.eager_partials):
+                                  fn.eager_partials, prog.n_multikey,
+                                  fn.compact_slots):
                 out, ovf = fn(*args)
             # host-sync: the per-knob overflow vector (a few int64s)
             # gates the capacity-retry loop — one fetch per dispatch

@@ -80,6 +80,7 @@ the program computes under ``scalar<k>`` (`_scalar_producer`,
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
@@ -239,13 +240,14 @@ def _flat(cols: Dict[str, Column], bits=(),
 def _compact_chunk(env, chunk: Chunk, cap: int, knob: int, ovfs) -> Chunk:
     """`chunk`'s live rows in a Chunk of capacity `cap`, where that is
     below its own (static: the chunk itself otherwise); the overflow
-    reported under `knob`, the compaction noted in `env` (what
-    FRAGMENT_COMPACTIONS counts)."""
+    reported under `knob`, the compaction and its slots noted in `env`
+    (what FRAGMENT_COMPACTIONS and FRAGMENT_COMPACT_SLOTS count)."""
     if cap >= chunk.capacity:
         return chunk
     out, nsel, ovf = _compact(_flat(chunk.columns), chunk.sel, cap)
     ovfs.append((knob, pmax(ovf, _AXES)))
     env["compactions"].append(knob)
+    env["compact_slots"].append(cap)
     cols = {
         uid: Column(data=out[uid + ".d"], valid=out[uid + ".v"],
                     type_=col.type_)
@@ -262,6 +264,33 @@ def _mix_hash(bits: List[jax.Array]) -> jax.Array:
     for b in bits:
         h = (h ^ b) * np.int64(-7046029254386353131) + np.int64(0x165667B19E3779F9)
     return h
+
+
+def _key_packing(bounds) -> Optional[List[Tuple[int, int, int]]]:
+    """(least value, bits, shift) a key of a join on several keys, where
+    the planner's bounds of every pair (`PHashJoin.key_bounds`) fit 62
+    bits together: the keys then pack into ONE exact sort key, the last
+    in the lowest bits. None where they do not."""
+    if not bounds:
+        return None
+    bits = [max(int(hi - lo).bit_length(), 1) for lo, hi in bounds]
+    if sum(bits) > 62:
+        return None
+    shifts = [sum(bits[i + 1:]) for i in range(len(bits))]
+    return [(int(lo), b, sh) for (lo, _hi), b, sh in zip(bounds, bits, shifts)]
+
+
+def _pack_keys(outs, packing) -> Tuple[jax.Array, jax.Array]:
+    """(the packed key of every slot, whether all its keys lie inside
+    the packing's bounds): a key outside them would alias another's."""
+    key, inside = None, None
+    for (d, _v), (lo, bits, shift) in zip(outs, packing):
+        off = d.astype(jnp.int64) - np.int64(lo)
+        ok = (off >= 0) & (off < np.int64(1 << bits))
+        part = off << np.int64(shift)
+        key = part if key is None else key | part
+        inside = ok if inside is None else inside & ok
+    return key, inside
 
 
 def _normalize_red_limbs(red, layout, aggs):
@@ -317,6 +346,8 @@ class FragmentProgram:
     # semi and anti joins decided by the rank's count (_join_producer)
     # — FRAGMENT_EXISTENCE_JOINS
     n_exists: int
+    # joins on several keys — FRAGMENT_MULTIKEY_JOINS
+    n_multikey: int
     sig: str
     # (growths tuple) -> per-shard program; its `join_probes` lists, once
     # traced, the probe path of each join ("table" | "search"), join0 first,
@@ -371,6 +402,8 @@ class _Compiler:
         self.n_subquery = 0
         self.n_scalar = 0
         self.n_exists = 0
+        # joins on several keys (packed into one, or hashed and verified)
+        self.n_multikey = 0
 
     def _add_growth(self, default: float, kind: str) -> int:
         idx = self.n_growth
@@ -769,7 +802,6 @@ class _Compiler:
         other_cond = join.other_cond
         other_pred = compile_predicate(other_cond) if other_cond is not None else None
         nk = len(probe_keys)
-        need_verify = nk > 1
         # a semi or anti join on ONE key with nothing more to test needs
         # to know of each probe slot only whether a live build row has its
         # key: the rank's count says it exactly (one key's hash is its
@@ -782,6 +814,14 @@ class _Compiler:
         g_exch = self._add_growth(2.0, "exch") if exchange else None
         g_expand = None if exists else self._add_growth(1.0, "expand")
         self.n_exists += exists
+        # several keys: ONE exact sort key where the planner's bounds let
+        # them pack, under a "pack" knob (a live key outside the bounds
+        # relaunches the program with the keys mixed into a hash, which
+        # a collision can join wrongly, so each key is verified after the
+        # gather); that hash from the start where the bounds are unknown
+        packing = _key_packing(join.key_bounds) if nk > 1 else None
+        g_pack = self._add_growth(1.0, "pack") if packing else None
+        self.n_multikey += nk > 1
         # numbered after its inputs, as the knobs are: join0's ops run first
         j_scope = f"join{self.n_join}"
         self.n_join += 1
@@ -796,7 +836,7 @@ class _Compiler:
         n_parts = self.n_parts
         self.sig.append(
             f"join:{kind}:{exists_sem}:{probe_keys!r}:{build_keys!r}:{other_cond!r}"
-            f":exch{exchange}"
+            f":exch{exchange}" + (f":pack{packing}" if packing else "")
         )
         # the joined rows' columns feed inner/left output and other_cond
         # evaluation; a semi or anti join reads only which rows matched
@@ -823,18 +863,31 @@ class _Compiler:
                 capB = int(np.ceil(growths[g_bcomp] * b_base))
                 bch = _compact_chunk(env, bch, capB, g_bcomp, ovfs)
 
-            p_outs = [eval_expr(k, pch) for k in probe_keys]
-            b_outs = [eval_expr(k, bch) for k in build_keys]
-            p_bits = [_key_bits(d, v) for d, v in p_outs]
-            b_bits = [_key_bits(d, v) for d, v in b_outs]
-            p_kvalid = p_outs[0][1]
-            b_kvalid = b_outs[0][1]
-            for _, v in p_outs[1:]:
-                p_kvalid = p_kvalid & v
-            for _, v in b_outs[1:]:
-                b_kvalid = b_kvalid & v
-            p_hash = _mix_hash(p_bits)
-            b_hash = _mix_hash(b_bits)
+            # trace-time static, by the knob: packed, or hashed and verified
+            packed = packing is not None and growths[g_pack] <= 1.0
+            need_verify = nk > 1 and not packed
+            with (jax.named_scope("join.key") if nk > 1
+                  else contextlib.nullcontext()):
+                p_outs = [eval_expr(k, pch) for k in probe_keys]
+                b_outs = [eval_expr(k, bch) for k in build_keys]
+                p_bits = [_key_bits(d, v) for d, v in p_outs]
+                b_bits = [_key_bits(d, v) for d, v in b_outs]
+                p_kvalid = p_outs[0][1]
+                b_kvalid = b_outs[0][1]
+                for _, v in p_outs[1:]:
+                    p_kvalid = p_kvalid & v
+                for _, v in b_outs[1:]:
+                    b_kvalid = b_kvalid & v
+                if packed:
+                    p_hash, p_in = _pack_keys(p_outs, packing)
+                    b_hash, b_in = _pack_keys(b_outs, packing)
+                    outside = (
+                        jnp.sum(pch.sel & p_kvalid & ~p_in, dtype=jnp.int64)
+                        + jnp.sum(bch.sel & b_kvalid & ~b_in, dtype=jnp.int64))
+                    ovfs.append((g_pack, pmax(outside, _AXES)))
+                else:
+                    p_hash = _mix_hash(p_bits)
+                    b_hash = _mix_hash(b_bits)
 
             # NOT IN null semantics: any live build row with a NULL key
             # empties the anti result — counted across the whole mesh
@@ -897,7 +950,12 @@ class _Compiler:
             if mode == "auto":
                 # the one sort is the build's too: `order` is the merged
                 # order itself and `lo` a place in it (the primitive
-                # scopes its sort join.build and the rest join.probe)
+                # scopes its sort join.build and the rest join.probe);
+                # a packed key of 32 bits or fewer sorts as one u32
+                # operand, not a 64-bit key's two
+                if packed and sum(b for _lo, b, _sh in packing) <= 32:
+                    b_hash2 = b_hash2.astype(jnp.uint32)
+                    p_hash2 = p_hash2.astype(jnp.uint32)
                 lo, cnt, order = merged_hash_ranges(
                     b_hash2, b_live, p_hash2, p_ok)
                 path = "merge"
@@ -1324,11 +1382,13 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
         # strategy); None = the hash_probe process default
         join_probes: List[str] = []
         compactions: List[int] = []
+        compact_slots: List[int] = []
         eager: List[str] = []
 
         def frag_general(*args):
             env = {"scan": [], "bcast": [], "probe_mode": probe_mode,
-                   "joins": [], "compactions": [], "eager": []}
+                   "joins": [], "compactions": [], "compact_slots": [],
+                   "eager": []}
             i = 0
             for _ in range(n_src):
                 env["scan"].append((args[i], args[i + 1], args[i + 2],
@@ -1341,6 +1401,7 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
             # trace time: static per program
             join_probes[:] = env["joins"]
             compactions[:] = env["compactions"]
+            compact_slots[:] = env["compact_slots"]
             eager[:] = env["eager"]
             # per-knob overflow vector, slot-indexed by knob id so the
             # executor always grows exactly the blown capacity (emission
@@ -1365,11 +1426,13 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
             # out_specs are the authority here
             check_vma=False,
         ))
-        # what FRAGMENT_JOINS, FRAGMENT_COMPACTIONS and
-        # FRAGMENT_EAGER_PARTIALS count at every launch: filled by the
-        # first call's trace, kept with the function in the fragment cache
+        # what FRAGMENT_JOINS, FRAGMENT_COMPACTIONS (and their slots,
+        # FRAGMENT_COMPACT_SLOTS) and FRAGMENT_EAGER_PARTIALS count at
+        # every launch: filled by the first call's trace, kept with the
+        # function in the fragment cache
         fn.join_probes = join_probes
         fn.compactions = compactions
+        fn.compact_slots = compact_slots
         fn.eager_partials = eager
         return fn
 
@@ -1379,7 +1442,7 @@ def compile_fragment(agg: PHashAgg, mesh, n_parts: int,
         n_reduce=(c.reduce_paths.count("runs"),
                   c.reduce_paths.count("scatter")),
         n_join=c.n_join, n_subquery=c.n_subquery, n_scalar=c.n_scalar,
-        n_exists=c.n_exists,
+        n_exists=c.n_exists, n_multikey=c.n_multikey,
         sig="|".join(c.sig),
         build_fn=build_fn,
         out_kind=out_kind, domains=domains,

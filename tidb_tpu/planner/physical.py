@@ -424,6 +424,9 @@ class PHashJoin(PhysicalPlan):
     other_cond: object = None
     build_side: int = 1  # child index used as build side
     exists_sem: bool = False  # see LJoin.exists_sem
+    # several keys: each pair's (least, greatest) value by the
+    # statistics, or None (`_pack_bounds`)
+    key_bounds: Optional[Tuple[Tuple[int, int], ...]] = None
 
     def op_name(self):
         return "HashJoin"
@@ -605,27 +608,136 @@ class PUnion(PhysicalPlan):
 _SEL_FILTER = 0.25
 
 
-def resolve_scan_col(plan: LogicalPlan, uid: str):
-    """Trace a column uid to its defining base-table column (through
-    pass-through projections). Returns (table, column_name) or None."""
+def _scan_of(plan: LogicalPlan, uid: str):
+    """Trace a column uid to the base-table scan that defines it
+    (through pass-through projections). Returns (LScan, column_name) or
+    None."""
     from tidb_tpu.expression.expr import ColumnRef
 
     if isinstance(plan, LScan):
         for c in plan.schema:
             if c.uid == uid:
-                return (plan.table, c.name) if plan.table is not None else None
+                return (plan, c.name) if plan.table is not None else None
         return None
     if isinstance(plan, LProjection):
         for c, e in zip(plan.schema, plan.exprs):
             if c.uid == uid:
                 if isinstance(e, ColumnRef):
-                    return resolve_scan_col(plan.child, e.name)
+                    return _scan_of(plan.child, e.name)
                 return None
     for ch in plan.children:
-        r = resolve_scan_col(ch, uid)
+        r = _scan_of(ch, uid)
         if r is not None:
             return r
     return None
+
+
+def resolve_scan_col(plan: LogicalPlan, uid: str):
+    """Trace a column uid to its defining base-table column (through
+    pass-through projections). Returns (table, column_name) or None."""
+    r = _scan_of(plan, uid)
+    return None if r is None else (r[0].table, r[1])
+
+
+def _keeps_rows_of(plan: LogicalPlan, scan: LScan) -> Optional[bool]:
+    """Whether each row of `scan` appears at most once in `plan`'s
+    output (None: `scan` is not under `plan`). A filter, projection,
+    sort or limit keeps its input's rows, a semi or anti join its left
+    side's; an inner or left join keeps a side's rows where the other
+    side's join keys cover a unique key of it (each row matches at most
+    once); anything else may repeat them."""
+    if plan is scan:
+        return True
+    if isinstance(plan, (LSelection, LProjection, LSort, LLimit)):
+        return _keeps_rows_of(plan.child, scan)
+    for i, ch in enumerate(plan.children):
+        kept = _keeps_rows_of(ch, scan)
+        if kept is None:
+            continue
+        if not kept or not isinstance(plan, LJoin):
+            return False
+        if plan.kind in ("semi", "anti"):
+            return i == 0
+        if plan.kind not in ("inner", "left") or not plan.eq_conds:
+            return False
+        keys = [pair[1 - i] for pair in plan.eq_conds]
+        return _unique_key_scan(plan.children[1 - i], keys) is not None
+    return None
+
+
+def _unique_key_scan(side: LogicalPlan, keys) -> Optional[LScan]:
+    """The scan under `side` whose table's declared primary key the
+    join keys `keys` (expressions over `side`) cover, with each of its
+    rows at most once in `side`'s output: every row of the other side
+    then matches at most one row of `side`. None where there is none."""
+    from tidb_tpu.expression.expr import ColumnRef
+
+    by_scan = {}
+    for k in keys:
+        r = _scan_of(side, k.name) if isinstance(k, ColumnRef) else None
+        if r is not None:
+            by_scan.setdefault(id(r[0]), (r[0], set()))[1].add(r[1])
+    for scan, cols in by_scan.values():
+        pk = getattr(scan.table.schema, "primary_key", None)
+        if pk and set(pk) <= cols and _keeps_rows_of(side, scan):
+            return scan
+    return None
+
+
+def _table_rows(table) -> float:
+    from tidb_tpu.statistics import table_stats
+
+    s = table_stats(table)
+    return float(s.n_rows) if s is not None else float(table.live_rows)
+
+
+def _unique_key_rows(left: LogicalPlan, right: LogicalPlan, eq_conds,
+                     l: float, r: float) -> Optional[float]:
+    """A join whose equalities cover a unique key of one side: each row
+    of the other side matches at most once, so the join emits the other
+    side's rows times the covered side's surviving share of its table
+    (the foreign-key join's estimate), the smaller where both sides are
+    covered. None where neither is."""
+    out = None
+    for side, keys, n_side, n_other in (
+            (right, [b for _, b in eq_conds], r, l),
+            (left, [a for a, _ in eq_conds], l, r)):
+        scan = _unique_key_scan(side, keys)
+        if scan is None:
+            continue
+        share = min(n_side / max(_table_rows(scan.table), 1.0), 1.0)
+        out = n_other * share if out is None else min(out, n_other * share)
+    return out
+
+
+def _pack_bounds(join: LJoin) -> Optional[Tuple[Tuple[int, int], ...]]:
+    """Per key pair of a join on several keys, the least and greatest
+    value either side's column holds by its statistics (ANALYZE's, or
+    what the bulk load recorded while nothing has been written since):
+    the fragment packs the keys into one exact sort key where these
+    bounds fit 62 bits together (`parallel/fragment.py`) and checks each
+    live row against them. None where a key is no integer column of a
+    table, or its bounds are unknown."""
+    from tidb_tpu.expression.expr import ColumnRef
+    from tidb_tpu.statistics import column_bounds
+    from tidb_tpu.types import TypeKind
+
+    out = []
+    for pair in join.eq_conds:
+        lo, hi = None, None
+        for child, e in zip(join.children, pair):
+            if not (isinstance(e, ColumnRef)
+                    and e.type_.kind in (TypeKind.INT, TypeKind.DATE)
+                    and e.type_.kind == pair[0].type_.kind):
+                return None
+            r = _scan_of(child, e.name)
+            b = column_bounds(r[0].table, r[1]) if r is not None else None
+            if b is None:
+                return None
+            lo = b[0] if lo is None else min(lo, b[0])
+            hi = b[1] if hi is None else max(hi, b[1])
+        out.append((lo, hi))
+    return tuple(out)
 
 
 def _eq_ndv(child: LogicalPlan, expr, child_rows: float) -> Optional[float]:
@@ -701,6 +813,20 @@ def eq_join_rows(left: LogicalPlan, right: LogicalPlan, eq_conds,
             out = max(min(float(got), l * r), 1.0)
             return max(out, l) if kind == "left" else out
 
+    if len(eq_conds) > 1:
+        # several keys are not independent where together they are a
+        # unique key of one side (TPC-H's partsupp (ps_partkey,
+        # ps_suppkey)): a product of per-key 1/ndv would read lineitem's
+        # one partsupp row a line as 1 in 2,500. One key keeps the rule
+        # below, which for a unique key says the same
+        got = _unique_key_rows(left, right, eq_conds, l, r)
+        if got is not None:
+            from tidb_tpu.utils import tracing
+
+            tracing.add("unique_key_estimates", 1)
+            out = max(got, 1.0)
+            return max(out, l) if kind == "left" else out
+
     sel = None
     for le, re_ in eq_conds:
         kl = _key_col_stats(left, le)
@@ -749,7 +875,7 @@ def _estimate(plan: LogicalPlan) -> float:
         if plan.table is None:
             return 1.0
         s = table_stats(plan.table)
-        n = float(s.n_rows) if s is not None else float(plan.table.live_rows)
+        n = _table_rows(plan.table)
         if plan.pushed_cond is not None:
             # plan feedback (ISSUE 15): an observed selectivity for this
             # (table, filter) shape — recorded where a past execution
@@ -893,6 +1019,7 @@ def lower(plan: LogicalPlan) -> PhysicalPlan:
             schema=plan.schema, children=[l, r], est_rows=est, kind=plan.kind,
             eq_left=eq_l, eq_right=eq_r, other_cond=plan.other_cond,
             build_side=build, exists_sem=plan.exists_sem,
+            key_bounds=_pack_bounds(plan) if len(plan.eq_conds) > 1 else None,
         )
     if isinstance(plan, LSort):
         return PSort(schema=plan.schema, children=[lower(plan.child)], est_rows=est, items=plan.items)

@@ -30,7 +30,7 @@ from tidb_tpu.types import TypeKind
 
 __all__ = ["ColumnStats", "TableStats", "analyze_table", "table_stats",
            "zone_map_stats", "load_stats", "record_load_stats",
-           "scan_selectivity", "column_ndv",
+           "scan_selectivity", "column_ndv", "column_bounds",
            "eq_join_selectivity", "NDVSketch", "HIST_BUCKETS", "MCV_SIZE"]
 
 HIST_BUCKETS = 64
@@ -328,6 +328,20 @@ def column_ndv(table, col_name: str) -> Optional[float]:
     return None
 
 
+def column_bounds(table, col_name: str):
+    """(least, greatest) value of a numeric column's device
+    representation, as integers, by ANALYZE's fresh statistics or, while
+    nothing has been written since, the bulk load's record; None where
+    neither has them."""
+    s = table_stats(table) or load_stats(table)
+    cs = s.cols.get(col_name) if s is not None else None
+    if cs is None or cs.min is None or cs.max is None:
+        return None
+    if max(abs(cs.min), abs(cs.max)) >= 2.0 ** 53:
+        return None  # past float64's integers: not read exactly
+    return int(np.floor(cs.min)), int(np.ceil(cs.max))
+
+
 def eq_join_selectivity(sl: TableStats, cl: ColumnStats,
                         sr: TableStats, cr: ColumnStats) -> float:
     """P(random left row key == random right row key) for an equi-join,
@@ -428,10 +442,39 @@ def _value_fraction(stats: TableStats, cs: ColumnStats, lit, dic) -> Optional[fl
     return cs.mcv.get(key, 0) / max(stats.n_rows, 1)
 
 
+def _code_map_share(stats: TableStats, pred, uid_to_col: Dict[str, str],
+                    dicts: Optional[dict]) -> Optional[float]:
+    """The share of rows a boolean code map over a dictionary column
+    keeps (a LIKE or NOT LIKE, a REGEXP: `binder.bind_like`), summed
+    from the count of every code where the statistics hold them all (a
+    bulk load's code counts): exact. None otherwise."""
+    from tidb_tpu.expression.expr import ColumnRef
+
+    if not isinstance(pred.arg, ColumnRef):
+        return None
+    col = uid_to_col.get(pred.arg.name)
+    cs = stats.cols.get(col) if col else None
+    dic = (dicts or {}).get(col)
+    if (dic is None or cs is None or cs.mcv is None
+            or cs.ndv != len(cs.mcv) or len(pred.table) != len(dic.values)):
+        return None
+    valid = pred.table_valid or (True,) * len(pred.table)
+    n = sum(cs.mcv.get(v, 0) for v, hit, ok in zip(dic.values, pred.table, valid)
+            if hit and ok)
+    from tidb_tpu.utils import tracing
+
+    tracing.add("dict_pred_estimates", 1)
+    return n / max(stats.n_rows, 1)
+
+
 def _pred_selectivity(stats: TableStats, pred, uid_to_col: Dict[str, str],
                       dicts: Optional[dict] = None) -> float:
-    from tidb_tpu.expression.expr import Call, ColumnRef, InList, Literal
+    from tidb_tpu.expression.expr import Call, ColumnRef, InList, Literal, Lookup
 
+    if isinstance(pred, Lookup) and pred.type_.kind == TypeKind.BOOL:
+        f = _code_map_share(stats, pred, uid_to_col, dicts)
+        if f is not None:
+            return f
     if isinstance(pred, Call) and pred.op in _CMP and len(pred.args) == 2:
         a, b = pred.args
         if isinstance(b, ColumnRef) and isinstance(a, Literal):

@@ -491,6 +491,14 @@ FRAGMENT_EXISTENCE_JOINS = Counter(
     "condition), with no expansion, gather or scatter of match flags, by "
     "fragment kind, static per program. A launch adds its program's count "
     "(TPC-H Q18: 1, its IN; Q22: 1, its NOT EXISTS)")
+FRAGMENT_MULTIKEY_JOINS = Counter(
+    "tidb_tpu_fragment_multikey_joins_total",
+    "Joins on several keys of the general fragment programs launched "
+    "(parallel/fragment.py _join_producer), by fragment kind, static per "
+    "program: the keys packed into ONE exact sort key where the "
+    "planner's bounds of them fit 62 bits, else mixed into a hash and "
+    "verified key by key. A launch adds its program's count (TPC-H Q9: 1, "
+    "lineitem with partsupp on its primary key)")
 FRAGMENT_COMPACTIONS = Counter(
     "tidb_tpu_fragment_compactions_total",
     "Compactions compiled into the general fragment programs launched "
@@ -503,6 +511,13 @@ FRAGMENT_COMPACTIONS = Counter(
     "compactions its program's trace took; a fragment whose targets all "
     "reach their chunks' capacities (TPC-H Q18's inner aggregate over an "
     "unfiltered scan) adds nothing")
+FRAGMENT_COMPACT_SLOTS = Counter(
+    "tidb_tpu_fragment_compact_slots_total",
+    "Slots of the compactions that FRAGMENT_COMPACTIONS counts, by "
+    "fragment kind: the static capacity of each (twice the planner's "
+    "estimate of the rows, per part, or grown by a relaunch), which every "
+    "later sort, gather and exchange of the program pays for. A launch "
+    "adds its program's sum; nothing is fetched to count it")
 FRAGMENT_EAGER_PARTIALS = Counter(
     "tidb_tpu_fragment_eager_partials_total",
     "Eager partial aggregates (a join input grouped per shard before the "
@@ -518,7 +533,8 @@ FRAGMENT_RETRY_TOTAL = Counter(
     "Fragment launches thrown away because a capacity knob overflowed "
     "(the fragment is compiled and launched anew with the knob grown), "
     "by fragment kind and the kind of knob that blew (compact / expand "
-    "/ exch; a launch in which two kinds blew counts under both)")
+    "/ exch / pack: a key of a packed join outside its bounds; a launch "
+    "in which two kinds blew counts under both)")
 EXTERNAL_AGG = Counter("tidb_tpu_external_agg_total",
                        "Key-range external aggregation merges (group "
                        "state exceeded the memory budget)")
